@@ -1,0 +1,467 @@
+"""Does the system still start on the chip? One process, one TPU.
+
+Drives the two hot paths through the entry points a user calls, at the
+full published size of ResNet-50 v1 (1000 classes, 224x224), with random
+weights from a fixed seed:
+
+  train    SPMDTrainer on a one-device mesh, bf16 compute, batch 128:
+           one compile, five steps on a repeated batch.
+  serve    InferenceSession -> DynamicBatcher -> ModelServer(port=0):
+           warm the buckets, POST eight /predict requests over HTTP,
+           compare with the hybridized net's own forward on the chip.
+  kernels  each Pallas kernel once against its lax twin, and the native
+           int8 fully-connected lowering against the dequant one.
+
+``--chips 4`` runs ONLY the data-parallel phase and what it is compared
+with (same seed, same global batch, one-device mesh vs dp=4 mesh).
+``--rehearse`` lifts the platform check, shrinks sizes and interprets
+the kernels so the control flow can be walked on the CPU; a rehearsal
+never prints the result line.
+
+Run with no arguments on one chip, the last line of stdout is
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": 1}}``.
+Any phase that fails raises: non-zero exit, no result line. The script
+refuses to run when jax's first device is not a TPU, starts no child
+process, and places no cache itself (jax's persistent cache goes where
+JAX_COMPILATION_CACHE_DIR says, else to <checkout>/.jax_cache).
+"""
+from __future__ import annotations
+
+import argparse
+import io
+import json
+import os
+import sys
+import time
+import urllib.request
+
+import numpy as onp
+
+SEED = 0
+
+
+class Cfg:
+    """Sizes of a run. The chip run is the published ResNet-50 width;
+    the rehearsal only walks the control flow."""
+
+    def __init__(self, rehearse):
+        self.rehearse = rehearse
+        self.platform = "cpu" if rehearse else "tpu"
+        self.image = 32 if rehearse else 224
+        self.classes = 10 if rehearse else 1000
+        self.train_batch = 8 if rehearse else 128
+        self.serve_buckets = (1, 4) if rehearse else (1, 8)
+        # Pallas runs compiled on the chip; interpreted in a rehearsal
+        self.pallas = "interpret" if rehearse else "pallas"
+
+    def expect(self, ok, msg):
+        """Assert a property of the training dynamics. It holds at the
+        real size; at the rehearsal's (BatchNorm over eight 1x1 maps)
+        the dynamics are chaotic, so there it is only reported."""
+        if self.rehearse:
+            log(f"rehearsal, not asserted: {'holds' if ok else 'FAILS'}: "
+                f"{msg}")
+        else:
+            assert ok, msg
+
+
+def log(msg):
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+def assert_on(arrays, platform, what):
+    """Every array lives on a device of ``platform`` — asked of the
+    arrays themselves, not of the context they were made under."""
+    for name, a in arrays:
+        plats = {d.platform for d in a.devices()}
+        assert plats == {platform}, \
+            f"{what} {name} lives on {sorted(plats)}, expected {platform}"
+
+
+def build_net(cfg, hybridize=False):
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon.model_zoo import vision
+
+    mx.random.seed(SEED)
+    net = vision.resnet50_v1(classes=cfg.classes, layout="NHWC",
+                             stem_s2d=True)
+    net.initialize(mx.init.Xavier())
+    if hybridize:
+        net.hybridize()
+    return net
+
+
+def build_trainer(cfg, mesh, lr=0.05):
+    from mxnet_tpu import gluon, parallel
+
+    return parallel.SPMDTrainer(
+        build_net(cfg), gluon.loss.SoftmaxCrossEntropyLoss(),
+        optimizer="sgd",
+        optimizer_params={"learning_rate": lr, "momentum": 0.9},
+        mesh=mesh, compute_dtype="bfloat16")
+
+
+def train_batch(cfg):
+    from mxnet_tpu import nd
+
+    rng = onp.random.RandomState(SEED)
+    b, s = cfg.train_batch, cfg.image
+    x = nd.array(rng.rand(b, s, s, 3).astype("f"))
+    y = nd.array(rng.randint(0, cfg.classes, b).astype("f"))
+    return x, y
+
+
+def run_steps(trainer, x, y, steps):
+    """(losses, seconds per step, retraces after the first step). The
+    loss readback is the barrier: a step is over when its loss is on
+    the host."""
+    import jax
+
+    from mxnet_tpu.utils import compile_cache as cc
+
+    losses, secs, retraced = [], [], None
+    for _ in range(steps):
+        t0 = time.perf_counter()
+        losses.append(float(jax.device_get(trainer.step(x, y).data)))
+        secs.append(time.perf_counter() - t0)
+        if retraced is None:
+            retraced = cc.compile_cache_stats()["retraces"]
+    later = cc.compile_cache_stats()["retraces"] - retraced
+    return losses, secs, later
+
+
+# ---------------------------------------------------------------------------
+# phases
+
+def phase_train(cfg):
+    import jax
+
+    from mxnet_tpu import parallel
+
+    mesh = parallel.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    trainer = build_trainer(cfg, mesh)
+    x, y = train_batch(cfg)
+    losses, secs, later = run_steps(trainer, x, y, steps=5)
+    log(f"train: build+compile+first step {secs[0]:.1f}s, "
+        f"later steps {[round(s, 4) for s in secs[1:]]}s")
+    log(f"train: loss trace {[round(v, 4) for v in losses]}")
+    assert all(onp.isfinite(losses)), f"non-finite loss: {losses}"
+    cfg.expect(losses[-1] < losses[0],
+               f"loss falls on the repeated batch: {losses}")
+    assert later == 0, f"{later} retrace(s) after the first step"
+    params = trainer.param_arrays()
+    assert len(params) > 100, len(params)
+    assert_on(params.items(), cfg.platform, "parameter")
+    log(f"train: {len(params)} parameters on {cfg.platform}, "
+        "0 retraces after the first step")
+
+
+def _post_npy(url, arr):
+    buf = io.BytesIO()
+    onp.save(buf, arr)
+    req = urllib.request.Request(
+        url + "/predict", data=buf.getvalue(),
+        headers={"Content-Type": "application/x-npy",
+                 "X-Timeout-Ms": "120000"})
+    with urllib.request.urlopen(req, timeout=300) as resp:
+        assert resp.status == 200, resp.status
+        return onp.load(io.BytesIO(resp.read()), allow_pickle=False)
+
+
+def phase_serve(cfg):
+    from mxnet_tpu import autograd, nd, serving
+    from mxnet_tpu.serving.metrics import METRICS
+    from mxnet_tpu.utils import compile_cache as cc
+
+    # an operator's SLO for a 224x224 model whose requests carry
+    # megabytes: admission control must not shed the smoke's traffic
+    os.environ.setdefault("MXNET_SERVING_SLO_MS", "10000")
+    s = cfg.image
+    net = build_net(cfg, hybridize=True)
+    t0 = time.perf_counter()
+    sess = serving.InferenceSession(
+        net, input_shapes=[(1, s, s, 3)], buckets=list(cfg.serve_buckets),
+        warm=False)
+    warm = sess.warmup()
+    log(f"serve: init + warm-up of buckets {sess.buckets} "
+        f"{time.perf_counter() - t0:.1f}s, {warm}")
+    assert warm["compiles"] + warm["disk_hits"] == len(sess.buckets), warm
+    assert_on(((n, p.data().data) for n, p in
+               net.collect_params().items()), cfg.platform, "serving param")
+
+    rng = onp.random.RandomState(SEED + 1)
+    small, big = cfg.serve_buckets
+    batches = [small, big, small, small, big, small, big, big]
+    payloads = [rng.rand(b, s, s, 3).astype("f") for b in batches]
+    # the reference: the hybridized net's own eval forward on the chip
+    # (compiles one CachedOp per batch size — before the counters below)
+    refs = []
+    with autograd.pause(train_mode=False):
+        for x in payloads:
+            out = net(nd.array(x))
+            assert_on([("", out.data)], cfg.platform, "reference output")
+            refs.append(out.asnumpy())
+    direct = sess.predict(payloads[1])
+    assert_on([("", direct.data)], cfg.platform, "served output")
+
+    bat = serving.DynamicBatcher(sess, timeout_ms=120000)
+    srv = serving.ModelServer(batcher=bat, port=0).start()
+    try:
+        traced = cc.compile_cache_stats()["retraces"]
+        compiled = METRICS.snapshot()["warm_compiles"]
+        outs = [_post_npy(srv.address, x) for x in payloads]
+        traced = cc.compile_cache_stats()["retraces"] - traced
+        compiled = METRICS.snapshot()["warm_compiles"] - compiled
+    finally:
+        srv.stop()
+        bat.close()
+        sess.close()
+    assert traced == 0 and compiled == 0, \
+        f"{traced} retrace(s), {compiled} compile(s) after warm-up"
+    # Tolerance: both sides are fp32 programs of the same graph, but
+    # they are two executables (CachedOp vs the serving bucket) that
+    # XLA may fuse differently, and the TPU's default fp32 convolution
+    # rounds operands to bf16 passes — so agreement is to bf16-pass
+    # rounding of the largest logit, not bitwise.
+    worst = 0.0
+    for b, got, ref in zip(batches, outs, refs):
+        assert got.shape == ref.shape == (b, cfg.classes), got.shape
+        assert onp.isfinite(got).all()
+        worst = max(worst, float(onp.abs(got - ref).max()
+                                 / max(onp.abs(ref).max(), 1e-30)))
+    log(f"serve: 8 requests (batches {batches}) answered, worst "
+        f"deviation from the net's own forward {worst:.3e} of max |logit|; "
+        "0 compiles after warm-up; server, batcher, session closed")
+    assert worst < 1e-2, worst
+
+
+def _lowered_has_kernel(fn, *args):
+    import jax
+
+    return "tpu_custom_call" in jax.jit(fn).lower(*args).as_text()
+
+
+def phase_kernels(cfg):
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import kernels
+    # importing attention and norm_act registers the cluster ops
+    from mxnet_tpu.kernels import attention, cost_model, norm_act  # noqa: F401
+    from mxnet_tpu.kernels.flash_attention import flash_attention
+    from mxnet_tpu.ndarray import ops_quant
+    from mxnet_tpu.ndarray.registry import get_op
+
+    on_chip = not cfg.rehearse
+    rs = onp.random.RandomState(SEED + 2)
+
+    def randn(*shape):
+        return jnp.asarray(rs.randn(*shape).astype("f"))
+
+    def check(name, got, ref, tol, fn=None, args=()):
+        err = float(jnp.abs(got - ref).max())
+        log(f"kernels: {name} max |pallas - lax| = {err:.3e} (tol {tol})")
+        assert bool(jnp.isfinite(got).all()) and err < tol, (name, err)
+        assert_on([(name, got)], cfg.platform, "kernel output")
+        if on_chip:
+            assert _lowered_has_kernel(fn, *args), \
+                f"{name}: no tpu_custom_call in the lowered program"
+
+    # The tolerance is the interpret-mode parity tests' documented-ulp
+    # bound (1e-5 absolute on O(1) fp32 values). It is a statement about
+    # fp32 arithmetic, so both twins are traced at HIGHEST matmul
+    # precision: the TPU's default rounds fp32 matmul operands to bf16,
+    # which the kernel and its lax twin would apply at different points.
+    with jax.default_matmul_precision("highest"):
+        b, h, sq, d = (2, 2, 128, 64) if cfg.rehearse else (8, 12, 512, 64)
+        q, k, v = (randn(b, h, sq, d) for _ in range(3))
+
+        def flash(q, k, v):
+            # use_pallas=None is the user's path: pallas on the TPU
+            return flash_attention(q, k, v, causal=True,
+                                   use_pallas=None if on_chip else True)
+
+        ref = flash_attention(q, k, v, causal=True, use_pallas=False)
+        check("flash forward", flash(q, k, v), ref, 1e-5, flash, (q, k, v))
+
+        bsz, heads, seq, hd = (2, 2, 64, 16) if cfg.rehearse \
+            else (8, 12, 1024, 64)
+        dq = randn(bsz, heads * hd)
+        kc, vc = randn(bsz, seq, heads * hd), randn(bsz, seq, heads * hd)
+        pos = jnp.asarray(rs.randint(0, seq, (bsz, 1)).astype("int32"))
+        dec = get_op("_attention_decode").fn
+        kw = {"num_heads": heads, "sm_scale": 1.0 / hd ** 0.5}
+
+        def decode(q, kc, vc, pos):
+            return dec(q, kc, vc, pos, impl=cfg.pallas, **kw)
+
+        vmem_refusals = kernels.counters().get("fallback_vmem_bound", 0)
+        check("decode flash", decode(dq, kc, vc, pos),
+              dec(dq, kc, vc, pos, impl="lax", **kw), 1e-5, decode,
+              (dq, kc, vc, pos))
+        assert kernels.counters().get("fallback_vmem_bound", 0) \
+            == vmem_refusals
+
+    rows, c = (16, 128) if cfg.rehearse else (4096, 768)
+    # LayerNorm parameters near their initial (1, 0)
+    x, g, beta = randn(rows, c), 1.0 + 0.1 * randn(c), 0.1 * randn(c)
+    norm = get_op("_fused_norm_act").fn
+    # relu is exact, so it holds the kernel's normalise + affine to the
+    # 1e-5 bound. tanh is not: on the chip XLA's tanh and Mosaic's are
+    # both hardware approximations, each 4.4e-5 from a float64 reference
+    # and 2.2e-5 from each other on this input (my chip run, PR 26), so
+    # the twins are held to 1e-4 there.
+    for act_op, act_type, tol in (("relu", None, 1e-5),
+                                  ("activation", "tanh", 1e-4)):
+        decision = cost_model.decide(
+            "norm_act", 2, out_shape=(rows, c),
+            backend=jax.default_backend(), act_type=act_type)
+        if on_chip:  # the cost model itself must pick the kernel here
+            assert (decision.fuse, decision.impl) == (True, "pallas"), \
+                decision
+        nkw = {"norm_kw": (), "act_op": act_op,
+               "act_kw": (("act_type", act_type),) if act_type else ()}
+
+        def norm_act(x, g, beta):
+            return norm(x, g, beta, impl=cfg.pallas, **nkw)
+
+        check(f"norm_act {act_type or act_op}", norm_act(x, g, beta),
+              norm(x, g, beta, impl="lax", **nkw), tol, norm_act,
+              (x, g, beta))
+
+    # int8 fully-connected at the ResNet-50 head's width. auto must
+    # resolve to the MXU's native int8 x int8 -> int32 contraction on
+    # the chip. Both lowerings land on the int32 lattice: the dequant
+    # one accumulates the same integer products in fp32, exact below
+    # 2^24, so the documented tolerance is one lattice step.
+    os.environ["MXNET_QUANTIZE_LOWERING"] = "auto"
+    resolved = ops_quant.lowering()
+    log(f"kernels: MXNET_QUANTIZE_LOWERING=auto resolved to {resolved!r}")
+    assert resolved == ("native" if on_chip else "dequant"), resolved
+    m, kdim, n = (8, 64, 16) if cfg.rehearse else (128, 2048, 1000)
+    qd = jnp.asarray(rs.randint(-127, 128, (m, kdim)).astype("int8"))
+    qw = jnp.asarray(rs.randint(-127, 128, (n, kdim)).astype("int8"))
+    lo, hi = jnp.float32(-1.0), jnp.float32(1.0)
+    qfc = get_op("_contrib_quantized_fully_connected").fn
+
+    accs = {}
+    for mode in ("auto", "dequant"):
+        os.environ["MXNET_QUANTIZE_LOWERING"] = mode
+
+        def fc(qd, qw):  # a new function per mode: lowering() is read
+            # while tracing, and jit caches traces by function
+            return qfc(qd, qw, lo, hi, lo, hi, num_hidden=n,
+                       no_bias=True)[0]
+
+        text = jax.jit(fc).lower(qd, qw).as_text()
+        accs[mode] = jax.jit(fc)(qd, qw)
+        int8_dot = "xi8>) -> tensor<%dx%dxi32>" % (m, n) in text
+        if on_chip:
+            assert int8_dot == (mode == "auto"), (mode, text[-2000:])
+    os.environ["MXNET_QUANTIZE_LOWERING"] = "auto"
+    step = int(jnp.abs(accs["auto"] - accs["dequant"]).max())
+    log(f"kernels: quantized FC {m}x{kdim}x{n}, {resolved} vs dequant "
+        f"max lattice distance {step}")
+    assert accs["auto"].dtype == jnp.int32 and step <= 1, step
+    assert int(jnp.abs(accs["auto"]).max()) > 0
+    assert_on(accs.items(), cfg.platform, "quantized FC output")
+
+
+def phase_dp4(cfg):
+    """Data-parallel training over four chips against the same steps on
+    one: same seed, same global batch.
+
+    The comparison needs well-conditioned dynamics. At the train phase's
+    lr 0.05 the first steps from a random init are not: on four chips
+    the traces started 1.5e-3 apart (bf16 reduction order) and were
+    1.1e-1 apart by step 3 (my chip run, PR 26). At lr 0.005 a rounding
+    difference stays a rounding difference, so a wrong gradient sum or a
+    per-shard BatchNorm would stand out."""
+    import jax
+
+    from mxnet_tpu import parallel
+
+    devs = jax.devices()
+    assert len(devs) >= 4, f"--chips 4 needs four devices, jax has {devs}"
+    x, y = train_batch(cfg)
+    traces = {}
+    for name, n in (("one device", 1), ("dp=4", 4)):
+        mesh = parallel.make_mesh({"dp": n}, devices=devs[:n])
+        trainer = build_trainer(cfg, mesh, lr=0.005)
+        losses, secs, later = run_steps(trainer, x, y, steps=3)
+        log(f"dp4: {name}: build+compile+first step {secs[0]:.1f}s, "
+            f"loss trace {[round(v, 4) for v in losses]}")
+        assert all(onp.isfinite(losses)) and later == 0, (losses, later)
+        traces[name] = losses
+    # `trainer` is now the dp=4 one
+    params = trainer.param_arrays()
+    assert_on(params.items(), cfg.platform, "parameter")
+    for name, a in params.items():
+        assert set(a.devices()) == set(devs[:4]), \
+            f"parameter {name} is on {a.devices()}, not on all four"
+    xd = parallel.shard_batch(x, trainer.mesh).data
+    shards = sorted((s.device.id, s.data.shape[0])
+                    for s in xd.addressable_shards)
+    assert len(shards) == 4 and \
+        all(rows == cfg.train_batch // 4 for _, rows in shards), shards
+    hlo = trainer.step_hlo(x, y)
+    assert "all-reduce" in hlo, "no all-reduce in the dp=4 step"
+    # bf16 compute: the four-way split changes the order of the batch
+    # reductions (BN statistics, gradient sums), nothing else
+    rel = max(abs(a - b) / abs(a)
+              for a, b in zip(traces["one device"], traces["dp=4"]))
+    log(f"dp4: {len(params)} parameters on all four devices, batch split "
+        f"{shards}, all-reduce in the step, worst relative loss "
+        f"difference {rel:.3e}")
+    cfg.expect(rel < 2e-2, f"loss traces agree within 2e-2: {traces}")
+
+
+# ---------------------------------------------------------------------------
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="walk the control flow on the CPU at tiny "
+                         "size; prints no result line")
+    args = ap.parse_args(argv)
+    cfg = Cfg(args.rehearse)
+
+    import jax
+
+    import mxnet_tpu  # noqa: F401 — fails here in a bare directory
+    from mxnet_tpu import _native
+    from mxnet_tpu.utils import compile_cache as cc
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    log(f"jax {jax.__version__}, device {device}")
+    if dev.platform != cfg.platform:
+        print(f"chip_smoke: jax's first device is {dev.platform!r}, not "
+              f"{cfg.platform!r}; refusing to run", file=sys.stderr)
+        return 1
+    libs = {n: getattr(_native, n, None) is not None
+            for n in ("lib", "englib", "textlib")}
+    log("native runtime: " + ", ".join(
+        f"{n}={'built' if ok else 'pure-Python fallback'}"
+        for n, ok in libs.items()))
+    log(f"jax compile cache: {cc.jax_cache_dir()}")
+
+    one_chip = {"train": phase_train, "serve": phase_serve,
+                "kernels": phase_kernels}
+    todo = {"dp4": phase_dp4} if args.chips == 4 else one_chip
+    for name, phase in todo.items():
+        t0 = time.perf_counter()
+        log(f"phase {name} ...")
+        phase(cfg)
+        log(f"phase {name} passed in {time.perf_counter() - t0:.1f}s")
+    if args.rehearse:
+        log("passed — a rehearsal, not the chip check: no result line")
+        return 0
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

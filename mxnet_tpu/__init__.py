@@ -18,21 +18,10 @@ from __future__ import annotations
 __version__ = "0.1.0"
 
 
-def _distributed_is_initialized(jax):
-    """``jax.distributed.is_initialized`` arrived after 0.4.x; there the
-    tell is the private rendezvous client (initialized iff it exists)."""
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return fn()
-    from jax._src import distributed as _dist
-
-    return getattr(_dist.global_state, "client", None) is not None
-
-
 def _maybe_init_distributed():
     """jax.distributed.initialize must run BEFORE anything touches the
-    XLA backend, and importing this package touches it (PRNG state) —
-    so when the launcher's rendezvous env is present (tools/launch.py
+    XLA backend, and the first op after import does — so when the
+    launcher's rendezvous env is present (tools/launch.py
     MXNET_COORDINATOR), join the cluster here, first thing. The analog
     of the reference's implicit ps-lite bootstrap inside ``import
     mxnet`` when DMLC_PS_ROOT_URI is set."""
@@ -50,7 +39,7 @@ def _maybe_init_distributed():
         return
     import jax
 
-    if _distributed_is_initialized(jax):
+    if jax.distributed.is_initialized():
         return  # an explicit launch.init() beat us
     # rendezvous failures propagate: a silently un-joined worker would
     # leave its peers hanging at their first collective — and a launch

@@ -199,16 +199,16 @@ def _fusion(graph, ctx):
                 and use_counts.get(k, 0) == 1 and node._num_outputs == 1
                 and node._output_index == 0)
 
-    def decide(pattern, members, root, score_shape=None):
+    def decide(pattern, members, root, score_shape=None, act_type=None):
         d = cost_model.decide(pattern, len(members),
                               out_shape=_shape_of(root, shapes),
                               backend=backend, mode=mode,
-                              score_shape=score_shape)
+                              score_shape=score_shape, act_type=act_type)
         if d.fuse:
             kernels._count(f"clusters_{pattern}")
             kernels._count(f"impl_{d.impl}")
             kernels._count("nodes_absorbed", len(members) - 1)
-        else:
+        if d.reason != "ok":
             kernels._count(f"fallback_{d.reason}")
         return d
 
@@ -286,7 +286,8 @@ def _fusion(graph, ctx):
             act_kw = _frozen_kwargs(n)
             if norm_kw is None or act_kw is None:
                 continue
-            d = decide("norm_act", members, n)
+            d = decide("norm_act", members, n,
+                       act_type=n._kwargs.get("act_type"))
             if not d.fuse:
                 continue
             claim(members, k, _fresh_like(n, "_fused_norm_act",

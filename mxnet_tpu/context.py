@@ -12,6 +12,8 @@ import threading
 
 import jax
 
+from .base import MXNetError
+
 __all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus",
            "num_tpus", "gpu_memory_info"]
 
@@ -76,7 +78,16 @@ class Context:
                 devs = jax.devices()
         else:
             devs = [d for d in jax.devices() if d.platform != "cpu"]
-            if not devs:  # CPU-only host (tests): tpu(i) falls back to cpu devices
+            if not devs:
+                # tpu(i) stands in for cpu devices only where the CPU
+                # platform was asked for by name (JAX_PLATFORMS=cpu, as
+                # the tests do) — never as a silent fallback on a
+                # machine that was supposed to have a chip
+                if jax.config.jax_platforms != "cpu":
+                    raise MXNetError(
+                        f"{self}: no accelerator device (jax lists only "
+                        f"{jax.default_backend()!r}); set JAX_PLATFORMS=cpu"
+                        " to run tpu contexts on host devices")
                 devs = jax.devices()
         return devs[self.device_id % len(devs)]
 

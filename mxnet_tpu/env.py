@@ -183,8 +183,10 @@ KNOBS = {
         "process start skips trace+XLA-compile; 0 disables (default 1)"),
     "MXNET_COMPILE_CACHE_DIR": (
         "wired", "utils.compile_cache",
-        "directory for the persistent compile cache (default "
-        "$MXNET_HOME/compile_cache); entries are keyed by op/graph "
+        "directory for the .mxc persistent compile cache (default "
+        "<checkout>/.jax_cache/mxc; jax's own cache follows "
+        "JAX_COMPILATION_CACHE_DIR, default <checkout>/.jax_cache); "
+        "entries are keyed by op/graph "
         "fingerprint + avals + donation + AMP version + "
         "jax/jaxlib/backend/framework versions, corrupt or mismatched "
         "entries are treated as misses and removed"),

@@ -150,10 +150,9 @@ def state_adopt(s):
 
     Restored optimizer states arrive as ``device_put`` uploads (host
     pickle -> ``nd.array``), and the fused step DONATES state buffers.
-    Donating an externally-uploaded buffer is unsafe on jaxlib
-    0.4.37's CPU client: the upload's storage is recycled while
-    earlier computation outputs still occupy it, which surfaces as
-    flaky silent corruption of unrelated live buffers on the steps
+    Donating an externally-uploaded buffer was seen to recycle the
+    upload's storage while earlier computation outputs still occupied
+    it — flaky silent corruption of unrelated live buffers on the steps
     after a ``load_states``/checkpoint restore (caught by the
     resilience bench's bitwise kill-and-resume gate). One ``jnp``
     copy makes every donated buffer an XLA computation output, which

@@ -695,9 +695,8 @@ class Trainer:
 
         # shared walk (fused_step.state_tree_restore): rebuilds the
         # tagged tree AND launders every buffer through state_adopt —
-        # the fused step donates state buffers, and donating raw
-        # device_put uploads corrupts memory on the jaxlib-0.4.37 CPU
-        # client
+        # the fused step donates state buffers, and only computation
+        # outputs (not raw device_put uploads) donate safely
         self._states = [_fs.state_tree_restore(s)
                         for s in payload["states"]]
         self._states_created = True

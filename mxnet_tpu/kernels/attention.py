@@ -30,6 +30,8 @@ worst, so each is ONE registered kernel):
 from __future__ import annotations
 
 from ..ndarray.registry import get_op, register
+from . import _count
+from .cost_model import pallas_fits_vmem
 
 _NEG = -1e30
 
@@ -107,6 +109,12 @@ def _attention_decode(q, k_cache, v_cache, pos, num_heads=1,
     H = int(num_heads)
     D = E // H
     n = jnp.reshape(pos, (B,)).astype(jnp.int32) + 1  # visible length
+    if impl == "pallas" and not pallas_fits_vmem(
+            "attention_decode", (S, D), k_cache.dtype.itemsize):
+        # the kernel holds a whole (S, D) K row and V row per grid
+        # step: past the VMEM bound the chip's compiler refuses it
+        _count("fallback_vmem_bound")
+        impl = "lax"
     if impl in ("pallas", "interpret"):
         from .flash_attention import _decode_flash
 

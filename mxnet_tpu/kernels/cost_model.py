@@ -48,16 +48,70 @@ class Decision:
     reason: str = "ok"
 
 
-def _pallas_viable(pattern, out_shape):
-    """True when the pattern has a TPU kernel AND the output shape meets
-    the tile floor (misaligned shapes pay relayout more than the kernel
-    wins)."""
-    if pattern not in ("norm_act", "attention"):
-        return False
+#: VMEM one Pallas TPU kernel may take: 7/8 of the 16 MiB the TPU
+#: compiler scopes to a kernel by default — hardware geometry. The 1/8
+#: margin is what the estimate below was seen to miss against the v5e
+#: compiler (tests/test_chip_compile.py holds both sides of the line)
+_VMEM_BUDGET_BYTES = 14 << 20  # graft-lint: allow(L1201)
+_BLOCK_ROWS = 128  # graft-lint: allow(L1201)
+
+#: activations whose bodies use a primitive the Pallas TPU lowering
+#: does not implement (expm1, erfc) — the norm_act kernel cannot take
+#: them, the lax replay can
+_PALLAS_UNLOWERABLE_ACTS = frozenset({"elu", "selu", "gelu"})
+
+
+def pallas_vmem_bytes(pattern, shape, itemsize=4):
+    """Upper estimate of the VMEM one grid step of ``pattern``'s TPU
+    kernel holds, from shape and dtype width alone: every operand and
+    result block double-buffered by the pipeline, the fp32 scratch, and
+    one fp32 working copy of the largest block. ``shape`` is the
+    cluster output shape — for ``attention_decode`` the ``(S, D)`` of
+    one cache row, which that kernel streams in whole."""
+    # lanes pad to the tile; graft-lint: allow(L1201)
+    lanes = -(-int(shape[-1]) // _TILE_COLS) * _TILE_COLS
+    if pattern == "norm_act":
+        rows = 1
+        for d in shape[:-1]:
+            rows *= int(d)
+        tile = max(_TILE_ROWS, min(_BLOCK_ROWS, rows)) * lanes
+        blocks, scratch, largest = 2 * tile, 0, tile  # x in, y out
+    elif pattern == "attention":
+        tile = _BLOCK_ROWS * lanes
+        blocks, scratch, largest = 4 * tile, tile * 4, tile  # q k v o
+    elif pattern == "attention_decode":
+        row = int(shape[-2]) * lanes
+        blocks, scratch, largest = 2 * row, 0, row  # K row, V row
+    else:
+        raise ValueError(f"no TPU kernel for pattern {pattern!r}")
+    # x2: double-buffered; x4: fp32 working copy
+    return 2 * blocks * itemsize + scratch + 4 * largest  # graft-lint: allow(L1201)
+
+
+def pallas_fits_vmem(pattern, shape, itemsize=4):
+    """Does one grid step of the pattern's TPU kernel fit the budget?"""
+    return pallas_vmem_bytes(pattern, shape, itemsize) <= _VMEM_BUDGET_BYTES
+
+
+def _pallas_refusal(pattern, out_shape, itemsize=4, act_type=None):
+    """Why the pattern's TPU kernel cannot take this cluster, or None
+    when it is viable: no kernel / unknown shape, output off the tile floor
+    (misaligned shapes pay relayout more than the kernel wins), an
+    absorbed activation the Pallas TPU lowering does not implement, or
+    blocks over the VMEM budget at that shape and dtype width (the
+    kernels hold whole rows — an oversize row would be refused by the
+    chip's compiler at bind time)."""
+    if pattern not in ("norm_act", "attention", "attention_decode"):
+        return "no_kernel"
     if not out_shape or len(out_shape) < 2:
-        return False
-    return (out_shape[-1] % _TILE_COLS == 0
-            and out_shape[-2] % _TILE_ROWS == 0)
+        return "no_shape"
+    if out_shape[-1] % _TILE_COLS or out_shape[-2] % _TILE_ROWS:
+        return "tile_misaligned"
+    if act_type in _PALLAS_UNLOWERABLE_ACTS:
+        return "act_unlowerable"
+    if not pallas_fits_vmem(pattern, out_shape, itemsize):
+        return "vmem_bound"
+    return None
 
 
 #: sequence length at which a lax attention cluster goes compute-bound:
@@ -93,7 +147,7 @@ def _bucket_pow2(n):
 
 
 def decide(pattern, n_nodes, out_shape=None, backend="cpu",
-           mode="heuristic", score_shape=None):
+           mode="heuristic", score_shape=None, act_type=None):
     """Decide one cluster: ``Decision(fuse, impl, reason)``.
 
     ``pattern`` is the cluster kind, ``n_nodes`` the member-op count,
@@ -101,7 +155,9 @@ def decide(pattern, n_nodes, out_shape=None, backend="cpu",
     it (None otherwise), ``backend`` the jax default backend, ``mode``
     the ``MXNET_FUSION_COST_MODEL`` knob. For ``attention`` clusters,
     ``score_shape`` is the (..., seq_q, seq_k) shape of the QK^T score
-    tensor when known.
+    tensor when known; for ``norm_act``, ``act_type`` is the absorbed
+    activation. The graph carries no per-node dtype, so the VMEM bound
+    prices blocks at fp32 width (the widest the kernels take).
 
     Each threshold consults the autotune record store first
     (``MXNET_AUTOTUNE=0`` turns that into a constant-time no-op) and
@@ -109,10 +165,17 @@ def decide(pattern, n_nodes, out_shape=None, backend="cpu",
     """
     if mode == "never":
         return Decision(False, reason="cost_model_never")
-    impl = ("pallas" if backend == "tpu"
-            and _pallas_viable(pattern, out_shape) else "lax")
+    refusal = (_pallas_refusal(pattern, out_shape, act_type=act_type)
+               if backend == "tpu" else "off_tpu")
+    impl = "lax" if refusal else "pallas"
+    # a kernel refused for what only the chip's compiler would have
+    # caught still fuses, priced as lax — the reason rides the decision
+    # so the pass can count it (fallback_vmem_bound, ...)
+    fused = Decision(True, impl=impl,
+                     reason=refusal if refusal in (
+                         "vmem_bound", "act_unlowerable") else "ok")
     if mode == "always":
-        return Decision(True, impl=impl)
+        return fused
     min_cluster = _lookup("fusion.min_cluster", (backend,))
     if min_cluster is None:
         min_cluster = MIN_CLUSTER
@@ -138,4 +201,4 @@ def decide(pattern, n_nodes, out_shape=None, backend="cpu",
             log2_cap = _ELEMENTWISE_BANDWIDTH_LOG2
         if size > (1 << log2_cap):
             return Decision(False, reason="bandwidth_bound")
-    return Decision(True, impl=impl)
+    return fused

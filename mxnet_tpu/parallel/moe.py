@@ -21,9 +21,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from . import _compat
-from ._compat import shard_map as _shard_map
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["moe_ffn", "switch_router", "moe_specs"]
@@ -66,7 +65,7 @@ def switch_router(x, gate_w, n_experts, capacity):
 def _moe_local(x, gate_w, w1, b1, w2, b2, axis_name, capacity, act):
     """Runs INSIDE shard_map: x (Tl, D) local tokens; w1 (El, D, H),
     b1 (El, H), w2 (El, H, D), b2 (El, D) local expert shards."""
-    p = _compat.axis_size(axis_name) if axis_name else 1
+    p = lax.axis_size(axis_name) if axis_name else 1
     n_local = w1.shape[0]
     n_experts = n_local * p
     d_model = x.shape[-1]

@@ -19,9 +19,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from . import _compat
-from ._compat import shard_map as _shard_map
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["pipeline_apply"]
@@ -32,7 +31,7 @@ def _pp_local(stage_params, x, fn, n_micro, axis_name):
     stage dim of size 1 squeezed by the caller's spec); x: the full
     (replicated) batch (B, ...). Returns the pipelined output (B, ...).
     """
-    p = _compat.axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B = x.shape[0]
     assert B % n_micro == 0, "batch must divide microbatches"
@@ -43,7 +42,7 @@ def _pp_local(stage_params, x, fn, n_micro, axis_name):
         try:
             return lax.pcast(v, (axis_name,), to="varying")
         except (AttributeError, TypeError):
-            return _compat.pvary(v, (axis_name,))
+            return lax.pvary(v, (axis_name,))
 
     state0 = _vary(jnp.zeros_like(mbs[0]))
     out0 = _vary(jnp.zeros_like(mbs))

@@ -17,8 +17,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ._compat import shard_map as _shard_map
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 _NEG = -1e30

@@ -20,9 +20,9 @@ from functools import partial
 
 import jax
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ._compat import shard_map as _shard_map
 
 __all__ = ["ulysses_attention"]
 

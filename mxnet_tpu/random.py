@@ -28,12 +28,25 @@ _GLOBAL_SEED = [0]
 
 class _RngState(threading.local):
     def __init__(self):
-        base = jax.random.PRNGKey(_GLOBAL_SEED[0])
-        if threading.current_thread() is not threading.main_thread():
-            base = jax.random.fold_in(base, threading.get_ident()
-                                      & 0x7FFFFFFF)
-        self.key = base
+        # the base key is derived on first use, not here: importing the
+        # package must not initialise a jax backend (a fleet router
+        # parent that did would hold the chip against its replicas)
+        self._key = None
         self.providers = []
+
+    @property
+    def key(self):
+        if self._key is None:
+            base = jax.random.PRNGKey(_GLOBAL_SEED[0])
+            if threading.current_thread() is not threading.main_thread():
+                base = jax.random.fold_in(base, threading.get_ident()
+                                          & 0x7FFFFFFF)
+            self._key = base
+        return self._key
+
+    @key.setter
+    def key(self, value):
+        self._key = value
 
 
 _STATE = _RngState()

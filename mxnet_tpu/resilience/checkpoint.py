@@ -795,7 +795,7 @@ class CheckpointManager:
         # shared walk (fused_step.state_tree_restore): rebuilds the
         # tagged tree with donation-safe (state_adopt'ed) buffers —
         # bitwise resume depends on not donating raw device_put
-        # uploads to the fused step (jaxlib-0.4.37 CPU corruption)
+        # uploads to the fused step
         trainer._states = [_fs.state_tree_restore(s)
                            for s in payload["states"]]
         trainer._states_created = True

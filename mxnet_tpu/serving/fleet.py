@@ -1034,8 +1034,6 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(
 
 _CHILD_BOOT = (
     "import sys; sys.path.insert(0, {root!r})\n"
-    "from _cpu_platform import force_cpu_platform\n"
-    "force_cpu_platform()\n"
     "from mxnet_tpu.serving.fleet import _replica_child\n"
     "_replica_child({factory!r}, {bundle!r})\n")
 
@@ -1085,9 +1083,15 @@ def spawn_replica(factory, bundle=None, env=None, timeout_s=300.0):
     ``warmup()`` (round 20), so combined with a shared
     ``MXNET_COMPILE_CACHE_DIR``/``MXNET_ARTIFACT_REMOTE`` in ``env``
     the join is compile-free. Blocks until the child prints its ready
-    line; returns a :class:`ReplicaProcess`."""
+    line; returns a :class:`ReplicaProcess`.
+
+    The child takes its jax platform from the environment it inherits
+    (plus ``env``): on a chip machine a replica takes the chip, so the
+    router parent must not have initialised a jax backend itself —
+    importing the package and running a :class:`FleetRouter` does not.
+    One chip serves one replica process; assigning replicas to chips
+    of a multi-chip host is the caller's ``env``."""
     child_env = dict(os.environ)
-    child_env.setdefault("JAX_PLATFORMS", "cpu")
     # compiles at dispatch time land in the shared store immediately,
     # so a peer joining later warms from them (round 23 satellite)
     child_env.setdefault("MXNET_DISPATCH_EAGER_PERSIST", "1")

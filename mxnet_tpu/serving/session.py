@@ -1055,10 +1055,10 @@ class InferenceSession:
         occupancy-bucket step executable; returns ``(outputs,
         new_states)`` as jax arrays sliced back to ``n`` rows.
 
-        The state argument is donated into the executable, and on
-        jaxlib-0.4.37 CPU donating a ``device_put``-uploaded buffer
-        corrupts unrelated live arrays (the fused_step ``state_adopt``
-        hazard) — so host-origin states are laundered through
+        The state argument is donated into the executable, and
+        donating a ``device_put``-uploaded buffer was seen to corrupt
+        unrelated live arrays (the fused_step ``state_adopt`` hazard)
+        — so host-origin states are laundered through
         ``jnp.array(..., copy=True)`` after upload, making every
         donated buffer an XLA computation output. ``adopted=True`` is
         the batcher's fast path: the states are ``SessionStateStore.

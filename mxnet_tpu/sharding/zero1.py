@@ -59,9 +59,9 @@ class FusedShardCfg:
 
         Buffers the executable DONATES (states always; params under
         ``donate_params``) are additionally laundered through a
-        device-side copy: donating a raw transfer's buffer is unsafe on
-        jaxlib 0.4.37's CPU client (the round-12 corruption bug), while
-        a computation output donates safely everywhere."""
+        device-side copy: a computation output donates safely
+        everywhere, a raw transfer's buffer was seen not to (the
+        round-12 corruption bug, ``fused_step.state_adopt``)."""
         import jax
         import jax.numpy as jnp
 

@@ -43,9 +43,7 @@ def init():
         return False
     import jax
 
-    from .. import _distributed_is_initialized
-
-    if not _distributed_is_initialized(jax):
+    if not jax.distributed.is_initialized():
         # rendezvous failures propagate — never run un-joined, and never
         # guess the rank (see mxnet_tpu.__init__._maybe_init_distributed)
         nproc = _env.get_str("MXNET_NUM_PROCESSES")

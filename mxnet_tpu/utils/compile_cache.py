@@ -22,8 +22,8 @@ entries are treated as misses (and removed). Entries whose output pytree
 contains live functions (the ``jax.vjp`` pullback of recording-mode
 dispatch entries) cannot serialize — those count as ``serialize_skips``
 and fall back to jax's own persistent compilation cache, which
-``_ensure_jax_fallback_cache`` points at the same directory (XLA-compile
-cost skipped; tracing still paid).
+``_ensure_jax_fallback_cache`` switches on (XLA-compile cost skipped;
+tracing still paid).
 
 **Retrace accounting.** ``counting_jit()`` is the blessed ``jax.jit``
 wrapper (the ``graft_lint`` ``jit-nocache`` rule flags raw call sites):
@@ -43,8 +43,9 @@ outputs after execution (``pad_batch``/``slice_batch``).
 
 Knobs (``env.py``): ``MXNET_COMPILE_CACHE=0`` disables the disk tier,
 ``MXNET_COMPILE_CACHE_DIR`` points it somewhere other than
-``$MXNET_HOME/compile_cache``, ``MXNET_SHAPE_BUCKETS`` enables
-bucketing. Counters surface via ``profiler.compile_cache_counters()``
+``<checkout>/.jax_cache/mxc``, ``JAX_COMPILATION_CACHE_DIR`` places jax's
+own cache (default ``<checkout>/.jax_cache``), ``MXNET_SHAPE_BUCKETS``
+enables bucketing. Counters surface via ``profiler.compile_cache_counters()``
 and the ``COMPILE_CACHE`` runtime feature.
 """
 from __future__ import annotations
@@ -61,8 +62,8 @@ import numpy as onp
 from ..telemetry import metrics as _telemetry
 from ..telemetry import tracer as _telem
 
-__all__ = ["cache_enabled", "cache_dir", "fingerprint", "disk_load",
-           "disk_store", "counting_jit", "note_retrace", "aot_compile",
+__all__ = ["cache_enabled", "cache_dir", "jax_cache_dir", "fingerprint",
+           "disk_load", "disk_store", "counting_jit", "note_retrace", "aot_compile",
            "load_or_compile", "GuardedCompiled", "bucket_spec",
            "bucket_size", "plan_bucketing", "pad_batch", "slice_batch",
            "compile_cache_stats", "reset_compile_cache_counters"]
@@ -116,46 +117,51 @@ def cache_enabled():
     return _env.get_bool("MXNET_COMPILE_CACHE", True)
 
 
+# the one place a default cache lives: a fixed path inside the checkout
+# (git-ignored). The path is part of jax's cache key, so it must not
+# move between runs — never a temp name, a pid or a timestamp.
+_DEFAULT_CACHE_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
 def cache_dir():
-    """MXNET_COMPILE_CACHE_DIR, defaulting to $MXNET_HOME/compile_cache
-    ($MXNET_HOME defaults to ~/.mxnet, like the model store)."""
+    """Directory of the ``.mxc`` tier: MXNET_COMPILE_CACHE_DIR, else the
+    fixed in-checkout default ``<checkout>/.jax_cache/mxc``."""
     from .. import env as _env
 
-    d = _env.get_str("MXNET_COMPILE_CACHE_DIR")
-    if d:
-        return d
-    home = _env.get_str("MXNET_HOME",
-                        os.path.join(os.path.expanduser("~"), ".mxnet"))
-    return os.path.join(home, "compile_cache")
+    return (_env.get_str("MXNET_COMPILE_CACHE_DIR")
+            or os.path.join(_DEFAULT_CACHE_ROOT, "mxc"))
 
 
-_JAX_FALLBACK = {"dir": None}
+def jax_cache_dir():
+    """Directory of jax's own persistent compilation cache: where
+    ``JAX_COMPILATION_CACHE_DIR`` says, else ``<checkout>/.jax_cache``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _DEFAULT_CACHE_ROOT
 
 
-def _ensure_jax_fallback_cache(directory):
-    """Point jax's own persistent compilation cache at our directory
-    (best effort). It keys on the lowered HLO, so it only kicks in
-    after tracing — but that still covers the entries this tier cannot
-    serialize (recording-mode vjp pairs, executor jits): their XLA
-    compile cost is skipped on a warm start even though the trace cost
-    is paid again."""
-    if _JAX_FALLBACK["dir"] == directory:
-        return
-    try:
-        import jax
+@functools.lru_cache(maxsize=None)  # once per process
+def _ensure_jax_fallback_cache():
+    """Switch on jax's own persistent compilation cache. It keys
+    on the lowered HLO, so it only kicks in after tracing — but that
+    still covers the entries the ``.mxc`` tier cannot serialize
+    (recording-mode vjp pairs, executor jits): their XLA compile cost
+    is skipped on a warm start even though the trace cost is paid again.
 
-        jax.config.update("jax_compilation_cache_dir", directory)
-        # only compiles worth the disk round-trip: caching every eager
-        # micro-prim (min_compile_time 0) measurably TAXES the hot path
-        # with serialize+write per prim — the .mxc tier already covers
-        # whole dispatch executables, this tier is for the big traced
-        # programs (CachedOp, executor, recording-entry first hits)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.05)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        _JAX_FALLBACK["dir"] = directory
-    except Exception:
-        _JAX_FALLBACK["dir"] = directory  # don't retry per call
+    Placement is the environment's: with ``JAX_COMPILATION_CACHE_DIR``
+    set jax already reads it and this code sets no directory at all;
+    unset, the cache goes to the fixed in-checkout default."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", _DEFAULT_CACHE_ROOT)
+    # only compiles worth the disk round-trip: caching every eager
+    # micro-prim (min_compile_time 0) measurably TAXES the hot path
+    # with serialize+write per prim — the .mxc tier already covers
+    # whole dispatch executables, this tier is for the big traced
+    # programs (CachedOp, executor, recording-entry first hits)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.05)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 # ---------------------------------------------------------------------------
@@ -289,7 +295,7 @@ def disk_load(fp):
 
 
 def _disk_load_inner(fp):
-    _ensure_jax_fallback_cache(cache_dir())
+    _ensure_jax_fallback_cache()
     path = _entry_path(fp)
     if not os.path.exists(path):
         _bump("disk_misses")
@@ -346,7 +352,7 @@ def disk_store(fp, compiled, meta=None, key_repr=None):
 
 
 def _disk_store_inner(fp, compiled, meta, key_repr):
-    _ensure_jax_fallback_cache(cache_dir())
+    _ensure_jax_fallback_cache()
     try:
         from jax.experimental import serialize_executable as _se
 
@@ -448,7 +454,7 @@ def counting_jit(fun, label=None, **jit_kwargs):
     if cache_enabled():
         # even entries this tier can't serialize (vjp pairs, executor
         # closures) get their XLA-compile cost cached across processes
-        _ensure_jax_fallback_cache(cache_dir())
+        _ensure_jax_fallback_cache()
     name = label or getattr(fun, "__name__", "fn")
 
     @functools.wraps(fun)

@@ -31,56 +31,6 @@ def pytest_configure(config):
         "via -m 'not slow')")
 
 
-# --------------------------------------------------------------------------
-# Environment-gated expected failures.
-#
-# This container pins jax/jaxlib 0.4.37, whose CPU backend rejects
-# cross-process collectives outright ("Multiprocess computations aren't
-# implemented on the CPU backend") — the multi-process launch tests
-# exercise exactly that path, so they cannot pass here regardless of
-# framework correctness. (jax.shard_map itself is shimmed via
-# mxnet_tpu.parallel._compat, which restores the single-process mesh
-# tests; only the true multi-PROCESS runs stay blocked.) The xfail is
-# version-gated: on a jax >= 0.5 container these run — and must pass —
-# again.
-_MULTIPROCESS_CPU_XFAIL = {
-    "test_dist_async_hardening.py",
-    "test_dist_moe_pipeline.py",
-    "test_dist_multiprocess.py",
-    "test_dist_ring_ulysses.py",
-    "test_dist_sharded_ckpt.py",
-}
-
-
-def _jax_cpu_lacks_multiprocess_collectives():
-    import jax
-
-    try:
-        major, minor = (int(x) for x in jax.__version__.split(".")[:2])
-    except ValueError:
-        return False
-    return (major, minor) < (0, 5)
-
-
-def pytest_collection_modifyitems(config, items):
-    if not _jax_cpu_lacks_multiprocess_collectives():
-        return
-    import jax
-
-    reason = (f"jaxlib {jax.__version__} CPU backend does not implement "
-              "multi-process collectives (needs jax >= 0.5); the "
-              "framework path is exercised single-process by "
-              "test_multidevice/test_moe/test_pipeline instead")
-    mark = pytest.mark.xfail(reason=reason, strict=False)
-    for item in items:
-        # only the tests that actually launch multiple processes — the
-        # same files also hold single-process tests that must keep
-        # counting as plain passes
-        if item.fspath.basename in _MULTIPROCESS_CPU_XFAIL and \
-                "process" in item.name:
-            item.add_marker(mark)
-
-
 @pytest.fixture
 def forced_device_subprocess():
     """Run a python snippet in a subprocess with a FORCED virtual
@@ -101,6 +51,12 @@ def forced_device_subprocess():
                 + snippet)
         full_env = dict(os.environ, JAX_PLATFORMS="cpu")
         full_env.update(env or {})
+        # a child handed its own (empty) .mxc directory gets its own
+        # jax cache there too, not this session's: "an empty local
+        # cache" has to be empty in both tiers
+        if env and "MXNET_COMPILE_CACHE_DIR" in env:
+            full_env["JAX_COMPILATION_CACHE_DIR"] = env.get(
+                "JAX_COMPILATION_CACHE_DIR", env["MXNET_COMPILE_CACHE_DIR"])
         out = subprocess.run([sys.executable, "-c", code], cwd=root,
                              env=full_env, capture_output=True,
                              text=True, timeout=timeout)
@@ -141,16 +97,26 @@ def _lock_check_gate():
 
 @pytest.fixture(scope="session", autouse=True)
 def _hermetic_compile_cache(tmp_path_factory):
-    """Point the persistent compile cache at a per-session tmpdir so
+    """Point both persistent compile caches at a per-session tmpdir so
     tier-1 runs are hermetic: no executables leak in from (or out to)
-    $MXNET_HOME/compile_cache across runs, and the suite never depends
-    on what a previous run happened to compile. Tests that need their
-    own isolation monkeypatch MXNET_COMPILE_CACHE_DIR on top."""
-    d = tmp_path_factory.mktemp("compile_cache")
-    prev = os.environ.get("MXNET_COMPILE_CACHE_DIR")
-    os.environ["MXNET_COMPILE_CACHE_DIR"] = str(d)
-    yield str(d)
-    if prev is None:
-        os.environ.pop("MXNET_COMPILE_CACHE_DIR", None)
-    else:
-        os.environ["MXNET_COMPILE_CACHE_DIR"] = prev
+    the checkout's .jax_cache across runs, and the suite never depends
+    on what a previous run happened to compile. The harness places
+    jax's cache the way any outside caller does — through
+    JAX_COMPILATION_CACHE_DIR, which the package then leaves alone (a
+    value already in the environment wins). Tests that need their own
+    isolation monkeypatch MXNET_COMPILE_CACHE_DIR on top."""
+    import jax
+
+    d = str(tmp_path_factory.mktemp("compile_cache"))
+    prev = {k: os.environ.get(k) for k in
+            ("MXNET_COMPILE_CACHE_DIR", "JAX_COMPILATION_CACHE_DIR")}
+    os.environ["MXNET_COMPILE_CACHE_DIR"] = d
+    if prev["JAX_COMPILATION_CACHE_DIR"] is None:
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = d
+        jax.config.update("jax_compilation_cache_dir", d)
+    yield d
+    for k, v in prev.items():
+        if v is None:
+            os.environ.pop(k, None)
+        else:
+            os.environ[k] = v

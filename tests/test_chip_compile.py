@@ -1,0 +1,172 @@
+"""The Pallas TPU kernels, put to the chip's own compiler at real widths.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a chip
+that is *described*, not attached (``jax.experimental.topologies``), so
+what it would refuse at bind time on the chip — a block over VMEM, a
+primitive the Pallas TPU lowering lacks — is caught here at no chip
+time. Nothing runs: these tests say nothing about results or speed
+(``chip_smoke.py kernels`` runs each kernel against its lax twin on the
+chip).
+
+Only one process may hold the TPU library, so the topology is described
+inside a module-scoped fixture of THIS file only (never at import time,
+never in conftest, never autouse), and every compile happens in the
+test's own process with the persistent compilation cache off (a
+described-chip executable can be written to it but not read back).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu import kernels
+from mxnet_tpu.kernels import cost_model
+from mxnet_tpu.kernels.attention import _attention_decode
+from mxnet_tpu.kernels.flash_attention import _decode_flash, _pallas_forward
+from mxnet_tpu.kernels.norm_act import _pallas_norm_act
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes_dtypes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes_dtypes]
+    return jax.jit(fn).lower(*args).compile()  # graft-lint: allow(jit-nocache)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# shapes the chip's compiler takes
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((8, 12, 512, 64), jnp.bfloat16),    # BERT-base heads, seq 512
+    ((8, 12, 512, 64), jnp.float32),
+    ((2, 16, 2048, 128), jnp.bfloat16),  # long-context LM heads
+    ((4, 4, 100, 64), jnp.float32),      # ragged seq: padded to the tile
+])
+def test_flash_forward_compiles(one_chip, shape, dtype):
+    c = _compile(lambda q, k, v: _pallas_forward(q, k, v, 0.125, True,
+                                                 False),
+                 one_chip, (shape, dtype), (shape, dtype), (shape, dtype))
+    _assert_kernel(c)
+    assert cost_model.pallas_fits_vmem("attention", shape[-2:],
+                                       jnp.dtype(dtype).itemsize)
+
+
+@pytest.mark.parametrize("b,h,s,d,dtype", [
+    (8, 12, 1024, 64, jnp.float32),
+    (32, 16, 4096, 128, jnp.float32),
+    (8, 16, 8192, 128, jnp.bfloat16),
+])
+def test_decode_flash_compiles(one_chip, b, h, s, d, dtype):
+    c = _compile(lambda q, k, v, n: _decode_flash(q, k, v, n, 0.125, False),
+                 one_chip, ((b, h, d), dtype), ((b, h, s, d), dtype),
+                 ((b, h, s, d), dtype), ((b,), jnp.int32))
+    _assert_kernel(c)
+    assert cost_model.pallas_fits_vmem("attention_decode", (s, d),
+                                       jnp.dtype(dtype).itemsize)
+
+
+@pytest.mark.parametrize("rows,c,dtype,act", [
+    (4096, 768, jnp.float32, ("relu", ())),   # BERT-base, batch x seq rows
+    (1000, 768, jnp.float32, ("activation", (("act_type", "tanh"),))),
+    (8192, 4096, jnp.bfloat16, ("leaky_relu", (("act_type", "leaky"),))),
+])
+def test_norm_act_compiles(one_chip, rows, c, dtype, act):
+    comp = _compile(
+        lambda x, g, b: _pallas_norm_act(x, g, b, 1e-5, act[0], act[1],
+                                         False),
+        one_chip, ((rows, c), dtype), ((c,), dtype), ((c,), dtype))
+    _assert_kernel(comp)
+    assert cost_model.pallas_fits_vmem("norm_act", (rows, c),
+                                       jnp.dtype(dtype).itemsize)
+
+
+# ---------------------------------------------------------------------------
+# shapes it refuses: the eligibility gate must say so first
+
+def test_decode_oversize_row_refused_by_gate(one_chip):
+    """fp32 S8192 D128: a whole (S, D) K row and V row per grid step,
+    double-buffered, is 16 MiB — the compiler runs out of VMEM. The gate
+    refuses from shape and dtype, and the registered op then takes its
+    lax twin and counts the refusal instead of failing to compile."""
+    b, h, s, d = 8, 16, 8192, 128
+    assert cost_model._pallas_refusal("attention_decode", (s, d), 4) \
+        == "vmem_bound"
+    assert cost_model._pallas_refusal("attention_decode", (s, d), 2) is None
+    with pytest.raises(Exception, match="vmem"):
+        _compile(lambda q, k, v, n: _decode_flash(q, k, v, n, 0.125, False),
+                 one_chip, ((b, h, d), jnp.float32),
+                 ((b, h, s, d), jnp.float32), ((b, h, s, d), jnp.float32),
+                 ((b,), jnp.int32))
+    before = kernels.counters().get("fallback_vmem_bound", 0)
+    c = _compile(
+        lambda q, kc, vc, pos: _attention_decode(
+            q, kc, vc, pos, num_heads=h, sm_scale=0.125, impl="pallas"),
+        one_chip, ((b, h * d), jnp.float32), ((b, s, h * d), jnp.float32),
+        ((b, s, h * d), jnp.float32), ((b, 1), jnp.int32))
+    assert "tpu_custom_call" not in c.as_text()
+    assert kernels.counters()["fallback_vmem_bound"] == before + 1
+
+
+def test_norm_act_oversize_row_refused_by_gate(one_chip):
+    """fp32 8192x8192: the (128, C) row block, in and out and
+    double-buffered, is 16 MiB (no column tiling). The gate refuses, the
+    cluster still fuses, priced as lax, under the reason the pass
+    counts."""
+    shape = (8192, 8192)
+    assert cost_model._pallas_refusal("norm_act", shape, 4) == "vmem_bound"
+    with pytest.raises(Exception, match="vmem"):
+        _compile(lambda x, g, b: _pallas_norm_act(x, g, b, 1e-5, "relu", (),
+                                                  False),
+                 one_chip, (shape, jnp.float32), ((8192,), jnp.float32),
+                 ((8192,), jnp.float32))
+    d = cost_model.decide("norm_act", 2, out_shape=shape, backend="tpu")
+    assert (d.fuse, d.impl, d.reason) == (True, "lax", "vmem_bound")
+    d = cost_model.decide("norm_act", 2, out_shape=(8192, 4096),
+                          backend="tpu")
+    assert (d.fuse, d.impl, d.reason) == (True, "pallas", "ok")
+
+
+@pytest.mark.parametrize("act_type", ["gelu", "elu", "selu"])
+def test_norm_act_unlowerable_activation_refused_by_gate(one_chip,
+                                                         act_type):
+    """erfc (exact GELU) and expm1 (ELU/SELU) have no Pallas TPU
+    lowering: LayerNorm->GELU must price as lax on the chip."""
+    with pytest.raises(Exception, match="Unimplemented primitive"):
+        _compile(
+            lambda x, g, b: _pallas_norm_act(
+                x, g, b, 1e-5, "leaky_relu", (("act_type", act_type),),
+                False),
+            one_chip, ((256, 512), jnp.float32), ((512,), jnp.float32),
+            ((512,), jnp.float32))
+    d = cost_model.decide("norm_act", 2, out_shape=(256, 512),
+                          backend="tpu", act_type=act_type)
+    assert (d.fuse, d.impl, d.reason) == (True, "lax", "act_unlowerable")

@@ -55,24 +55,47 @@ def _mxc_files():
 def test_tier1_cache_dir_is_hermetic():
     """The session conftest pins MXNET_COMPILE_CACHE_DIR into pytest's
     tmpdir (this test's fixture narrows it further): nothing the suite
-    compiles may land in — or be served from — $MXNET_HOME."""
+    compiles may land in — or be served from — the checkout's default
+    cache."""
     d = cc.cache_dir()
-    home_cache = os.path.join(
-        os.environ.get("MXNET_HOME",
-                       os.path.join(os.path.expanduser("~"), ".mxnet")),
-        "compile_cache")
-    assert d != home_cache
-    assert "compile_cache" not in os.path.commonprefix([d, home_cache]) \
-        or not d.startswith(home_cache)
-    before = set(os.listdir(home_cache)) if os.path.isdir(home_cache) \
-        else set()
+    default = cc._DEFAULT_CACHE_ROOT
+    assert not os.path.abspath(d).startswith(default)
+    assert not os.path.abspath(cc.jax_cache_dir()).startswith(default)
+    mxc = os.path.join(default, "mxc")
+    before = set(os.listdir(mxc)) if os.path.isdir(mxc) else set()
     x = nd.ones((3, 5))
     nd.tanh(x)
     nd.tanh(x)  # first hit: AOT compile + disk write
     assert _mxc_files(), "executable was not persisted into the tmpdir"
-    after = set(os.listdir(home_cache)) if os.path.isdir(home_cache) \
-        else set()
-    assert after == before, "suite leaked cache entries into $MXNET_HOME"
+    after = set(os.listdir(mxc)) if os.path.isdir(mxc) else set()
+    assert after == before, "suite leaked cache entries into the checkout"
+
+
+@pytest.mark.parametrize("placed", [True, False],
+                         ids=["env_set", "env_unset"])
+def test_jax_cache_placement_rule(placed, forced_device_subprocess,
+                                  tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, importing and using the
+    package leaves jax's cache directory at that value (the package
+    sets no other); unset, it is the one fixed in-checkout path — and
+    the .mxc tier's default is fixed inside the checkout too."""
+    outside = str(tmp_path / "placed_from_outside")
+    out = forced_device_subprocess(
+        "import json, os, jax\n"
+        "os.environ.pop('MXNET_COMPILE_CACHE_DIR', None)\n"
+        "import mxnet_tpu as mx\n"
+        "from mxnet_tpu.utils import compile_cache as cc\n"
+        "f = cc.counting_jit(lambda a: a + 1)\n"
+        "mx.nd.tanh(mx.nd.ones((2, 3))).wait_to_read()\n"
+        "print(json.dumps({'jax': jax.config.jax_compilation_cache_dir,\n"
+        "                  'fn': cc.jax_cache_dir(), 'mxc': cc.cache_dir(),\n"
+        "                  'root': cc._DEFAULT_CACHE_ROOT}))\n",
+        env={"JAX_COMPILATION_CACHE_DIR": outside if placed else ""})
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert out["root"] == os.path.join(repo, ".jax_cache")
+    assert out["mxc"] == os.path.join(repo, ".jax_cache", "mxc")
+    want = outside if placed else out["root"]
+    assert out["jax"] == want and out["fn"] == want, out
 
 
 # ---------------------------------------------------------------------------

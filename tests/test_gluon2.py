@@ -359,6 +359,24 @@ def test_register_op_hook_nested_hybrid_and_order():
     assert len(names2) == n2  # fully detached, cache path restored
 
 
+def test_tpu_context_needs_cpu_asked_for_by_name():
+    """tpu(i) stands in for a CPU device only because the suite asked
+    for JAX_PLATFORMS=cpu by name; without that request and without an
+    accelerator it raises instead of silently falling back."""
+    import jax
+
+    from mxnet_tpu.base import MXNetError
+
+    assert mx.tpu(0).jax_device.platform == "cpu"
+    jax.config.update("jax_platforms", "")
+    try:
+        with pytest.raises(MXNetError, match="no accelerator"):
+            mx.tpu(0).jax_device
+        assert mx.cpu(0).jax_device.platform == "cpu"
+    finally:
+        jax.config.update("jax_platforms", "cpu")
+
+
 def test_parameter_reset_ctx():
     import numpy as onp
 

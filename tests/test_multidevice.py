@@ -294,7 +294,7 @@ def test_spmd_param_sync_back_to_gluon():
 
 def test_sync_batch_norm_stats_match_global_batch():
     """pmean-reduced statistics == stats of the full (unsharded) batch."""
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
     from mxnet_tpu.gluon.contrib import nn as contrib_nn
 
