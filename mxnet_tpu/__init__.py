@@ -15,6 +15,10 @@ Conventional import: ``import mxnet_tpu as mx``.
 """
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_T0 = _time.monotonic()  # the "mxnet_tpu.import" span starts here
+
 __version__ = "0.1.0"
 
 
@@ -97,6 +101,8 @@ from . import parallel
 from . import pipeline  # noqa: F401
 from . import resilience  # noqa: F401
 from . import utils  # noqa: F401
+from . import telemetry  # noqa: F401
+from .utils import compile_cache as _compile_cache
 from . import engine  # noqa: F401
 from . import libinfo  # noqa: F401
 from . import misc  # noqa: F401
@@ -155,3 +161,10 @@ if env.get_bool("MXNET_PROFILER_AUTOSTART"):
     profiler.set_config(aggregate_stats=True)
     profiler.start()
 env.check()
+# telemetry: JAX's compile durations become compile.* spans, and the
+# import itself is the first span of the flight recorder (it began
+# before the tracer's epoch, so its ts is negative)
+_compile_cache.install_compile_listener()
+if telemetry.tracing():
+    telemetry.emit_span("mxnet_tpu.import", "setup", _IMPORT_T0,
+                        _time.monotonic())

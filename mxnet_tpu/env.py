@@ -48,13 +48,16 @@ KNOBS = {
         "wired", "profiler", "start profiling at import when 1"),
     "MXNET_TELEMETRY": (
         "wired", "telemetry.tracer",
-        "span tracing detail: 0 off (default; cost is one env read "
-        "per site), 1 structural spans (fused step, serving "
-        "lifecycle, pipeline, checkpoint, cache IO), 2 adds "
-        "high-frequency spans (per-op dispatch, per-pass graph opt)"),
+        "span tracing detail: 0 off (cost is one env read per "
+        "site), 1 structural spans (default, also when unset: "
+        "SPMDTrainer build and step, compile, parameter init, fused "
+        "step, serving lifecycle, pipeline, checkpoint, cache IO), 2 "
+        "adds high-frequency spans (per-op dispatch, per-pass graph "
+        "opt)"),
     "MXNET_TELEMETRY_BUFFER": (
         "wired", "telemetry.tracer",
-        "span ring-buffer capacity (default 65536 events); on "
+        "span ring-buffer capacity (default 8192 events, about 5 MB "
+        "when full); on "
         "overflow the oldest events drop and dropped_spans counts "
         "them"),
     "MXNET_ENFORCE_DETERMINISM": (

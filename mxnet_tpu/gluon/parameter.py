@@ -20,6 +20,7 @@ from .. import ndarray as nd
 from ..ndarray import NDArray
 from .. import initializer
 from ..context import current_context
+from ..telemetry import tracer as _telem
 
 __all__ = ["DeferredInitializationError", "Parameter", "Constant",
            "ParameterDict", "tensor_types"]
@@ -111,27 +112,33 @@ class Parameter:
             return
         self._finish_init(init, ctx, default_init)
 
-    def _finish_init(self, init, ctx, default_init):
-        arr = nd.zeros(self._shape, ctx=ctx if not isinstance(ctx, list) else
-                       ctx[0], dtype=self.dtype)
-        actual = init if init is not None else (self.init if self.init
-                                                is not None else default_init)
-        if isinstance(actual, str):
-            actual = initializer.create(actual)
-        actual(initializer.InitDesc(self.name), arr)
-        self._ndarray = arr
-        self._deferred_init = None
-        if self._grad_req != "null":
-            self._attach_grad()
+    def _finish_init(self, init, ctx, default_init, discarded=False):
+        """Allocate and run the initializer. ``discarded`` marks the
+        span of a run whose result ``set_data`` overwrites at once."""
+        with _telem.span("gluon.param_init", cat="setup", param=self.name,
+                         discarded=discarded) as sp:
+            arr = nd.zeros(self._shape,
+                           ctx=ctx if not isinstance(ctx, list) else ctx[0],
+                           dtype=self.dtype)
+            actual = init if init is not None else (
+                self.init if self.init is not None else default_init)
+            if isinstance(actual, str):
+                actual = initializer.create(actual)
+            actual(initializer.InitDesc(self.name), arr)
+            self._ndarray = arr
+            self._deferred_init = None
+            if self._grad_req != "null":
+                self._attach_grad()
+            sp.set(bytes=int(arr.data.nbytes))
 
-    def _finish_deferred_init(self, inferred_shape=None):
+    def _finish_deferred_init(self, inferred_shape=None, discarded=False):
         if inferred_shape is not None:
             self.shape = inferred_shape
         if self._deferred_init is None:
             raise DeferredInitializationError(
                 f"Parameter {self.name} has not been initialized")
         init, ctx, default_init = self._deferred_init
-        self._finish_init(init, ctx, default_init)
+        self._finish_init(init, ctx, default_init, discarded=discarded)
 
     def _attach_grad(self):
         from .. import autograd
@@ -198,7 +205,8 @@ class Parameter:
         self.shape = data.shape
         if self._ndarray is None:
             if self._deferred_init is not None and self._shape_complete():
-                self._finish_deferred_init()
+                # the initializer runs only to be overwritten below
+                self._finish_deferred_init(discarded=True)
             else:
                 raise RuntimeError(
                     f"Parameter {self.name} has not been initialized")

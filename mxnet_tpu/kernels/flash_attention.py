@@ -129,6 +129,7 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret):
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",  # the HLO instruction's name on a device trace
     )(qr, kr, vr)
     out = out.reshape(B, H, Sq_p, D)
     return out[:, :, :S_q] if pq else out
@@ -187,6 +188,7 @@ def _decode_flash(q, k, v, lengths, sm_scale, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, 1, D), q.dtype),
         interpret=interpret,
+        name="flash_decode",
     )(lens, qr, kr, vr)
     return out.reshape(B, H, D)
 
@@ -238,8 +240,9 @@ def _flash_bwd(sm_scale, causal, impl, res, do):
         dk_acc += jnp.einsum("bhqk,bhqd->bhkd", ds, qb) * sm_scale
         return (dk_acc, dv_acc, ci + 1), dqb
 
-    (dk, dv, _), dqs = lax.scan(
-        step, (jnp.zeros_like(kf), jnp.zeros_like(vf), 0), (qc, doc))
+    with jax.named_scope("flash_bwd"):
+        (dk, dv, _), dqs = lax.scan(
+            step, (jnp.zeros_like(kf), jnp.zeros_like(vf), 0), (qc, doc))
     dq = dqs.transpose(1, 2, 0, 3, 4).reshape(B, H, S_q + pad, D)[
         :, :, :S_q]
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
@@ -269,4 +272,5 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, use_pallas=None):
         impl = "pallas" if jax.default_backend() == "tpu" else "interpret"
     else:
         impl = "xla"
-    return _flash(q, k, v, float(sm_scale), bool(causal), impl)
+    with jax.named_scope("attn"):
+        return _flash(q, k, v, float(sm_scale), bool(causal), impl)

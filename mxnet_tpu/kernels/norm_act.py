@@ -81,6 +81,7 @@ def _pallas_norm_act(data, gamma, beta, eps, act_op, act_kw, interpret):
         out_specs=pl.BlockSpec((br, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows + pr, c), data.dtype),
         interpret=interpret,
+        name="norm_act",
     )(x2, gamma.reshape(1, c), beta.reshape(1, c))
     if pr:
         out = out[:rows]

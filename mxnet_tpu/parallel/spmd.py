@@ -23,10 +23,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..base import MXNetError
 from .. import ndarray as nd
 from ..utils import compile_cache as _cc
+from ..telemetry import metrics as _metrics
+from ..telemetry import tracer as _telem
 from ..ndarray import NDArray
 from .. import autograd
 from .. import random as mxrandom
 from .mesh import make_mesh
+
+# counted at the span sites below, so only while MXNET_TELEMETRY >= 1
+_COUNTERS = _metrics.counter_family(
+    "spmd", {"steps": 0, "builds": 0, "placed_bytes": 0})
 
 __all__ = ["all_reduce", "all_reduce_coalesced", "group_all_reduce",
            "shard_batch", "replicate", "shard_params", "SPMDTrainer"]
@@ -327,6 +333,12 @@ class SPMDTrainer:
     def _ensure_built(self, x, y):
         if self._compiled is not None:
             return
+        with _telem.span("spmd.build", cat="train"):
+            self._build(x)
+        if _telem.tracing():
+            _COUNTERS.add("builds")
+
+    def _build(self, x):
         net, loss = self._net, self._loss
         # Finish deferred init eagerly on a ONE-sample batch — only shapes
         # matter here — with the host CPU backend as jax's default device
@@ -341,7 +353,8 @@ class SPMDTrainer:
             pass
         init_ctx = (jax.default_device(cpu) if cpu is not None
                     else contextlib.nullcontext())
-        with init_ctx, autograd.pause(train_mode=True):
+        with _telem.span("spmd.build.init_forward", cat="train"), \
+                init_ctx, autograd.pause(train_mode=True):
             xs = x
             if getattr(x, "shape", None) and x.shape:
                 # fresh 1-sample batch, created INSIDE that scope: the
@@ -389,8 +402,11 @@ class SPMDTrainer:
                     if cdtype is not None and \
                             jnp.issubdtype(xin.dtype, jnp.floating):
                         xin = xin.astype(cdtype)
+                    # "fwd" reaches the ops' metadata as jvp(fwd) and,
+                    # for the backward, transpose(jvp(fwd))
                     with autograd.pause(train_mode=True), \
-                            mxrandom.key_provider(fwd_key):
+                            mxrandom.key_provider(fwd_key), \
+                            jax.named_scope("fwd"):
                         out = net.forward(NDArray(xin))
                         if cdtype is not None:
                             out = NDArray(out.data.astype(jnp.float32))
@@ -406,29 +422,35 @@ class SPMDTrainer:
             (lval, mut), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(param_vals)
             new_params, new_states = [], []
-            for i, (w, g, s) in enumerate(zip(param_vals, grads, states)):
-                if not trainable[i]:
-                    # mutated aux state (BN running stats) back to the
-                    # master dtype
-                    w2 = mut.get(str(i), w)
-                    new_params.append(w2.astype(w.dtype))
-                    new_states.append(s)
-                else:
-                    w2, s2 = update(w, g, s, t)
-                    new_params.append(w2)
-                    new_states.append(s2)
+            with jax.named_scope("update"):
+                for i, (w, g, s) in enumerate(
+                        zip(param_vals, grads, states)):
+                    if not trainable[i]:
+                        # mutated aux state (BN running stats) back to
+                        # the master dtype
+                        w2 = mut.get(str(i), w)
+                        new_params.append(w2.astype(w.dtype))
+                        new_states.append(s)
+                    else:
+                        w2, s2 = update(w, g, s, t)
+                        new_params.append(w2)
+                        new_states.append(s2)
             return lval, new_params, new_states, (key, t)
 
-        self._states = [
-            jax.tree_util.tree_map(
-                lambda z, s=s: jax.device_put(z, s),
-                self._init_state(p._ndarray.data))
-            if trainable[i] else None
-            for i, (p, s) in enumerate(zip(self._params, self._pshard))]
+        with _telem.span("spmd.build.place", cat="train",
+                         params=len(self._params)) as sp:
+            self._states = [
+                jax.tree_util.tree_map(
+                    lambda z, s=s: jax.device_put(z, s),
+                    self._init_state(p._ndarray.data))
+                if trainable[i] else None
+                for i, (p, s) in enumerate(zip(self._params, self._pshard))]
+            self._param_vals = [jax.device_put(p._ndarray.data, s)
+                                for p, s in zip(self._params, self._pshard)]
+            sp.set(bytes=sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(
+                (self._param_vals, self._states))))
         state_shards = [jax.tree_util.tree_map(lambda _, ps=ps: ps, st)
                         for st, ps in zip(self._states, self._pshard)]
-        self._param_vals = [jax.device_put(p._ndarray.data, s)
-                            for p, s in zip(self._params, self._pshard)]
         self._t = 0  # display-only mirror; the authoritative counter is
         # the on-device aux[1], incremented inside the compiled step
         key0 = mxrandom.next_key()
@@ -450,12 +472,29 @@ class SPMDTrainer:
     def step(self, x, y):
         """Run one sharded training step; returns the (replicated) loss."""
         self._ensure_built(x, y)
-        xd = shard_batch(x, self._mesh, self._axis).data
-        yd = shard_batch(y, self._mesh, self._axis).data
+        if not _telem.tracing():  # MXNET_TELEMETRY=0: this one check
+            return NDArray(self._launch(*self._place(x, y)))
+        with _telem.span("spmd.step", cat="train", step=self._t + 1):
+            with _telem.span("spmd.step.place", cat="train") as sp:
+                xd, yd = self._place(x, y)
+                nbytes = int(xd.nbytes) + int(yd.nbytes)
+                sp.set(bytes=nbytes)
+            # the enqueue only: the call returns before the device is done
+            with _telem.span("spmd.step.launch", cat="train"):
+                lval = self._launch(xd, yd)
+        _COUNTERS.add("steps")
+        _COUNTERS.add("placed_bytes", nbytes)
+        return NDArray(lval)
+
+    def _place(self, x, y):
+        return (shard_batch(x, self._mesh, self._axis).data,
+                shard_batch(y, self._mesh, self._axis).data)
+
+    def _launch(self, xd, yd):
         self._t += 1
         lval, self._param_vals, self._states, self._aux = self._compiled(
             self._param_vals, self._states, self._aux, xd, yd)
-        return NDArray(lval)
+        return lval
 
     def param_arrays(self):
         """``{name: jax.Array}`` — the device-resident parameter values
@@ -469,8 +508,7 @@ class SPMDTrainer:
         """Optimised HLO text of the step executable for this batch:
         where to look for the collectives XLA inserted over the mesh."""
         self._ensure_built(x, y)
-        xd = shard_batch(x, self._mesh, self._axis).data
-        yd = shard_batch(y, self._mesh, self._axis).data
+        xd, yd = self._place(x, y)
         return self._compiled.lower(
             self._param_vals, self._states, self._aux, xd,
             yd).compile().as_text()

@@ -94,6 +94,7 @@ class DeviceFeed:
         self._t_first = None     # first-next timestamp of this pass
         self._served = 0         # batches delivered this pass (cursor)
         self._skip_base = 0      # batches skip()'d before this pass
+        self._staged_bytes = 0   # bytes the batch being staged has placed
         _count_set("prefetch_depth", self._depth)
 
     # -- staging ------------------------------------------------------------
@@ -108,10 +109,11 @@ class DeviceFeed:
         # consumer's next() exactly like a real device_put failure.
         _faults.maybe_fail("device_put")
         if isinstance(x, NDArray):
-            return NDArray(jax.device_put(x.data, self._device))
-        if isinstance(x, (onp.ndarray, jax.Array)):
-            return NDArray(jax.device_put(x, self._device))
-        return x
+            x = x.data
+        elif not isinstance(x, (onp.ndarray, jax.Array)):
+            return x
+        self._staged_bytes += x.nbytes
+        return NDArray(jax.device_put(x, self._device))
 
     def _stage(self, item):
         """Map ``_stage_leaf`` over the batch structure (DataBatch /
@@ -153,10 +155,13 @@ class DeviceFeed:
                     return
                 # its own lane in the trace: staging runs on the
                 # device-feed thread, parallel to the consumer's step
-                # spans — the round-11 overlap, visible
+                # spans — the round-11 overlap, visible. It times the
+                # enqueue of device_put, not the transfer's end.
                 with _telem.span("pipeline.prefetch_stage",
-                                 cat="pipeline"):
+                                 cat="pipeline") as sp:
+                    self._staged_bytes = 0
                     staged = self._stage(batch)
+                    sp.set(bytes=self._staged_bytes)
                 if not self._put(ep, staged):
                     return
         except BaseException as e:  # noqa: BLE001 — re-raised in consumer

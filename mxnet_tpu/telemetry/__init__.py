@@ -12,7 +12,8 @@ registry.
 Three pieces, importable à la carte:
 
 - :mod:`.tracer` — ``span()`` / ``instant()`` / ``trace_context()``,
-  ``MXNET_TELEMETRY={0,1,2}``-gated, bounded drop-oldest ring.
+  ``MXNET_TELEMETRY={0,1,2}``-gated (default 1), bounded drop-oldest
+  ring, anchored to the wall clock (``epoch_unix_ns``).
 - :mod:`.metrics` — :class:`MetricsRegistry` (:data:`REGISTRY`):
   owned :class:`CounterFamily` dicts + probed families + ONE
   Prometheus exposition for training and serving.
@@ -29,7 +30,8 @@ See ``docs/TELEMETRY.md``.
 from __future__ import annotations
 
 from .tracer import (TELEMETRY_KNOB, buffer_capacity, current_trace_id,
-                     dropped_spans, emit_span, events, instant, level,
+                     dropped_spans, emit_span, epoch_unix_ns, events,
+                     instant, level,
                      new_trace_id, reset as reset_trace, span,
                      thread_names, trace_context, tracing)
 from .metrics import (REGISTRY, CounterFamily, MetricsRegistry,
@@ -42,7 +44,7 @@ __all__ = [
     "TELEMETRY_KNOB", "level", "tracing", "span", "instant",
     "emit_span", "trace_context", "current_trace_id", "new_trace_id",
     "events", "reset_trace", "dropped_spans", "buffer_capacity",
-    "thread_names",
+    "thread_names", "epoch_unix_ns",
     # metrics
     "REGISTRY", "MetricsRegistry", "CounterFamily", "counter_family",
     "register_family", "register_exposition", "family_snapshot",

@@ -18,7 +18,9 @@ One assembly point for everything the process knows about time:
 The output is the Trace Event Format JSON array-of-dicts that
 chrome://tracing and https://ui.perfetto.dev load directly:
 ``{"traceEvents": [{"name", "cat", "ph", "ts", "dur", "pid", "tid",
-"args"}, ...], "displayTimeUnit": "ms"}``.
+"args"}, ...], "displayTimeUnit": "ms", "otherData": {"epoch_unix_ns"}}``:
+``ts`` is µs since the tracer epoch, and ``otherData.epoch_unix_ns`` is
+that epoch on the wall clock.
 """
 from __future__ import annotations
 
@@ -75,11 +77,14 @@ def build_trace(extra_events=None, counters=True):
         events.extend(extra_events)
     if counters:
         events.extend(counter_samples())
-    payload = {"traceEvents": events, "displayTimeUnit": "ms"}
+    # the clock anchor: ts 0 is this unix time, so the dump can be laid
+    # over a device trace (XProf's profile_start_time is on that clock)
+    other = {"epoch_unix_ns": tracer.epoch_unix_ns()}
     dropped = tracer.dropped_spans()
     if dropped:
-        payload["otherData"] = {"dropped_spans": dropped}
-    return payload
+        other["dropped_spans"] = dropped
+    return {"traceEvents": events, "displayTimeUnit": "ms",
+            "otherData": other}
 
 
 def dump_trace(path, extra_events=None, counters=True):

@@ -10,9 +10,9 @@ exportable to Perfetto.
 
 Design constraints, in order:
 
-1. **Zero-cost disabled.** ``MXNET_TELEMETRY=0`` (the default) must add
-   nothing measurable to the eager-dispatch and fused-step hot loops:
-   one env-dict lookup and an integer compare, no allocation, no lock.
+1. **Zero-cost disabled.** ``MXNET_TELEMETRY=0`` must add nothing
+   measurable to the eager-dispatch and training-step hot loops: one
+   env-dict lookup and an integer compare, no allocation, no lock.
    ``span(...)`` returns a shared no-op context manager.
 2. **Never block the hot path.** The buffer is a bounded
    ``deque(maxlen=...)`` ring: appends are O(1), GIL-atomic, and when
@@ -26,15 +26,21 @@ Design constraints, in order:
    propagated via :func:`trace_context` or an explicit ``trace_id=``
    argument), which every span stamps into its args.
 
-Levels (``MXNET_TELEMETRY``): ``0`` off; ``1`` structural spans (step,
-batch, request lifecycle, checkpoint, disk IO — a handful per step /
-request); ``2`` adds high-frequency detail (per-op eager dispatch,
-per-rewrite-pass spans). Levels gate at span creation, so a level-2
-call site costs only the env read when the level is 1.
+Levels (``MXNET_TELEMETRY``): ``0`` off; ``1`` (the default, also when
+the variable is unset) structural spans (build, step, compile, batch,
+request lifecycle, checkpoint, disk IO — a handful per step / request):
+the flight recorder is on unless switched off; ``2`` adds
+high-frequency detail (per-op eager dispatch, per-rewrite-pass spans).
+Levels gate at span creation, so a level-2 call site costs only the env
+read when the level is 1.
 
 Clock: ``time.monotonic()`` everywhere (one clock across every thread;
 serving deadline math already lives on it — graft_lint L602).
 Timestamps are exported in microseconds relative to the tracer epoch.
+The epoch is anchored to the wall clock once, at import
+(:func:`epoch_unix_ns`): an event lies at ``epoch_unix_ns() + ts * 1000``
+unix nanoseconds, the clock a device trace's ``profile_start_time`` is
+on, so a dump can be laid over an XProf trace.
 """
 from __future__ import annotations
 
@@ -47,22 +53,28 @@ from collections import deque
 __all__ = ["TELEMETRY_KNOB", "level", "tracing", "span", "instant",
            "emit_span", "trace_context", "current_trace_id",
            "new_trace_id", "events", "reset", "dropped_spans",
-           "buffer_capacity", "thread_names"]
+           "buffer_capacity", "thread_names", "epoch_unix_ns",
+           "current_span_id"]
 
 TELEMETRY_KNOB = "MXNET_TELEMETRY"
 _BUFFER_KNOB = "MXNET_TELEMETRY_BUFFER"
-_DEFAULT_CAPACITY = 65536
+# A full ring is what a server left running for weeks keeps resident:
+# 8192 events of 0.5-0.6 KB each (tracemalloc; docs/TELEMETRY.md), under
+# 5 MB. A training run's set-up plus a minute of steps is a few thousand
+# events.
+_DEFAULT_CAPACITY = 8192
 
 
 def level():
-    """``MXNET_TELEMETRY`` as an int (0 off / 1 structural / 2 verbose).
-    Read per call — the hot-path cost of the disabled tracer IS this
-    read, one dict lookup — so tests and benchmarks toggle it without
-    reimport. Not routed through ``env.get_int`` on purpose: that
-    helper logs on garbage, and this runs on every dispatch."""
+    """``MXNET_TELEMETRY`` as an int (0 off / 1 structural / 2 verbose;
+    unset reads 1). Read per call — the hot-path cost of the disabled
+    tracer IS this read, one dict lookup — so tests and benchmarks
+    toggle it without reimport. Not routed through ``env.get_int`` on
+    purpose: that helper logs on garbage, and this runs on every
+    dispatch."""
     v = os.environ.get(TELEMETRY_KNOB)  # graft-lint: allow(L101)
     if not v:
-        return 0
+        return 1
     try:
         return int(v)
     except ValueError:
@@ -100,8 +112,22 @@ def _capacity():
     return max(16, cap)
 
 
-#: tracer epoch: every exported ts is monotonic-µs since this instant
-_EPOCH = time.monotonic()
+def _anchor(tries=5):
+    """(monotonic seconds, unix ns) of one instant: the wall clock read
+    between two monotonic reads, the tightest of a few tries kept."""
+    best = None
+    for _ in range(tries):
+        a = time.monotonic()
+        wall = time.time_ns()
+        b = time.monotonic()
+        if best is None or b - a < best[0]:
+            best = (b - a, (a + b) / 2.0, wall)
+    return best[1], best[2]
+
+
+#: tracer epoch: every exported ts is monotonic-µs since this instant,
+#: which the wall clock read as _EPOCH_UNIX_NS
+_EPOCH, _EPOCH_UNIX_NS = _anchor()
 _RING = _Ring(_capacity())
 _SPAN_IDS = itertools.count(1)  # next() is GIL-atomic
 _THREADS = {}  # tid -> thread name, for exporter "M" metadata events
@@ -139,6 +165,19 @@ def thread_names():
 
 def _stack():
     return _TLS.stack
+
+
+def current_span_id():
+    """Id of the calling thread's innermost open span, or None."""
+    stack = _TLS.stack
+    return stack[-1] if stack else None
+
+
+def epoch_unix_ns():
+    """Unix nanoseconds of the tracer epoch: an event's wall-clock start
+    is ``epoch_unix_ns() + ts * 1000`` (``ts`` in µs), its duration stays
+    a monotonic difference."""
+    return _EPOCH_UNIX_NS
 
 
 # -- trace-id propagation ---------------------------------------------------
@@ -193,8 +232,10 @@ def emit_span(name, cat, t0, t1, trace_id=None, parent=None, **attrs):
     endpoints — for durations measured before the tracer gets involved
     (a request's queue wait runs from ``t_submit``, stamped in
     ``submit()``, to batch formation in a worker thread). Honors the
-    ambient trace context when ``trace_id`` is not given. No level
-    check: the caller gates (it usually already knows)."""
+    ambient trace context when ``trace_id`` is not given; ``parent`` is
+    the caller's to give (:func:`current_span_id` where the duration
+    lies inside the thread's open span). No level check: the caller
+    gates (it usually already knows)."""
     args = attrs
     tid = trace_id if trace_id is not None else current_trace_id()
     if tid is not None:

@@ -29,7 +29,15 @@ tracing still paid).
 wrapper (the ``graft_lint`` ``jit-nocache`` rule flags raw call sites):
 it drops a host-side counter tick into the traced body, so *actual*
 traces — not calls — are counted, framework-wide. Shape-bucketing wins
-and warm-start wins both show up as a flat ``retraces`` counter.
+and warm-start wins both show up as a flat ``retraces`` counter, and
+each trace leaves a ``retrace`` instant with the program's label.
+
+**Compile spans.** ``install_compile_listener()`` listens to the
+durations JAX publishes through ``jax.monitoring`` (trace to jaxpr,
+lowering, backend compile or cache load, each with ``fun_name``) and
+records them as ``compile.trace`` / ``compile.lower`` /
+``compile.backend`` spans and as the ``trace_s`` / ``lower_s`` /
+``backend_compile_s`` / ``programs`` counters.
 
 **Shape bucketing.** ``plan_bucketing()`` rounds the batch axis of
 eligible op dispatches up to a bucket boundary (``MXNET_SHAPE_BUCKETS``:
@@ -55,6 +63,7 @@ import hashlib
 import os
 import pickle
 import threading
+import time
 import warnings
 
 import numpy as onp
@@ -63,7 +72,8 @@ from ..telemetry import metrics as _telemetry
 from ..telemetry import tracer as _telem
 
 __all__ = ["cache_enabled", "cache_dir", "jax_cache_dir", "fingerprint",
-           "disk_load", "disk_store", "counting_jit", "note_retrace", "aot_compile",
+           "disk_load", "disk_store", "counting_jit", "note_retrace",
+           "install_compile_listener", "aot_compile",
            "load_or_compile", "GuardedCompiled", "bucket_spec",
            "bucket_size", "plan_bucketing", "pad_batch", "slice_batch",
            "compile_cache_stats", "reset_compile_cache_counters"]
@@ -75,7 +85,10 @@ def _zero_stats():
     return {"disk_hits": 0, "disk_misses": 0, "disk_writes": 0,
             "disk_corrupt": 0, "disk_evicted": 0, "prunes": 0,
             "serialize_skips": 0, "retraces": 0,
-            "bucketed_calls": 0, "padded_rows": 0, "true_rows": 0}
+            "bucketed_calls": 0, "padded_rows": 0, "true_rows": 0,
+            # from jax.monitoring, while MXNET_TELEMETRY >= 1
+            "trace_s": 0.0, "lower_s": 0.0, "backend_compile_s": 0.0,
+            "programs": 0}
 
 
 # registry-owned since round 18; the registered "compile_cache" probe
@@ -438,9 +451,89 @@ def _maybe_prune(directory):
 
 def note_retrace(label=None):
     """Count one actual trace (called from inside traced bodies, so it
-    fires at trace time only — cached executions never reach it)."""
-    del label  # per-label breakdown can ride later without API change
+    fires at trace time only — cached executions never reach it) and
+    leave a ``retrace`` instant naming the program that was traced."""
     _bump("retraces")
+    _telem.instant("retrace", cat="compile", label=label)
+
+
+# what JAX publishes about a compile -> (span, counter of its seconds)
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("compile.trace", "trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("compile.lower", "lower_s"),
+    "/jax/core/compile/backend_compile_duration":
+        ("compile.backend", "backend_compile_s"),
+}
+# published, on a hit only, just before the backend duration of the same
+# program on the same thread
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+# A shorter trace is counted but leaves no span. JAX publishes one for
+# every jitted jnp function an eager op or a trace goes through: 7,353 in
+# one run of the benchmark's OPT cell, of which the 77 of a millisecond
+# or more covered 97.5% of the traced time (my chip run, PR 28).
+_MIN_TRACE_SPAN_S = 1e-3
+
+
+class _CompileThread(threading.local):
+    """Per-thread state of the listener."""
+
+    def __init__(self):
+        self.cache_hit = False  # the persistent cache served the
+        # program whose backend duration comes next
+        self.traces = []        # (start, end) of counted traces, disjoint,
+        # in time order
+
+
+_COMPILING = _CompileThread()
+
+
+def _on_jax_duration(event, duration, **attrs):
+    """``jax.monitoring`` duration listener: one span per published
+    compile phase, ending now and lasting what JAX reports. A trace's
+    duration holds those of the jitted functions traced inside it, each
+    published before it: the span keeps the whole, the counter adds only
+    what no earlier trace of this thread covered."""
+    if not _telem.tracing():
+        return
+    st = _COMPILING
+    if event == _CACHE_RETRIEVAL:
+        st.cache_hit = True
+        return
+    spec = _COMPILE_EVENTS.get(event)
+    if spec is None:
+        return
+    name, counter = spec
+    t1 = time.monotonic()
+    t0 = t1 - duration
+    own = duration
+    args = {"fun_name": attrs.get("fun_name")}
+    if name == "compile.trace":
+        inner = st.traces
+        while inner and inner[-1][1] > t0:
+            a, b = inner.pop()
+            own -= b - a
+        inner.append((t0, t1))
+        del inner[:-1024]
+    elif name == "compile.backend":
+        args["cache_hit"] = st.cache_hit
+        st.cache_hit = False
+        _bump("programs")
+    _bump(counter, max(0.0, own))
+    if name == "compile.trace" and duration < _MIN_TRACE_SPAN_S:
+        return
+    _telem.emit_span(name, "compile", t0, t1,
+                     parent=_telem.current_span_id(), **args)
+
+
+@functools.lru_cache(maxsize=None)  # once per process
+def install_compile_listener():
+    """Register :func:`_on_jax_duration` with ``jax.monitoring`` (the
+    package does at import). With ``MXNET_TELEMETRY=0`` the listener
+    returns after its level check."""
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 def counting_jit(fun, label=None, **jit_kwargs):
@@ -461,6 +554,10 @@ def counting_jit(fun, label=None, **jit_kwargs):
     def counted(*args, **kwargs):
         note_retrace(name)
         return fun(*args, **kwargs)
+
+    # jax names the program after the function: jit_<label> on a trace's
+    # "XLA Modules" line and fun_name in the compile spans
+    counted.__name__ = counted.__qualname__ = name
 
     return jax.jit(counted, **jit_kwargs)  # graft-lint: allow(jit-nocache)
 

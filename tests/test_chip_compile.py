@@ -15,6 +15,7 @@ test's own process with the persistent compilation cache off (a
 described-chip executable can be written to it but not read back).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -58,8 +59,13 @@ def _compile(fn, one_chip, *shapes_dtypes):
     return jax.jit(fn).lower(*args).compile()  # graft-lint: allow(jit-nocache)
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(compiled, name):
+    """The kernel is there, under the instruction name a device trace
+    shows it by (``benchmarks/metrics/readers/op_time.py`` matches it)."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%" + name + r"(\.\d+)? = [^\n]*custom-call\(", text), \
+        [ln for ln in text.splitlines() if "custom-call(" in ln]
 
 
 # ---------------------------------------------------------------------------
@@ -75,7 +81,7 @@ def test_flash_forward_compiles(one_chip, shape, dtype):
     c = _compile(lambda q, k, v: _pallas_forward(q, k, v, 0.125, True,
                                                  False),
                  one_chip, (shape, dtype), (shape, dtype), (shape, dtype))
-    _assert_kernel(c)
+    _assert_kernel(c, "flash_fwd")
     assert cost_model.pallas_fits_vmem("attention", shape[-2:],
                                        jnp.dtype(dtype).itemsize)
 
@@ -89,7 +95,7 @@ def test_decode_flash_compiles(one_chip, b, h, s, d, dtype):
     c = _compile(lambda q, k, v, n: _decode_flash(q, k, v, n, 0.125, False),
                  one_chip, ((b, h, d), dtype), ((b, h, s, d), dtype),
                  ((b, h, s, d), dtype), ((b,), jnp.int32))
-    _assert_kernel(c)
+    _assert_kernel(c, "flash_decode")
     assert cost_model.pallas_fits_vmem("attention_decode", (s, d),
                                        jnp.dtype(dtype).itemsize)
 
@@ -104,7 +110,7 @@ def test_norm_act_compiles(one_chip, rows, c, dtype, act):
         lambda x, g, b: _pallas_norm_act(x, g, b, 1e-5, act[0], act[1],
                                          False),
         one_chip, ((rows, c), dtype), ((c,), dtype), ((c,), dtype))
-    _assert_kernel(comp)
+    _assert_kernel(comp, "norm_act")
     assert cost_model.pallas_fits_vmem("norm_act", (rows, c),
                                        jnp.dtype(dtype).itemsize)
 

@@ -6,9 +6,13 @@ threads, ring wraparound (drop-oldest + ``dropped_spans``),
 Chrome-trace JSON schema, trace-id propagation end-to-end through the
 DynamicBatcher, the unified Prometheus exposition (training families
 scrapeable next to the serving block), and the ``MXNET_TELEMETRY=0``
-zero-emission guarantee."""
+zero-emission guarantee; then the training path's own spans
+(``SPMDTrainer``, compile, parameter init), recorded by default and on
+the wall clock's anchor."""
 import json
+import re
 import threading
+import time
 
 import numpy as onp
 import pytest
@@ -98,8 +102,8 @@ def test_ring_wraparound_drops_oldest(monkeypatch):
     assert [e["name"] for e in evs] == [f"ev{i}" for i in range(4, 12)]
     assert telemetry.dropped_spans() == 4
     # the drop count rides the export payload
-    assert telemetry.build_trace(counters=False)["otherData"] == \
-        {"dropped_spans": 4}
+    assert telemetry.build_trace(counters=False)["otherData"][
+        "dropped_spans"] == 4
 
 
 # ---------------------------------------------------------------------------
@@ -213,3 +217,210 @@ def test_disabled_level_emits_nothing(monkeypatch):
     assert telemetry.current_trace_id() is None
     assert telemetry.events() == []
     assert telemetry.dropped_spans() == 0
+
+
+# ---------------------------------------------------------------------------
+# the training path measured from inside: recorded by default, anchored
+# to the wall clock, nothing at level 0
+
+def _spmd_run(steps=3, seed=3):
+    """A fresh trainer through ``steps`` steps; (trainer, x, y, losses
+    as raw float32 bits)."""
+    import jax
+
+    from mxnet_tpu import gluon, parallel
+
+    mx.random.seed(seed)
+    net = nn.HybridSequential()
+    net.add(nn.Dense(16, activation="relu"), nn.Dense(4))
+    net.initialize()
+    trainer = parallel.SPMDTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), optimizer="adamw",
+        optimizer_params={"learning_rate": 0.01},
+        mesh=parallel.make_mesh({"dp": 1}, devices=jax.devices()[:1]))
+    rng = onp.random.default_rng(seed)
+    x = nd.array(rng.standard_normal((8, 10)).astype("float32"))
+    y = nd.array(rng.integers(0, 4, (8,)).astype("float32"))
+    losses = [trainer.step(x, y).asnumpy().tobytes() for _ in range(steps)]
+    return trainer, x, y, losses
+
+
+def _named(name):
+    return [e for e in telemetry.events() if e["name"] == name]
+
+
+def test_unix_anchor_puts_a_span_on_the_wall_clock(monkeypatch):
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    with telemetry.span("anchored", cat="test"):
+        wall = time.time_ns()
+    (ev,) = _named("anchored")
+    start = telemetry.epoch_unix_ns() + ev["ts"] * 1000
+    assert abs(start - wall) < 5e6, (start - wall) / 1e6
+    # the exporter carries the anchor, so a dump can be laid over a
+    # device trace
+    assert telemetry.build_trace(counters=False)["otherData"][
+        "epoch_unix_ns"] == telemetry.epoch_unix_ns()
+
+
+def test_structural_spans_are_recorded_with_the_environment_unset(
+        monkeypatch):
+    monkeypatch.delenv("MXNET_TELEMETRY", raising=False)
+    assert telemetry.level() == 1 and telemetry.tracing()
+    assert not telemetry.tracing(2)
+    _spmd_run()
+    assert len(_named("spmd.step")) == 3
+    # a full default ring stays small: under 0.6 KB an event
+    # (docs/TELEMETRY.md)
+    assert telemetry.buffer_capacity() <= 8192
+
+
+def test_level_zero_records_nothing_from_a_build_and_three_steps(
+        monkeypatch):
+    monkeypatch.setenv("MXNET_TELEMETRY", "0")
+    before = telemetry.family_snapshot("spmd")
+    compiles = telemetry.family_snapshot("compile_cache")["programs"]
+    _spmd_run()
+    assert telemetry.events() == []
+    assert telemetry.family_snapshot("spmd") == before
+    assert telemetry.family_snapshot("compile_cache")["programs"] == compiles
+
+
+def test_spmd_step_nests_place_and_launch_and_build_appears_once(
+        monkeypatch):
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    before = telemetry.family_snapshot("spmd")
+    _spmd_run()
+    (build,) = _named("spmd.build")
+    (init_fwd,) = _named("spmd.build.init_forward")
+    (placed,) = _named("spmd.build.place")
+    assert init_fwd["args"]["parent"] == build["args"]["span_id"]
+    assert placed["args"]["parent"] == build["args"]["span_id"]
+    assert placed["args"]["params"] == 4 and placed["args"]["bytes"] > 0
+    # deferred parameters finish inside the init forward
+    inits = _named("gluon.param_init")
+    weights = [e for e in inits if e["args"]["param"].endswith("_weight")]
+    assert len(inits) == 4 and len(weights) == 2
+    assert all(e["args"]["parent"] == init_fwd["args"]["span_id"]
+               for e in weights)
+    assert all(e["args"]["bytes"] > 0 and e["args"]["discarded"] is False
+               for e in inits)
+    steps = _named("spmd.step")
+    assert [e["args"]["step"] for e in steps] == [1, 2, 3]
+    for step, place, launch in zip(steps, _named("spmd.step.place"),
+                                   _named("spmd.step.launch")):
+        sid = step["args"]["span_id"]
+        assert place["args"]["parent"] == launch["args"]["parent"] == sid
+        assert place["args"]["bytes"] == 8 * 10 * 4 + 8 * 4
+        assert step["ts"] <= place["ts"] <= launch["ts"]
+        assert launch["ts"] + launch["dur"] <= step["ts"] + step["dur"] + 1
+    after = telemetry.family_snapshot("spmd")
+    assert after["steps"] - before["steps"] == 3
+    assert after["builds"] - before["builds"] == 1
+    assert after["placed_bytes"] - before["placed_bytes"] == 3 * 352
+    assert "mxnet_spmd_steps" in telemetry.prometheus_text()
+
+
+def test_set_data_on_a_deferred_parameter_marks_the_init_discarded(
+        monkeypatch):
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    layer = nn.Dense(4)          # in_units unknown: deferred
+    layer.initialize()
+    layer.weight.set_data(nd.ones((4, 6)))
+    (ev,) = [e for e in _named("gluon.param_init")
+             if e["args"]["param"].endswith("weight")]
+    assert ev["args"]["discarded"] is True
+    assert ev["args"]["bytes"] == 4 * 6 * 4
+
+
+def test_first_step_yields_compile_spans_and_a_fourth_step_none(
+        monkeypatch):
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    stats0 = telemetry.family_snapshot("compile_cache")
+    trainer, x, y, _ = _spmd_run(steps=3)
+    (launch,) = [e for e in _named("spmd.step.launch")][:1]
+    for phase in ("compile.trace", "compile.lower", "compile.backend"):
+        mine = [e for e in _named(phase)
+                if "spmd_step" in (e["args"]["fun_name"] or "")]
+        assert len(mine) == 1, (phase, mine)
+        # compiled inside the first step's launch, and says so
+        assert mine[0]["args"]["parent"] == launch["args"]["span_id"]
+    (backend,) = [e for e in _named("compile.backend")
+                  if "spmd_step" in e["args"]["fun_name"]]
+    assert backend["args"]["cache_hit"] in (True, False)
+    stats1 = telemetry.family_snapshot("compile_cache")
+    assert stats1["programs"] > stats0["programs"]
+    for key in ("trace_s", "lower_s", "backend_compile_s"):
+        assert stats1[key] > stats0[key]
+
+    telemetry.reset_trace()
+    trainer.step(x, y)
+    assert len(_named("spmd.step")) == 1
+    assert not [e for e in telemetry.events()
+                if e["name"].startswith("compile.")
+                or e["name"] == "retrace"]
+
+
+def test_nested_traces_count_once_and_short_ones_leave_no_span(monkeypatch):
+    """What JAX publishes, replayed: a trace of 50 ms that holds one of
+    4 ms and one of 0.2 ms, each published before it."""
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    from mxnet_tpu.utils import compile_cache as cc
+
+    event = "/jax/core/compile/jaxpr_trace_duration"
+    before = telemetry.family_snapshot("compile_cache")["trace_s"]
+    time.sleep(0.06)
+    cc._on_jax_duration(event, 0.004, fun_name="inner")
+    time.sleep(0.001)
+    cc._on_jax_duration(event, 0.0002, fun_name="eager_add")
+    time.sleep(0.001)
+    cc._on_jax_duration(event, 0.05, fun_name="outer")
+    cc._on_jax_duration("/jax/some/other/duration", 9.0)
+    after = telemetry.family_snapshot("compile_cache")["trace_s"]
+    assert after - before == pytest.approx(0.05, abs=1e-6)
+    spans = [(e["args"]["fun_name"], round(e["dur"]))
+             for e in _named("compile.trace")]
+    assert spans == [("inner", 4000), ("outer", 50000)]
+
+
+def test_forced_retrace_leaves_an_instant_with_its_label(monkeypatch):
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    from mxnet_tpu.utils import compile_cache as cc
+
+    fn = cc.counting_jit(lambda a: a * 2, label="telemetry_probe")
+    fn(onp.ones((3,), "float32"))
+    fn(onp.ones((3,), "float32"))          # cached: no trace
+    fn(onp.ones((5,), "float32"))          # new shape: a retrace
+    got = [e for e in _named("retrace")
+           if e["args"]["label"] == "telemetry_probe"]
+    assert len(got) == 2 and all(e["ph"] == "i" for e in got)
+    named = [e for e in _named("compile.backend")
+             if "telemetry_probe" in e["args"]["fun_name"]]
+    assert len(named) == 2
+
+
+def test_losses_are_bitwise_equal_with_tracing_on_and_off(monkeypatch):
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    on = _spmd_run()[3]
+    monkeypatch.setenv("MXNET_TELEMETRY", "0")
+    off = _spmd_run()[3]
+    assert on == off and len(set(on)) == 3
+
+
+def test_step_hlo_carries_forward_backward_and_update_scopes():
+    trainer, x, y, _ = _spmd_run(steps=1)
+    names = set(re.findall(r'op_name="([^"]+)"', trainer.step_hlo(x, y)))
+    assert any("jit(spmd_step)/jvp(fwd)/" in n for n in names)
+    assert any("jit(spmd_step)/transpose(jvp(fwd))/" in n for n in names)
+    assert any("jit(spmd_step)/update/" in n for n in names)
+
+
+def test_prefetch_stage_span_carries_the_bytes_it_placed(monkeypatch):
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    from mxnet_tpu.pipeline import DeviceFeed
+
+    batch = (onp.zeros((4, 3), "float32"), onp.zeros((4,), "int32"))
+    feed = DeviceFeed(iter([batch, batch]), depth=1)
+    assert len(list(feed)) == 2
+    feed.close()
+    staged = _named("pipeline.prefetch_stage")
+    assert [e["args"]["bytes"] for e in staged] == [64, 64]
