@@ -67,7 +67,9 @@ def pallas_vmem_bytes(pattern, shape, itemsize=4):
     result block double-buffered by the pipeline, the fp32 scratch, and
     one fp32 working copy of the largest block. ``shape`` is the
     cluster output shape — for ``attention_decode`` the ``(S, D)`` of
-    one cache row, which that kernel streams in whole."""
+    one cache row, which that kernel streams in whole; for
+    ``attention`` the ``(S, D)`` of one head, priced at the tiles the
+    flash kernels' own chooser gives its two passes."""
     # lanes pad to the tile; graft-lint: allow(L1201)
     lanes = -(-int(shape[-1]) // _TILE_COLS) * _TILE_COLS
     if pattern == "norm_act":
@@ -77,8 +79,11 @@ def pallas_vmem_bytes(pattern, shape, itemsize=4):
         tile = max(_TILE_ROWS, min(_BLOCK_ROWS, rows)) * lanes
         blocks, scratch, largest = 2 * tile, 0, tile  # x in, y out
     elif pattern == "attention":
-        tile = _BLOCK_ROWS * lanes
-        blocks, scratch, largest = 4 * tile, tile * 4, tile  # q k v o
+        # the kernel's own chooser and footprints, forward and backward
+        # (imported here: flash_attention imports Pallas)
+        from .flash_attention import vmem_bytes
+        return vmem_bytes(int(shape[-2]), int(shape[-2]), int(shape[-1]),
+                          itemsize)
     elif pattern == "attention_decode":
         row = int(shape[-2]) * lanes
         blocks, scratch, largest = 2 * row, 0, row  # K row, V row

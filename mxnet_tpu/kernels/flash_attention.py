@@ -1,17 +1,39 @@
-"""Flash attention: blockwise online-softmax attention as a Pallas kernel.
+"""Flash attention: blockwise online-softmax attention as Pallas kernels.
 
 NEW capability beyond the reference (MXNet 1.5 has no attention op —
 SURVEY §5.7: long-context handling is a first-class requirement of the TPU
-rebuild, not a port). Design:
+rebuild, not a port). The S_q x S_k scores never leave VMEM, in either
+pass, so attention memory is O(S·D) and HBM carries q, k, v, o and their
+gradients once. Design:
 
-- forward: Pallas TPU kernel, grid (B*H, S_q/bq). Each program holds its
-  q tile in VMEM and streams k/v tiles, keeping running (max, sumexp,
-  acc) — attention memory is O(S·D) instead of O(S²), and the two matmuls
-  per tile run back-to-back on the MXU from VMEM.
-- backward: jax.custom_vjp with an XLA recompute of the tile softmax (the
-  standard flash trade: no S² residuals saved; FLOPs are recomputed).
-- off-TPU (tests, CPU) the same kernel runs under interpret=True, or the
-  pure-XLA reference path via flash_attention(..., use_pallas=False).
+- tiles: ``choose_tiles`` sizes (bq, bk) from (S_q, S_k, D, itemsize)
+  and the VMEM budget ``cost_model`` states — multiples of 128 that
+  divide the sequence padded to 128, as large as the pass's cap and the
+  budget allow, so a grid step carries enough work to hide its overhead.
+  ``cost_model.pallas_vmem_bytes("attention", ...)`` prices the same
+  tiles, so the gate and the kernel cannot disagree.
+- both kernels take (B, H, S, D) as it is, one head a grid row: no
+  (B*H, S, D) array exists in the program.
+- forward ``flash_fwd``: grid (B, H, S_q/bq, S_k/bk), k innermost. A q
+  tile stays in VMEM while k/v tiles stream past it; running (max, sum,
+  acc) are float32 scratch; both products take MXU operands in the
+  input's dtype with float32 accumulation. Causal tiles wholly above the
+  diagonal are skipped and their k/v index maps clamp to the last live
+  tile, so they cost no copy. Under differentiation the kernel also
+  writes the row log-sum-exp (float32, lane-dense (B, H, 1, S_q)).
+- backward ``flash_bwd``: ONE fused kernel, grid (B, H, S_k/bk, S_q/bq),
+  q innermost, on the transposed tile s^T = k q^T (bk, bq) so the row
+  statistics (lse, delta = rowsum(do * o)) broadcast along sublanes.
+  p^T = exp(s^T - lse) is recomputed, ds^T = p^T (dp^T - delta) stays
+  float32 in VMEM; dk and dv tiles are float32 scratch resident over the
+  q sweep; the (S_q, D) float32 dq of one head is the resident output
+  block of the whole sweep. A shape whose dq does not fit the budget
+  takes the ``lax.scan`` backward and counts ``flash_bwd_scan`` in
+  ``kernels.counters()`` (the kernel counts ``flash_bwd_pallas``).
+- off-TPU (tests, CPU) the same kernels run under interpret=True, or the
+  pure-XLA path via flash_attention(..., use_pallas=False): plain
+  attention forward, q-chunk recompute scan backward — the oracle the
+  kernels are tested against.
 """
 from __future__ import annotations
 
@@ -23,11 +45,21 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from . import _count
+from .cost_model import _TILE_COLS, _VMEM_BUDGET_BYTES
+
 _NEG = -1e30
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+
+#: largest (bq, bk) each pass starts from, the fastest of those measured
+#: on the chip at (2, 32, 2048, 64) bf16 (PERF.md section 6, PR 29); the
+#: budget and the sequence only ever shrink them
+_FWD_CAPS = (1024, 1024)
+_BWD_CAPS = (512, 512)
 
 
 def _ref_attention(q, k, v, sm_scale, causal, s_k_real):
-    """Plain XLA attention, the correctness oracle + backward recompute.
+    """Plain XLA attention, the correctness oracle.
 
     Causal masking is bottom-right aligned: query row i sits at global
     position i + (S_k - S_q), so decode-style calls (S_q=1 against a long
@@ -45,15 +77,114 @@ def _ref_attention(q, k, v, sm_scale, causal, s_k_real):
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *, bq, bk, nk,
-               sm_scale, causal, s_k_real, causal_off):
-    """Grid (BH, nq, nk), kb innermost: one (bq, bk) tile per step. Only a
-    q tile, one k/v tile and the (m, l, acc) scratch live in VMEM — true
+# ---------------------------------------------------------------------------
+# tiles
+
+def _pad128(n):
+    return -(-int(n) // _TILE_COLS) * _TILE_COLS
+
+
+def _tile_under(padded, cap):
+    """Largest multiple of 128 that divides ``padded`` and is <= cap."""
+    n = padded // _TILE_COLS
+    return _TILE_COLS * max(d for d in range(1, n + 1)
+                            if n % d == 0 and d * _TILE_COLS <= cap)
+
+
+def tile_vmem_bytes(bq, bk, s_q, d, itemsize, backward=False):
+    """Upper estimate of the VMEM one grid step holds at tiles (bq, bk):
+    every operand and result block double-buffered by the pipeline (the
+    head dimension padded to the 128 lanes), the float32 scratch, and the
+    float32 (bq, bk) working tiles with their operand-dtype casts. The
+    v5e compiler takes about twice the tile this admits and refuses four
+    times (tests/test_chip_compile.py holds both sides)."""
+    lanes = _pad128(d)
+    row = lanes * itemsize
+    if backward:
+        # q, do, k, v in; dk, dv out; lse, delta rows; dq of a whole head
+        blocks = 2 * bq * row + 4 * bk * row + 2 * 8 * bq * 4 \
+            + _pad128(s_q) * lanes * 4
+        scratch = 2 * bk * lanes * 4
+        work = bq * bk * (3 * 4 + 2 * itemsize)  # s/p, dp/ds, ds.T + casts
+    else:
+        blocks = 2 * bq * row + 2 * bk * row + 8 * bq * 4  # q o, k v, lse
+        scratch = 2 * bq * _TILE_COLS * 4 + bq * lanes * 4  # m l, acc
+        work = bq * bk * (2 * 4 + itemsize)  # s, p + cast
+    return 2 * blocks + scratch + work
+
+
+def choose_tiles(s_q, s_k, d, itemsize, backward=False,
+                 budget=_VMEM_BUDGET_BYTES):
+    """(bq, bk) for one pass of the kernel, or None when not even a
+    128 x 128 tile fits ``budget``: a pure function of the shape. Tiles
+    are multiples of 128 dividing the sequence padded to 128, as large as
+    the pass's cap and the budget allow."""
+    sq_p, sk_p = _pad128(s_q), _pad128(s_k)
+    cq, ck = _BWD_CAPS if backward else _FWD_CAPS
+    while True:
+        bq, bk = _tile_under(sq_p, cq), _tile_under(sk_p, ck)
+        if tile_vmem_bytes(bq, bk, s_q, d, itemsize,
+                           backward) <= budget:
+            return bq, bk
+        if bq == bk == _TILE_COLS:
+            return None
+        if bq >= bk:  # shrink the larger side first
+            cq = bq - _TILE_COLS
+        else:
+            ck = bk - _TILE_COLS
+
+
+def vmem_bytes(s_q, s_k, d, itemsize):
+    """What the gate prices: the larger of the two passes' footprints at
+    the tiles the chooser gives them — at the 128 floor where nothing
+    fits, so that the answer is over the budget then."""
+    floor = (_TILE_COLS, _TILE_COLS)
+    return max(
+        tile_vmem_bytes(*(choose_tiles(s_q, s_k, d, itemsize, bwd)
+                          or floor), s_q, d, itemsize, bwd)
+        for bwd in (False, True))
+
+
+def _pad_rows(x, n):
+    return jnp.pad(x, ((0, 0), (0, 0), (0, n), (0, 0))) if n else x
+
+
+def _tile_mask(shape, q0, k0, q_axis, causal, s_k_real):
+    """Which scores of the tile whose first query sits at global causal
+    position ``q0`` and first key at ``k0`` count: real keys, on or under
+    the (bottom-right aligned) diagonal. Queries run along ``q_axis``."""
+    kid = k0 + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    mask = kid < s_k_real
+    if causal:
+        mask &= kid <= q0 + lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    return mask
+
+
+def _tile_kinds(i, kb, bq, bk, causal, s_k_real, causal_off):
+    """(live, masked) of tile (i, kb): live unless wholly above the
+    diagonal; masked when the diagonal or the keys' padding crosses it —
+    a tile under both pays no iota, compare and select."""
+    masked = (kb + 1) * bk > s_k_real
+    if not causal:
+        return True, masked
+    live = kb * bk <= (i + 1) * bq - 1 + causal_off
+    return live, masked | ((kb + 1) * bk - 1 > i * bq + causal_off)
+
+
+# ---------------------------------------------------------------------------
+# forward
+
+def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, bq, bk, nk, sm_scale,
+               causal, s_k_real, causal_off):
+    """Grid (B, H, nq, nk), kb innermost: one (bq, bk) tile per step. Only
+    a q tile, one k/v tile and the (m, l, acc) scratch live in VMEM — true
     streaming, O(bq·D + bk·D) on-chip whatever the sequence length. The
     scratch carries the online softmax across the kb sweep (TPU grid steps
-    run sequentially, scratch persists)."""
-    i = pl.program_id(1)
-    kb = pl.program_id(2)
+    run sequentially, scratch persists). ``rest`` is (m, l, acc), led by
+    the lse output block when the backward will want it."""
+    *lse_out, m_s, l_s, acc_s = rest
+    i = pl.program_id(2)
+    kb = pl.program_id(3)
 
     @pl.when(kb == 0)
     def _init():
@@ -61,23 +192,13 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *, bq, bk, nk,
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    # causal: tiles entirely above the diagonal contribute nothing — skip
-    # both MXU matmuls (halves causal-LM FLOPs)
-    live = (kb * bk <= (i + 1) * bq - 1 + causal_off) if causal else True
-
-    @pl.when(live)
-    def _tile():
-        q = q_ref[0].astype(jnp.float32)  # (bq, D)
-        k = k_ref[0].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-        kid = kb * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = kid < s_k_real
-        if causal:
-            qid = i * bq + causal_off + \
-                lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            mask &= kid <= qid
-        s = jnp.where(mask, s, _NEG)
+    def _tile(masked):
+        v = v_ref[:]
+        s = lax.dot_general(q_ref[:], k_ref[:], _NT,
+                            preferred_element_type=jnp.float32) * sm_scale
+        if masked:
+            s = jnp.where(_tile_mask((bq, bk), i * bq + causal_off, kb * bk,
+                                     0, causal, s_k_real), s, _NEG)
         m = m_s[:]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -85,55 +206,213 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *, bq, bk, nk,
         m_s[:] = m_new
         l_s[:] = l_s[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_s[:] = acc_s[:] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    live, masked = _tile_kinds(i, kb, bq, bk, causal, s_k_real, causal_off)
+    pl.when(live & masked)(functools.partial(_tile, True))
+    pl.when(live & ~masked)(functools.partial(_tile, False))
 
     @pl.when(kb == nk - 1)
     def _finalize():
-        o_ref[0] = (acc_s[:] / jnp.maximum(l_s[:], 1e-30)).astype(
-            o_ref.dtype)
+        l = jnp.maximum(l_s[:], 1e-30)
+        o_ref[:] = (acc_s[:] / l).astype(o_ref.dtype)
+        if lse_out:
+            # the TPU's log is good to 2e-5 of its value (1e-4 at l = 600,
+            # my chip run, PR 29), which a float32 backward shows: one
+            # Newton step on exp(y) = l makes exp(s - lse) sum to 1 under
+            # the exp the backward recomputes p with (5e-6 there)
+            log_l = jnp.log(l)
+            log_l += l * jnp.exp(-log_l) - 1.0
+            # (bq, 1) column to the lane-dense (1, bq) row the backward
+            # broadcasts along sublanes
+            lse = jnp.broadcast_to(m_s[:] + log_l, (bq, _TILE_COLS))
+            lse_out[0][:] = lse.T[:1]
 
 
-def _pallas_forward(q, k, v, sm_scale, causal, interpret):
+def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
+                    bq=None, bk=None):
+    """The forward kernel at the chooser's tiles (``bq``/``bk`` override
+    them for tests). Returns o, or (o, lse) with lse float32 of shape
+    (B, H, 1, S_q padded to bq) when ``with_lse``."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S_q, D = q.shape
     S_k = k.shape[2]
-    bq = min(128, S_q)
-    bk = min(128, S_k)
+    if bq is None or bk is None:
+        bq, bk = choose_tiles(S_q, S_k, D, q.dtype.itemsize)
     pq = (-S_q) % bq
     pk = (-S_k) % bk
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, pq), (0, 0))) if pq else q
-    kp = jnp.pad(k, ((0, 0), (0, 0), (0, pk), (0, 0))) if pk else k
-    vp = jnp.pad(v, ((0, 0), (0, 0), (0, pk), (0, 0))) if pk else v
     Sq_p, Sk_p = S_q + pq, S_k + pk
-    qr = qp.reshape(B * H, Sq_p, D)
-    kr = kp.reshape(B * H, Sk_p, D)
-    vr = vp.reshape(B * H, Sk_p, D)
     nk = Sk_p // bk
+    off = S_k - S_q
     kern = functools.partial(_fa_kernel, bq=bq, bk=bk, nk=nk,
                              sm_scale=sm_scale, causal=causal,
-                             s_k_real=S_k, causal_off=S_k - S_q)
+                             s_k_real=S_k, causal_off=off)
+
+    def kv_map(b, h, i, kb):
+        # a tile above the diagonal is skipped: name the last live one
+        # again and the pipeline copies nothing
+        if causal:
+            kb = jnp.minimum(kb, ((i + 1) * bq - 1 + off) // bk)
+        return b, h, kb, 0
+
+    # one head a grid row, its block's two leading dimensions squeezed:
+    # the kernel sees (bq, D), (bk, D) and the (1, bq) row of lse
+    q_spec = pl.BlockSpec((None, None, bq, D),
+                          lambda b, h, i, kb: (b, h, i, 0))
+    kv_spec = pl.BlockSpec((None, None, bk, D), kv_map)
+    out_specs = [q_spec]
+    out_shape = [jax.ShapeDtypeStruct((B, H, Sq_p, D), q.dtype)]
+    if with_lse:
+        out_specs.append(pl.BlockSpec((None, None, 1, bq),
+                                      lambda b, h, i, kb: (b, h, 0, i)))
+        out_shape.append(jax.ShapeDtypeStruct((B, H, 1, Sq_p), jnp.float32))
     out = pl.pallas_call(
         kern,
-        grid=(B * H, Sq_p // bq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, kb: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, kb: (b, kb, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, kb: (b, kb, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, kb: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq_p, D), q.dtype),
+        grid=(B, H, Sq_p // bq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=out_specs if with_lse else out_specs[0],
+        out_shape=out_shape if with_lse else out_shape[0],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="flash_fwd",  # the HLO instruction's name on a device trace
-    )(qr, kr, vr)
-    out = out.reshape(B, H, Sq_p, D)
-    return out[:, :, :S_q] if pq else out
+    )(_pad_rows(q, pq), _pad_rows(k, pk), _pad_rows(v, pk))
+    if with_lse:
+        return out[0][:, :, :S_q], out[1]
+    return out[:, :, :S_q]
 
+
+# ---------------------------------------------------------------------------
+# backward
+
+def _fa_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   dk_ref, dv_ref, dk_s, dv_s, *, bq, bk, nq, sm_scale,
+                   causal, s_k_real, causal_off):
+    """Grid (B, H, nk, nq), i innermost: one transposed (bk, bq) tile per
+    step. dk_s/dv_s accumulate one k tile's gradients over the q sweep;
+    dq_ref is the whole head's (S_q, D) float32 block, resident until the
+    head changes, and takes each tile's rows as they come (every q tile is
+    live at kb == 0, which therefore assigns)."""
+    kb = pl.program_id(2)
+    i = pl.program_id(3)
+
+    @pl.when(i == 0)
+    def _init():
+        dk_s[:] = jnp.zeros_like(dk_s)
+        dv_s[:] = jnp.zeros_like(dv_s)
+
+    def _tile(masked):
+        q, k, v, do = q_ref[:], k_ref[:], v_ref[:], do_ref[:]
+        sT = lax.dot_general(k, q, _NT,
+                             preferred_element_type=jnp.float32) * sm_scale
+        if masked:
+            sT = jnp.where(_tile_mask((bk, bq), i * bq + causal_off,
+                                      kb * bk, 1, causal, s_k_real),
+                           sT, _NEG)
+        pT = jnp.exp(sT - lse_ref[:])  # (1, bq) rows along sublanes
+        dpT = lax.dot_general(v, do, _NT,
+                              preferred_element_type=jnp.float32)
+        dsT = pT * (dpT - delta_ref[:])
+        dv_s[:] += jnp.dot(pT.astype(do.dtype), do,
+                           preferred_element_type=jnp.float32)
+        dk_s[:] += jnp.dot(dsT.astype(q.dtype), q,
+                           preferred_element_type=jnp.float32)
+        dq = jnp.dot(dsT.T.astype(k.dtype), k,
+                     preferred_element_type=jnp.float32) * sm_scale
+        rows = pl.ds(pl.multiple_of(i * bq, bq), bq)
+
+        @pl.when(kb == 0)
+        def _first():
+            dq_ref[rows, :] = dq
+
+        @pl.when(kb > 0)
+        def _rest():
+            dq_ref[rows, :] += dq
+
+    live, masked = _tile_kinds(i, kb, bq, bk, causal, s_k_real, causal_off)
+    pl.when(live & masked)(functools.partial(_tile, True))
+    pl.when(live & ~masked)(functools.partial(_tile, False))
+
+    @pl.when(i == nq - 1)
+    def _finalize():
+        dk_ref[:] = (dk_s[:] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[:] = dv_s[:].astype(dv_ref.dtype)
+
+
+def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
+                     bq=None, bk=None):
+    """(dq, dk, dv) by the fused backward kernel at the chooser's tiles
+    (``bq``/``bk`` override them for tests), from the forward's o and
+    lse."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, S_q, D = q.shape
+    S_k = k.shape[2]
+    if bq is None or bk is None:
+        bq, bk = choose_tiles(S_q, S_k, D, q.dtype.itemsize, backward=True)
+    pq = (-S_q) % bq
+    pk = (-S_k) % bk
+    Sq_p, Sk_p = S_q + pq, S_k + pk
+    if lse.shape != (B, H, 1, Sq_p):
+        raise ValueError(f"lse {lse.shape} is not the forward's for q "
+                         f"{q.shape} padded to tiles of {bq}")
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.pad(delta, ((0, 0), (0, 0), (0, pq)))[:, :, None]
+    nq = Sq_p // bq
+    off = S_k - S_q
+    kern = functools.partial(_fa_bwd_kernel, bq=bq, bk=bk, nq=nq,
+                             sm_scale=sm_scale, causal=causal,
+                             s_k_real=S_k, causal_off=off)
+
+    def _live_i(i, kb):
+        # q tiles above the diagonal of k tile kb are skipped: name the
+        # first live one and the pipeline copies nothing
+        if causal:
+            i = jnp.maximum(i, jnp.maximum(kb * bk - off, 0) // bq)
+        return i
+
+    q_spec = pl.BlockSpec((None, None, bq, D),
+                          lambda b, h, kb, i: (b, h, _live_i(i, kb), 0))
+    row_spec = pl.BlockSpec((None, None, 1, bq),
+                            lambda b, h, kb, i: (b, h, 0, _live_i(i, kb)))
+    kv_spec = pl.BlockSpec((None, None, bk, D),
+                           lambda b, h, kb, i: (b, h, kb, 0))
+    dq, dk, dv = pl.pallas_call(
+        kern,
+        grid=(B, H, Sk_p // bk, nq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[
+            pl.BlockSpec((None, None, Sq_p, D),
+                         lambda b, h, kb, i: (b, h, 0, 0)),
+            kv_spec, kv_spec,
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, Sq_p, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sk_p, D), k.dtype),
+            jax.ShapeDtypeStruct((B, H, Sk_p, D), v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, D), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+        # attn_bwd_ms.tokens finds the backward by this name alone
+        name="flash_bwd",
+    )(_pad_rows(q, pq), _pad_rows(k, pk), _pad_rows(v, pk),
+      _pad_rows(do, pq), lse, delta)  # zero do: padded rows add nothing
+    return (dq[:, :, :S_q].astype(q.dtype), dk[:, :, :S_k], dv[:, :, :S_k])
+
+
+# ---------------------------------------------------------------------------
+# decode
 
 def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, sm_scale):
     """Decode-mode kernel, grid (B*H,): one query row against its whole
@@ -193,6 +472,9 @@ def _decode_flash(q, k, v, lengths, sm_scale, interpret):
     return out.reshape(B, H, D)
 
 
+# ---------------------------------------------------------------------------
+# the differentiable entry
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash(q, k, v, sm_scale, causal, impl):
     if impl == "xla":
@@ -202,22 +484,28 @@ def _flash(q, k, v, sm_scale, causal, impl):
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, impl):
-    return _flash(q, k, v, sm_scale, causal, impl), (q, k, v)
+    fits = impl != "xla" and choose_tiles(
+        q.shape[2], k.shape[2], q.shape[3], q.dtype.itemsize, backward=True)
+    if not fits:  # the scan backward recomputes from q, k, v alone
+        return _flash(q, k, v, sm_scale, causal, impl), (q, k, v, None, None)
+    o, lse = _pallas_forward(q, k, v, sm_scale, causal,
+                             impl == "interpret", with_lse=True)
+    return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(sm_scale, causal, impl, res, do):
+def _scan_backward(q, k, v, do, sm_scale, causal):
     """Backward by q-chunk recompute (lax.scan): peak extra memory is
     O(chunk·S_k) instead of materializing the full S_q×S_k attention
-    matrix — long-context training keeps the flash memory property."""
-    q, k, v = res
+    matrix. The ``xla`` path's backward, the oracle of the kernel's, and
+    where a shape the kernel cannot hold ends up."""
     S_q, S_k = q.shape[2], k.shape[2]
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
     chunk = min(512, S_q)
     pad = (-S_q) % chunk
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0))).astype(jnp.float32)
-    dop = jnp.pad(do, ((0, 0), (0, 0), (0, pad), (0, 0))).astype(
-        jnp.float32)  # zero do on padding → padded rows contribute nothing
+    qp = _pad_rows(q, pad).astype(jnp.float32)
+    # zero do on padding → padded rows contribute nothing
+    dop = _pad_rows(do, pad).astype(jnp.float32)
     nchunk = (S_q + pad) // chunk
     B, H, _, D = q.shape
     qc = qp.reshape(B, H, nchunk, chunk, D).transpose(2, 0, 1, 3, 4)
@@ -240,12 +528,25 @@ def _flash_bwd(sm_scale, causal, impl, res, do):
         dk_acc += jnp.einsum("bhqk,bhqd->bhkd", ds, qb) * sm_scale
         return (dk_acc, dv_acc, ci + 1), dqb
 
-    with jax.named_scope("flash_bwd"):
-        (dk, dv, _), dqs = lax.scan(
-            step, (jnp.zeros_like(kf), jnp.zeros_like(vf), 0), (qc, doc))
+    (dk, dv, _), dqs = lax.scan(
+        step, (jnp.zeros_like(kf), jnp.zeros_like(vf), 0), (qc, doc))
     dq = dqs.transpose(1, 2, 0, 3, 4).reshape(B, H, S_q + pad, D)[
         :, :, :S_q]
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+def _flash_bwd(sm_scale, causal, impl, res, do):
+    """Counts, at trace time, which backward it lowered: the kernel, or
+    the scan (the ``xla`` path, and any shape whose dq the kernel cannot
+    keep in VMEM — loudly, in ``kernels.counters()``)."""
+    q, k, v, o, lse = res
+    with jax.named_scope("flash_bwd"):
+        if lse is None:
+            _count("flash_bwd_scan")
+            return _scan_backward(q, k, v, do, sm_scale, causal)
+        _count("flash_bwd_pallas")
+        return _pallas_backward(q, k, v, o, lse, do, sm_scale, causal,
+                                impl == "interpret")
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

@@ -25,7 +25,9 @@ from jax.sharding import SingleDeviceSharding
 from mxnet_tpu import kernels
 from mxnet_tpu.kernels import cost_model
 from mxnet_tpu.kernels.attention import _attention_decode
-from mxnet_tpu.kernels.flash_attention import _decode_flash, _pallas_forward
+from mxnet_tpu.kernels import flash_attention as fa
+from mxnet_tpu.kernels.flash_attention import (
+    _decode_flash, _flash, _pallas_forward)
 from mxnet_tpu.kernels.norm_act import _pallas_norm_act
 
 
@@ -71,12 +73,16 @@ def _assert_kernel(compiled, name):
 # ---------------------------------------------------------------------------
 # shapes the chip's compiler takes
 
-@pytest.mark.parametrize("shape,dtype", [
+FLASH_SHAPES = [
     ((8, 12, 512, 64), jnp.bfloat16),    # BERT-base heads, seq 512
     ((8, 12, 512, 64), jnp.float32),
     ((2, 16, 2048, 128), jnp.bfloat16),  # long-context LM heads
     ((4, 4, 100, 64), jnp.float32),      # ragged seq: padded to the tile
-])
+    ((2, 32, 2048, 64), jnp.bfloat16),   # the cell opt1.3b-train-s2048
+]
+
+
+@pytest.mark.parametrize("shape,dtype", FLASH_SHAPES)
 def test_flash_forward_compiles(one_chip, shape, dtype):
     c = _compile(lambda q, k, v: _pallas_forward(q, k, v, 0.125, True,
                                                  False),
@@ -84,6 +90,31 @@ def test_flash_forward_compiles(one_chip, shape, dtype):
     _assert_kernel(c, "flash_fwd")
     assert cost_model.pallas_fits_vmem("attention", shape[-2:],
                                        jnp.dtype(dtype).itemsize)
+
+
+@pytest.mark.parametrize("shape,dtype", FLASH_SHAPES)
+def test_flash_backward_compiles(one_chip, shape, dtype):
+    """``jax.grad`` through the kernel path, at the chooser's tiles: the
+    forward that also writes the row log-sum-exp, and the fused backward
+    under the one name ``attn_bwd_ms.tokens`` finds it by. No ``while``
+    is left: the scan is the other path."""
+    def loss(q, k, v):
+        # flash_attention()'s own scope; it picks "pallas" by the backend,
+        # which is the CPU here. (The jvp wraps the outermost scope's
+        # name: without one the forward is named jvp_flash_fwd_.)
+        with jax.named_scope("attn"):
+            o = _flash(q, k, v, 0.125, True, "pallas")
+        return o.astype(jnp.float32).sum()
+
+    before = kernels.counters()
+    c = _compile(jax.grad(loss, (0, 1, 2)), one_chip,
+                 (shape, dtype), (shape, dtype), (shape, dtype))
+    _assert_kernel(c, "flash_fwd")
+    _assert_kernel(c, "flash_bwd")
+    assert not re.search(r"\bwhile\(", c.as_text())
+    after = kernels.counters()
+    assert after["flash_bwd_pallas"] == before.get("flash_bwd_pallas", 0) + 1
+    assert after.get("flash_bwd_scan", 0) == before.get("flash_bwd_scan", 0)
 
 
 @pytest.mark.parametrize("b,h,s,d,dtype", [
@@ -140,6 +171,28 @@ def test_decode_oversize_row_refused_by_gate(one_chip):
         ((b, s, h * d), jnp.float32), ((b, 1), jnp.int32))
     assert "tpu_custom_call" not in c.as_text()
     assert kernels.counters()["fallback_vmem_bound"] == before + 1
+
+
+def test_flash_oversize_tile_refused_by_estimate(one_chip):
+    """bf16 S4096 D64 at forced 2048 x 2048 tiles: the compiler runs out
+    of VMEM on the float32 score tile. The footprint the chooser goes by
+    says so first (and is the careful side: the compiler still takes
+    2048 x 1024, the estimate stops at the 1024 x 1024 it chooses)."""
+    shape, dtype = (1, 2, 4096, 64), jnp.bfloat16
+
+    def fwd(bq, bk):
+        return _compile(lambda q, k, v: _pallas_forward(
+            q, k, v, 0.125, True, False, bq=bq, bk=bk), one_chip,
+            (shape, dtype), (shape, dtype), (shape, dtype))
+
+    assert fa.tile_vmem_bytes(2048, 2048, 4096, 64, 2) \
+        > cost_model._VMEM_BUDGET_BYTES
+    with pytest.raises(Exception, match="vmem"):
+        fwd(2048, 2048)
+    assert fa.tile_vmem_bytes(2048, 1024, 4096, 64, 2) \
+        > cost_model._VMEM_BUDGET_BYTES
+    _assert_kernel(fwd(2048, 1024), "flash_fwd")
+    assert fa.choose_tiles(4096, 4096, 64, 2) == (1024, 1024)
 
 
 def test_norm_act_oversize_row_refused_by_gate(one_chip):
