@@ -387,6 +387,118 @@ def phase_kernels(cfg):
     assert_on(accs.items(), cfg.platform, "quantized FC output")
 
 
+def phase_masked(cfg):
+    """The kernels of the block-diffusion mixture-of-experts step against
+    their plain twins: the flash forward and backward under
+    ``BlockDiffusionMask`` with grouped heads, and the grouped matrix
+    products. Float32 at ``highest`` to the tolerances ``phase_kernels``
+    holds the causal kernels to; then bfloat16 at the cell's own shapes,
+    (2, 32 over 4, 8192, 128) and (16384, 2048) x (16, 2048, 1536),
+    (16384, 768) x (16, 768, 2048), to bfloat16's rounding. The kernels
+    are forced (``use_pallas=True``): what the chip's compiler refuses
+    fails here, nothing falls back."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import kernels
+    from mxnet_tpu.kernels.flash_attention import (BlockDiffusionMask,
+                                                   flash_attention)
+    from mxnet_tpu.kernels.grouped_matmul import grouped_matmul
+
+    on_chip = not cfg.rehearse
+    rs = onp.random.RandomState(SEED + 3)
+
+    def randn(dtype, *shape):
+        return jnp.asarray(rs.randn(*shape).astype("f"), dtype)
+
+    def close(name, got, want, tol, scale=1.0):
+        got, want = (jnp.asarray(a, jnp.float32) for a in (got, want))
+        err = float(jnp.abs(got - want).max()) / scale
+        log(f"masked: {name} max |pallas - plain| = {err:.3e} (tol {tol})")
+        assert bool(jnp.isfinite(got).all()) and err < tol, (name, err)
+
+    def attention(dtype, b, hq, hkv, seq, d, tols):
+        mask = BlockDiffusionMask(seq, 4)
+        q, do = (randn(dtype, b, hq, 2 * seq, d) for _ in range(2))
+        k, v = (randn(dtype, b, hkv, 2 * seq, d) for _ in range(2))
+
+        def run(pallas):
+            def f(q, k, v, do):
+                o, vjp = jax.vjp(lambda *a: flash_attention(
+                    *a, mask=mask, use_pallas=pallas), q, k, v)
+                return (o,) + vjp(do)
+            return jax.jit(f)
+
+        scans = kernels.counters().get("flash_bwd_scan", 0)
+        got = run(True)(q, k, v, do)
+        assert kernels.counters().get("flash_bwd_scan", 0) == scans
+        if on_chip:
+            assert "tpu_custom_call" in run(True).lower(
+                q, k, v, do).as_text()
+        # the plain twin one query head at a time: its (S, S) scores in
+        # float32 would not fit the chip for all heads at once
+        group, plain = hq // hkv, run(False)
+        f32 = jnp.float32
+        want = [jnp.zeros(a.shape, f32) for a in (q, q, k, v)]
+        for bi in range(b):
+            for h in range(hq):
+                kv = (slice(bi, bi + 1), slice(h // group, h // group + 1))
+                qh = (slice(bi, bi + 1), slice(h, h + 1))
+                o, dq, dk, dv = plain(q[qh].astype(f32), k[kv].astype(f32),
+                                      v[kv].astype(f32), do[qh].astype(f32))
+                want[0] = want[0].at[qh].set(o)
+                want[1] = want[1].at[qh].set(dq)
+                want[2] = want[2].at[kv].add(dk)
+                want[3] = want[3].at[kv].add(dv)
+        for name, g, w, tol in zip(("forward", "dq", "dk", "dv"), got, want,
+                                   tols):
+            close(f"flash {jnp.dtype(dtype).name} {(b, hq, hkv, 2 * seq, d)}"
+                  f" {name}", g, w, tol)
+
+    def products(dtype, m, k, n, groups, tol):
+        lhs, dout = randn(dtype, m, k), randn(dtype, m, n)
+        rhs = randn(dtype, groups, k, n) * 0.05
+        # uneven: an empty group, a large one, sizes off the tile, and
+        # rows past the last group
+        cut = onp.sort(rs.randint(0, m - m // 16, groups - 2))
+        sizes = onp.diff(onp.concatenate([[0], cut, [m - m // 16]]))
+        sizes = jnp.asarray(onp.concatenate([[0], sizes]), jnp.int32)
+
+        def run(pallas):
+            def f(lhs, rhs, dout):
+                out, vjp = jax.vjp(lambda a, b: grouped_matmul(
+                    a, b, sizes, use_pallas=pallas), lhs, rhs)
+                return (out,) + vjp(dout)
+            return jax.jit(f)
+
+        got = run(True)(lhs, rhs, dout)
+        if on_chip:
+            assert "tpu_custom_call" in run(True).lower(
+                lhs, rhs, dout).as_text()
+        want = run(False)(lhs, rhs, dout)
+        for name, g, w in zip(("product", "dlhs", "drhs"), got, want):
+            close(f"grouped {jnp.dtype(dtype).name} ({m}, {k}) x "
+                  f"({groups}, {k}, {n}) {name}", g, w, tol,
+                  scale=float(jnp.abs(jnp.asarray(w, jnp.float32)).max()))
+
+    with jax.default_matmul_precision("highest"):
+        attention(jnp.float32, *((1, 4, 2, 128, 32) if cfg.rehearse
+                                 else (1, 8, 2, 1024, 128)),
+                  (1e-5, 1e-4, 1e-4, 1e-4))
+        products(jnp.float32, *((512, 128, 256, 4) if cfg.rehearse
+                                else (4096, 768, 2048, 16)), 1e-5)
+    if cfg.rehearse:
+        return
+    # bfloat16 carries 8 bits: results of size ~1 and gradients of size
+    # ~10 round by up to 4e-3 and 4e-2 of a unit on either side
+    attention(jnp.bfloat16, 2, 32, 4, 4096, 128, (2e-2, 1e-1, 2e-1, 2e-1))
+    products(jnp.bfloat16, 16384, 2048, 1536, 16, 1e-2)
+    products(jnp.bfloat16, 16384, 768, 2048, 16, 1e-2)
+    counted = kernels.counters()
+    assert counted.get("flash_mask_pallas", 0) > 0
+    assert counted.get("moe_gmm_pallas", 0) > 0
+
+
 def phase_dp4(cfg):
     """Data-parallel training over four chips against the same steps on
     one: same seed, same global batch.
@@ -444,6 +556,8 @@ def main(argv=None):
     ap.add_argument("--rehearse", action="store_true",
                     help="walk the control flow on the CPU at tiny "
                          "size; prints no result line")
+    ap.add_argument("--phases", default="",
+                    help="run only these phases, comma-separated")
     args = ap.parse_args(argv)
     cfg = Cfg(args.rehearse)
 
@@ -469,8 +583,10 @@ def main(argv=None):
     log(f"jax compile cache: {cc.jax_cache_dir()}")
 
     one_chip = {"train": phase_train, "serve": phase_serve,
-                "kernels": phase_kernels}
+                "kernels": phase_kernels, "masked": phase_masked}
     todo = {"dp4": phase_dp4} if args.chips == 4 else one_chip
+    if args.phases:
+        todo = {n: todo[n] for n in args.phases.split(",")}
     for name, phase in todo.items():
         t0 = time.perf_counter()
         log(f"phase {name} ...")

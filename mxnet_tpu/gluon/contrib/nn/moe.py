@@ -1,12 +1,14 @@
-"""Gluon layer over the expert-parallel Switch-MoE FFN
-(parallel/moe.py). NEW capability vs the reference zoo — the Gluon
-face of SURVEY §5.7's scale features, alongside SyncBatchNorm.
+"""Gluon layers over the mixture-of-experts FFNs of parallel/moe.py: the
+expert-parallel Switch layer (top-1, static capacity) and the drop-free
+top-k layer that is told which experts it holds. NEW capability vs the
+reference zoo — the Gluon face of SURVEY §5.7's scale features,
+alongside SyncBatchNorm.
 """
 from __future__ import annotations
 
 from ...block import HybridBlock
 
-__all__ = ["SwitchMoE"]
+__all__ = ["SwitchMoE", "TopKMoE"]
 
 
 class SwitchMoE(HybridBlock):
@@ -126,3 +128,91 @@ class SwitchMoE(HybridBlock):
     def __repr__(self):
         return (f"SwitchMoE(experts={self._E}, hidden={self._H}, "
                 f"axis='{self._axis}')")
+
+
+class TopKMoE(HybridBlock):
+    """Drop-free top-k mixture of SiLU-gated experts
+    (``parallel.moe.top_k_router`` + ``expert_ffn``).
+
+    The router is ``num_experts`` wide and takes the ``top_k`` largest
+    probabilities, renormalised with ``norm_topk_prob``. The layer holds
+    ``experts_held=(first, count)`` of the experts (all of them by
+    default) and computes exactly the part of the result that those
+    give, for every assignment that lands on them: what the experts held
+    elsewhere would add is not in ``out``. forward(x (B, S, D)) -> out
+    (B, S, D) without the residual. ``expert_rows`` (no gradient) holds
+    the rows each held expert got in the last training forward; a
+    compiled step carries it back like BatchNorm's running statistics,
+    and ``SPMDTrainer`` publishes it as the ``moe/*`` telemetry counters.
+
+    With ``axis_name`` on the active mesh (``mesh=`` or
+    ``parallel.mesh_scope``) and every expert held, the experts are
+    sharded over that axis (``parallel.moe.expert_parallel_ffn``).
+    """
+
+    def __init__(self, num_experts, hidden_size, top_k, in_units=0,
+                 experts_held=None, norm_topk_prob=True, axis_name="ep",
+                 mesh=None, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        from ....parallel.moe import note_expert_rows
+
+        self._E, self._F, self._k = int(num_experts), int(hidden_size), \
+            int(top_k)
+        first, count = experts_held or (0, self._E)
+        if not 0 <= first <= first + count <= self._E or count < 1:
+            raise ValueError(f"experts_held {experts_held} of {self._E}")
+        self._held = (int(first), int(count))
+        self._norm = bool(norm_topk_prob)
+        self._axis, self._mesh = axis_name, mesh
+        D = int(in_units)
+        with self.name_scope():
+            self.gate_weight = self.params.get(
+                "gate_weight", shape=(D, self._E),
+                allow_deferred_init=True)
+            self.expert_w13 = self.params.get(
+                "expert_w13", shape=(count, D, 2 * self._F),
+                allow_deferred_init=True)
+            self.expert_w2 = self.params.get(
+                "expert_w2", shape=(count, self._F, D),
+                allow_deferred_init=True)
+            self.expert_rows = self.params.get(
+                "expert_rows", grad_req="null", shape=(count,),
+                init="zeros", differentiable=False)
+        self.expert_rows.step_stat = note_expert_rows
+
+    def infer_param_shapes(self, x, *args):
+        D, count = x.shape[-1], self._held[1]
+        self.gate_weight.shape = (D, self._E)
+        self.expert_w13.shape = (count, D, 2 * self._F)
+        self.expert_w2.shape = (count, self._F, D)
+
+    def hybrid_forward(self, F, x, gate_weight, expert_w13, expert_w2,
+                       expert_rows):
+        from .... import autograd
+        from ....ndarray.registry import apply_pure
+        from ....parallel import moe
+        from ....parallel.mesh import current_mesh
+
+        mesh = self._mesh or current_mesh()
+        sharded = mesh is not None and self._axis in mesh.axis_names \
+            and mesh.shape[self._axis] > 1 and self._held[1] == self._E
+        k, held, n, norm = self._k, self._held, self._E, self._norm
+
+        def pure(xv, gw, w13, w2):
+            flat = xv.reshape(-1, xv.shape[-1])
+            if sharded:
+                y, rows = moe.expert_parallel_ffn(
+                    flat, gw, w13, w2, k, mesh, self._axis, norm)
+            else:
+                idx, gates = moe.top_k_router(flat, gw, k, norm)
+                y, rows = moe.expert_ffn(flat, idx, gates, w13, w2, held, n)
+            return y.reshape(xv.shape), rows.astype("float32")
+
+        out, rows = apply_pure(pure, [x, gate_weight, expert_w13, expert_w2])
+        if autograd.is_training():
+            expert_rows._data = rows.data
+        return out
+
+    def __repr__(self):
+        return (f"TopKMoE(experts={self._E}, top_k={self._k}, "
+                f"held={self._held}, hidden={self._F})")

@@ -13,8 +13,8 @@ from ..block import Block, HybridBlock
 from ..parameter import Parameter
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "BatchNorm",
-           "InstanceNorm", "LayerNorm", "GroupNorm", "Embedding", "Flatten",
-           "Lambda", "HybridLambda"]
+           "InstanceNorm", "LayerNorm", "RMSNorm", "GroupNorm", "Embedding",
+           "Flatten", "Lambda", "HybridLambda"]
 
 
 class Sequential(Block):
@@ -275,6 +275,42 @@ class LayerNorm(HybridBlock):
     def hybrid_forward(self, F, x, gamma, beta):
         return F.layer_norm(x, gamma, beta, axis=self._axis,
                             eps=self._epsilon)
+
+
+def rms_norm(x, gamma, epsilon):
+    """``x * rsqrt(mean(x^2) + epsilon) * gamma`` over the last axis of a
+    jax array, the statistics in float32, the result in ``x``'s type."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf * lax.rsqrt(ms + epsilon)
+            * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+class RMSNorm(HybridBlock):
+    """Root-mean-square norm over the last axis, ``x * rsqrt(mean(x^2) +
+    epsilon) * gamma`` with the statistics in float32 (Zhang & Sennrich
+    2019; no counterpart in the reference's layers)."""
+
+    def __init__(self, epsilon=1e-6, gamma_initializer="ones",
+                 in_channels=0, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._epsilon = float(epsilon)
+        with self.name_scope():
+            self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                         init=gamma_initializer,
+                                         allow_deferred_init=True)
+
+    def infer_param_shapes(self, x, *args):
+        self.gamma.shape = (x.shape[-1],)
+
+    def hybrid_forward(self, F, x, gamma):
+        from ...ndarray.registry import apply_pure
+
+        eps = self._epsilon
+        return apply_pure(lambda xv, g: rms_norm(xv, g, eps), [x, gamma])
 
 
 class GroupNorm(HybridBlock):

@@ -30,6 +30,19 @@ gradients once. Design:
   block of the whole sweep. A shape whose dq does not fit the budget
   takes the ``lax.scan`` backward and counts ``flash_bwd_scan`` in
   ``kernels.counters()`` (the kernel counts ``flash_bwd_pallas``).
+- which scores live: ``causal`` (the diagonal, decided from the grid
+  indices as it always was), or a static ``mask`` spec such as
+  ``BlockDiffusionMask``: at trace time the spec gives a table of tile
+  kinds (dead, whole, partly masked) per (q tile, k tile), which rides
+  into both kernels as a prefetched scalar array and drives the skipping
+  and the index maps exactly as the diagonal does; a partly masked tile
+  evaluates the spec's element rule from its own indices. No (S, S)
+  array exists in HBM either way.
+- grouped heads: q of H heads reads k, v of H / group heads in place
+  through the index map. The backward writes dk, dv per query head and
+  the group is summed after the kernel (a grid order that kept one k
+  tile's dk, dv resident across the group would revisit each head's dq
+  block once per k tile, which an output block cannot survive).
 - off-TPU (tests, CPU) the same kernels run under interpret=True, or the
   pure-XLA path via flash_attention(..., use_pallas=False): plain
   attention forward, q-chunk recompute scan backward — the oracle the
@@ -37,11 +50,13 @@ gradients once. Design:
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as onp
 from jax import lax
 from jax.experimental import pallas as pl
 
@@ -57,13 +72,117 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _FWD_CAPS = (1024, 1024)
 _BWD_CAPS = (512, 512)
 
+# ---------------------------------------------------------------------------
+# mask specs
 
-def _ref_attention(q, k, v, sm_scale, causal, s_k_real):
+#: kinds of a (q tile, k tile) pair in a mask's table; FIRST is added to
+#: the first live k tile of a q tile (the backward's dq assigns there)
+DEAD, WHOLE, PARTIAL, FIRST = 0, 1, 2, 4
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusionMask:
+    """Which scores live in block-diffusion training (BD3-LM, Arriola et
+    al. 2025): the input is ``[xt ; x0]``, ``seq_len`` noised positions
+    and then their ``seq_len`` clean copies, cut into blocks of
+    ``block_length``. A noised query sees its own noised block (both
+    directions) and the clean copy of every earlier block; a clean query
+    sees the clean copies causally by blocks. Static and hashable: the
+    kernels are specialised on it."""
+
+    seq_len: int
+    block_length: int
+
+    def __post_init__(self):
+        if self.seq_len % self.block_length:
+            raise ValueError(f"seq_len {self.seq_len} is no multiple of "
+                             f"block_length {self.block_length}")
+
+    @property
+    def size(self):
+        return 2 * self.seq_len
+
+    def _block(self, pos):
+        b = self.block_length
+        if b & (b - 1) == 0:    # a shift where the vector units have no divide
+            return jnp.right_shift(pos, b.bit_length() - 1)
+        return lax.div(pos, jnp.int32(b))
+
+    def element(self, qid, kid):
+        """The element rule on int32 index arrays that broadcast."""
+        L = self.seq_len
+        q_noisy, k_noisy = qid < L, kid < L
+        qb = self._block(jnp.where(q_noisy, qid, qid - L))
+        kb = self._block(jnp.where(k_noisy, kid, kid - L))
+        # logical ops only: Mosaic has no select between masks
+        return (k_noisy & q_noisy & (qb == kb)) | (
+            ~k_noisy & ((kb < qb) | (~q_noisy & (kb == qb))))
+
+    def row_intervals(self):
+        """(S, 2, 2) int: every query row's live keys as two [start, end)
+        intervals, from which the tile table is counted."""
+        L, b = self.seq_len, self.block_length
+        blk = onp.arange(L) // b
+        empty = onp.zeros(L, onp.int64)
+        noisy = onp.stack([onp.stack([blk * b, (blk + 1) * b], -1),
+                           onp.stack([L + empty, L + blk * b], -1)], 1)
+        clean = onp.stack([onp.stack([L + empty, L + (blk + 1) * b], -1),
+                           onp.stack([empty, empty], -1)], 1)
+        return onp.concatenate([noisy, clean], 0)
+
+
+@functools.lru_cache(maxsize=None)
+def mask_tile_table(mask, bq, bk):
+    """(nq, nk) int32 table of tile kinds for ``mask`` at tiles
+    (bq, bk): DEAD, WHOLE or PARTIAL by the count of live scores in the
+    tile, plus FIRST on each q tile's first live k tile."""
+    S = mask.size
+    if S % bq or S % bk:
+        raise ValueError(f"tiles ({bq}, {bk}) do not divide the mask's "
+                         f"{S} positions")
+    nq, nk = S // bq, S // bk
+    iv = mask.row_intervals()                       # (S, n, 2)
+    edges = onp.arange(nk + 1) * bk                 # (nk + 1,)
+    lo = onp.maximum(iv[:, :, :1], edges[None, None, :-1])
+    hi = onp.minimum(iv[:, :, 1:], edges[None, None, 1:])
+    live = onp.maximum(hi - lo, 0).sum(1)           # (S, nk) per row
+    count = live.reshape(nq, bq, nk).sum(1)
+    kinds = onp.where(count == 0, DEAD,
+                      onp.where(count == bq * bk, WHOLE, PARTIAL))
+    if (kinds == DEAD).all(1).any():
+        raise ValueError("a q tile with no live score: its output and dq "
+                         "would never be written")
+    first = (kinds != DEAD).argmax(1)
+    kinds[onp.arange(nq), first] += FIRST
+    return kinds.astype(onp.int32)
+
+
+def _fetch_table(kinds, axis):
+    """For each tile the index along ``axis`` (1: k tiles of a q row; 0:
+    q tiles of a k column) of the block to name in the index map: its
+    own where it is live, else the live one named last (the first live
+    one before any), so that a dead tile copies nothing."""
+    live = (kinds & (WHOLE | PARTIAL)) != 0
+    if axis == 0:
+        live = live.T
+    n = live.shape[1]
+    idx = onp.where(live, onp.arange(n)[None], -1)
+    last = onp.maximum.accumulate(idx, axis=1)
+    first = live.argmax(1)[:, None]
+    out = onp.where(last < 0, first, last).astype(onp.int32)
+    return out.T if axis == 0 else out
+
+
+def _ref_attention(q, k, v, sm_scale, causal, s_k_real, spec=None):
     """Plain XLA attention, the correctness oracle.
 
     Causal masking is bottom-right aligned: query row i sits at global
     position i + (S_k - S_q), so decode-style calls (S_q=1 against a long
-    KV cache) attend to the whole prefix."""
+    KV cache) attend to the whole prefix. A ``mask`` spec gives a dense
+    boolean mask from its element rule; grouped heads repeat k and v."""
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(a, group, axis=1) for a in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * sm_scale
     S_q, S_k = q.shape[2], k.shape[2]
@@ -72,6 +191,8 @@ def _ref_attention(q, k, v, sm_scale, causal, s_k_real):
     if causal:
         qid = jnp.arange(S_q)[:, None] + (s_k_real - S_q)
         mask = mask & (kid <= qid)
+    if spec is not None:
+        mask = mask & spec.element(jnp.arange(S_q)[:, None], kid)
     s = jnp.where(mask[None, None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
@@ -160,6 +281,14 @@ def _tile_mask(shape, q0, k0, q_axis, causal, s_k_real):
     return mask
 
 
+def _spec_mask(mask, shape, q0, k0, q_axis):
+    """A partly masked tile under a mask spec: its element rule on the
+    tile's own indices, queries along ``q_axis``."""
+    qid = q0 + lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    kid = k0 + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return mask.element(qid, kid)
+
+
 def _tile_kinds(i, kb, bq, bk, causal, s_k_real, causal_off):
     """(live, masked) of tile (i, kb): live unless wholly above the
     diagonal; masked when the diagonal or the keys' padding crosses it —
@@ -174,14 +303,18 @@ def _tile_kinds(i, kb, bq, bk, causal, s_k_real, causal_off):
 # ---------------------------------------------------------------------------
 # forward
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, bq, bk, nk, sm_scale,
-               causal, s_k_real, causal_off):
+def _fa_kernel(*refs, bq, bk, nk, sm_scale, causal, s_k_real, causal_off,
+               mask=None):
     """Grid (B, H, nq, nk), kb innermost: one (bq, bk) tile per step. Only
     a q tile, one k/v tile and the (m, l, acc) scratch live in VMEM — true
     streaming, O(bq·D + bk·D) on-chip whatever the sequence length. The
     scratch carries the online softmax across the kb sweep (TPU grid steps
     run sequentially, scratch persists). ``rest`` is (m, l, acc), led by
-    the lse output block when the backward will want it."""
+    the lse output block when the backward will want it. Under a ``mask``
+    spec its two prefetched tables lead the refs."""
+    if mask is not None:
+        kinds_ref, _, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, *rest = refs
     *lse_out, m_s, l_s, acc_s = rest
     i = pl.program_id(2)
     kb = pl.program_id(3)
@@ -196,7 +329,10 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, bq, bk, nk, sm_scale,
         v = v_ref[:]
         s = lax.dot_general(q_ref[:], k_ref[:], _NT,
                             preferred_element_type=jnp.float32) * sm_scale
-        if masked:
+        if masked and mask is not None:
+            s = jnp.where(_spec_mask(mask, (bq, bk), i * bq, kb * bk, 0),
+                          s, _NEG)
+        elif masked:
             s = jnp.where(_tile_mask((bq, bk), i * bq + causal_off, kb * bk,
                                      0, causal, s_k_real), s, _NEG)
         m = m_s[:]
@@ -208,9 +344,15 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, bq, bk, nk, sm_scale,
         acc_s[:] = acc_s[:] * alpha + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
-    live, masked = _tile_kinds(i, kb, bq, bk, causal, s_k_real, causal_off)
-    pl.when(live & masked)(functools.partial(_tile, True))
-    pl.when(live & ~masked)(functools.partial(_tile, False))
+    if mask is not None:
+        kind = kinds_ref[i * nk + kb] & (WHOLE | PARTIAL)
+        pl.when(kind == PARTIAL)(functools.partial(_tile, True))
+        pl.when(kind == WHOLE)(functools.partial(_tile, False))
+    else:
+        live, masked = _tile_kinds(i, kb, bq, bk, causal, s_k_real,
+                                   causal_off)
+        pl.when(live & masked)(functools.partial(_tile, True))
+        pl.when(live & ~masked)(functools.partial(_tile, False))
 
     @pl.when(kb == nk - 1)
     def _finalize():
@@ -229,15 +371,42 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, bq, bk, nk, sm_scale,
             lse_out[0][:] = lse.T[:1]
 
 
+def _mask_tiles(mask, q, k, backward, bq, bk):
+    """Tiles and the two prefetched tables of a mask spec's pass: the
+    kinds, flattened row-major over (q tile, k tile), and per tile the
+    block to name where it is dead (k blocks forward, q blocks
+    backward)."""
+    S = q.shape[2]
+    if mask.size != S or k.shape[2] != S:
+        raise ValueError(f"mask over {mask.size} positions, q {q.shape} "
+                         f"and k {k.shape}")
+    if S % _TILE_COLS:
+        raise ValueError(f"a mask spec needs a multiple of {_TILE_COLS} "
+                         f"positions, got {S}")
+    if bq is None or bk is None:
+        bq, bk = choose_tiles(S, S, q.shape[3], q.dtype.itemsize,
+                              backward=backward)
+    kinds = mask_tile_table(mask, bq, bk)
+    fetch = _fetch_table(kinds, 0 if backward else 1)
+    return bq, bk, jnp.asarray(kinds.reshape(-1)), \
+        jnp.asarray(fetch.reshape(-1))
+
+
 def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
-                    bq=None, bk=None):
+                    bq=None, bk=None, mask=None):
     """The forward kernel at the chooser's tiles (``bq``/``bk`` override
     them for tests). Returns o, or (o, lse) with lse float32 of shape
-    (B, H, 1, S_q padded to bq) when ``with_lse``."""
+    (B, H, 1, S_q padded to bq) when ``with_lse``. k and v may hold
+    H / group heads."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S_q, D = q.shape
     S_k = k.shape[2]
+    group = H // k.shape[1]
+    tables = ()
+    if mask is not None:
+        bq, bk, *tables = _mask_tiles(mask, q, k, False, bq, bk)
+        _count("flash_mask_pallas")
     if bq is None or bk is None:
         bq, bk = choose_tiles(S_q, S_k, D, q.dtype.itemsize)
     pq = (-S_q) % bq
@@ -247,42 +416,55 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
     off = S_k - S_q
     kern = functools.partial(_fa_kernel, bq=bq, bk=bk, nk=nk,
                              sm_scale=sm_scale, causal=causal,
-                             s_k_real=S_k, causal_off=off)
+                             s_k_real=S_k, causal_off=off, mask=mask)
 
-    def kv_map(b, h, i, kb):
-        # a tile above the diagonal is skipped: name the last live one
-        # again and the pipeline copies nothing
-        if causal:
+    def kv_map(b, h, i, kb, *tabs):
+        # a dead tile (above the diagonal, or by the mask's table) is
+        # skipped: name the last live one again and the pipeline copies
+        # nothing
+        if tabs:
+            kb = tabs[1][i * nk + kb]
+        elif causal:
             kb = jnp.minimum(kb, ((i + 1) * bq - 1 + off) // bk)
+        if group > 1:
+            h = h // group
         return b, h, kb, 0
 
     # one head a grid row, its block's two leading dimensions squeezed:
     # the kernel sees (bq, D), (bk, D) and the (1, bq) row of lse
     q_spec = pl.BlockSpec((None, None, bq, D),
-                          lambda b, h, i, kb: (b, h, i, 0))
+                          lambda b, h, i, kb, *_: (b, h, i, 0))
     kv_spec = pl.BlockSpec((None, None, bk, D), kv_map)
     out_specs = [q_spec]
     out_shape = [jax.ShapeDtypeStruct((B, H, Sq_p, D), q.dtype)]
     if with_lse:
         out_specs.append(pl.BlockSpec((None, None, 1, bq),
-                                      lambda b, h, i, kb: (b, h, 0, i)))
+                                      lambda b, h, i, kb, *_: (b, h, 0, i)))
         out_shape.append(jax.ShapeDtypeStruct((B, H, 1, Sq_p), jnp.float32))
+    grid = (B, H, Sq_p // bq, nk)
+    in_specs = [q_spec, kv_spec, kv_spec]
+    out_specs = out_specs if with_lse else out_specs[0]
+    scratch = [
+        pltpu.VMEM((bq, 1), jnp.float32),
+        pltpu.VMEM((bq, 1), jnp.float32),
+        pltpu.VMEM((bq, D), jnp.float32),
+    ]
+    if tables:
+        how = {"grid_spec": pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch)}
+    else:
+        how = {"grid": grid, "in_specs": in_specs, "out_specs": out_specs,
+               "scratch_shapes": scratch}
     out = pl.pallas_call(
         kern,
-        grid=(B, H, Sq_p // bq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=out_specs if with_lse else out_specs[0],
         out_shape=out_shape if with_lse else out_shape[0],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="flash_fwd",  # the HLO instruction's name on a device trace
-    )(_pad_rows(q, pq), _pad_rows(k, pk), _pad_rows(v, pk))
+        **how,
+    )(*tables, _pad_rows(q, pq), _pad_rows(k, pk), _pad_rows(v, pk))
     if with_lse:
         return out[0][:, :, :S_q], out[1]
     return out[:, :, :S_q]
@@ -291,16 +473,22 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
 # ---------------------------------------------------------------------------
 # backward
 
-def _fa_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dk_ref, dv_ref, dk_s, dv_s, *, bq, bk, nq, sm_scale,
-                   causal, s_k_real, causal_off):
+def _fa_bwd_kernel(*refs, bq, bk, nq, sm_scale, causal, s_k_real,
+                   causal_off, mask=None):
     """Grid (B, H, nk, nq), i innermost: one transposed (bk, bq) tile per
     step. dk_s/dv_s accumulate one k tile's gradients over the q sweep;
     dq_ref is the whole head's (S_q, D) float32 block, resident until the
-    head changes, and takes each tile's rows as they come (every q tile is
-    live at kb == 0, which therefore assigns)."""
+    head changes, and takes each tile's rows as they come: the first live
+    k tile of a q tile assigns (kb == 0 under ``causal``, the table's
+    FIRST under a ``mask`` spec, whose two tables lead the refs)."""
+    if mask is not None:
+        kinds_ref, _, *refs = refs
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
+     dv_ref, dk_s, dv_s) = refs
     kb = pl.program_id(2)
     i = pl.program_id(3)
+    if mask is not None:
+        kind = kinds_ref[i * (mask.size // bk) + kb]
 
     @pl.when(i == 0)
     def _init():
@@ -311,7 +499,10 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         q, k, v, do = q_ref[:], k_ref[:], v_ref[:], do_ref[:]
         sT = lax.dot_general(k, q, _NT,
                              preferred_element_type=jnp.float32) * sm_scale
-        if masked:
+        if masked and mask is not None:
+            sT = jnp.where(_spec_mask(mask, (bk, bq), i * bq, kb * bk, 1),
+                           sT, _NEG)
+        elif masked:
             sT = jnp.where(_tile_mask((bk, bq), i * bq + causal_off,
                                       kb * bk, 1, causal, s_k_real),
                            sT, _NEG)
@@ -327,17 +518,23 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                      preferred_element_type=jnp.float32) * sm_scale
         rows = pl.ds(pl.multiple_of(i * bq, bq), bq)
 
-        @pl.when(kb == 0)
+        @pl.when(kb == 0 if mask is None else (kind & FIRST) != 0)
         def _first():
             dq_ref[rows, :] = dq
 
-        @pl.when(kb > 0)
+        @pl.when(kb > 0 if mask is None else (kind & FIRST) == 0)
         def _rest():
             dq_ref[rows, :] += dq
 
-    live, masked = _tile_kinds(i, kb, bq, bk, causal, s_k_real, causal_off)
-    pl.when(live & masked)(functools.partial(_tile, True))
-    pl.when(live & ~masked)(functools.partial(_tile, False))
+    if mask is not None:
+        live_kind = kind & (WHOLE | PARTIAL)
+        pl.when(live_kind == PARTIAL)(functools.partial(_tile, True))
+        pl.when(live_kind == WHOLE)(functools.partial(_tile, False))
+    else:
+        live, masked = _tile_kinds(i, kb, bq, bk, causal, s_k_real,
+                                   causal_off)
+        pl.when(live & masked)(functools.partial(_tile, True))
+        pl.when(live & ~masked)(functools.partial(_tile, False))
 
     @pl.when(i == nq - 1)
     def _finalize():
@@ -346,14 +543,19 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
-                     bq=None, bk=None):
+                     bq=None, bk=None, mask=None):
     """(dq, dk, dv) by the fused backward kernel at the chooser's tiles
     (``bq``/``bk`` override them for tests), from the forward's o and
-    lse."""
+    lse. With grouped heads the kernel writes dk, dv per query head and
+    the group is summed here, in float32."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S_q, D = q.shape
     S_k = k.shape[2]
+    group = H // k.shape[1]
+    tables = ()
+    if mask is not None:
+        bq, bk, *tables = _mask_tiles(mask, q, k, True, bq, bk)
     if bq is None or bk is None:
         bq, bk = choose_tiles(S_q, S_k, D, q.dtype.itemsize, backward=True)
     pq = (-S_q) % bq
@@ -365,50 +567,73 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = jnp.pad(delta, ((0, 0), (0, 0), (0, pq)))[:, :, None]
     nq = Sq_p // bq
+    nk = Sk_p // bk
     off = S_k - S_q
     kern = functools.partial(_fa_bwd_kernel, bq=bq, bk=bk, nq=nq,
                              sm_scale=sm_scale, causal=causal,
-                             s_k_real=S_k, causal_off=off)
+                             s_k_real=S_k, causal_off=off, mask=mask)
 
-    def _live_i(i, kb):
-        # q tiles above the diagonal of k tile kb are skipped: name the
-        # first live one and the pipeline copies nothing
-        if causal:
+    def _live_i(i, kb, tabs):
+        # q tiles that are dead for k tile kb (above the diagonal, or by
+        # the mask's table) are skipped: name a live one and the pipeline
+        # copies nothing
+        if tabs:
+            i = tabs[1][i * nk + kb]
+        elif causal:
             i = jnp.maximum(i, jnp.maximum(kb * bk - off, 0) // bq)
         return i
 
-    q_spec = pl.BlockSpec((None, None, bq, D),
-                          lambda b, h, kb, i: (b, h, _live_i(i, kb), 0))
-    row_spec = pl.BlockSpec((None, None, 1, bq),
-                            lambda b, h, kb, i: (b, h, 0, _live_i(i, kb)))
-    kv_spec = pl.BlockSpec((None, None, bk, D),
-                           lambda b, h, kb, i: (b, h, kb, 0))
+    def kv_map(b, h, kb, i, *_):
+        return b, (h // group if group > 1 else h), kb, 0
+
+    q_spec = pl.BlockSpec(
+        (None, None, bq, D),
+        lambda b, h, kb, i, *tabs: (b, h, _live_i(i, kb, tabs), 0))
+    row_spec = pl.BlockSpec(
+        (None, None, 1, bq),
+        lambda b, h, kb, i, *tabs: (b, h, 0, _live_i(i, kb, tabs)))
+    kv_spec = pl.BlockSpec((None, None, bk, D), kv_map)
+    dkv_spec = pl.BlockSpec((None, None, bk, D),
+                            lambda b, h, kb, i, *_: (b, h, kb, 0))
+    grid = (B, H, nk, nq)
+    in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+    out_specs = [
+        pl.BlockSpec((None, None, Sq_p, D),
+                     lambda b, h, kb, i, *_: (b, h, 0, 0)),
+        dkv_spec, dkv_spec,
+    ]
+    scratch = [
+        pltpu.VMEM((bk, D), jnp.float32),
+        pltpu.VMEM((bk, D), jnp.float32),
+    ]
+    if tables:
+        how = {"grid_spec": pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch)}
+    else:
+        how = {"grid": grid, "in_specs": in_specs, "out_specs": out_specs,
+               "scratch_shapes": scratch}
     dq, dk, dv = pl.pallas_call(
         kern,
-        grid=(B, H, Sk_p // bk, nq),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[
-            pl.BlockSpec((None, None, Sq_p, D),
-                         lambda b, h, kb, i: (b, h, 0, 0)),
-            kv_spec, kv_spec,
-        ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq_p, D), jnp.float32),
             jax.ShapeDtypeStruct((B, H, Sk_p, D), k.dtype),
             jax.ShapeDtypeStruct((B, H, Sk_p, D), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         # attn_bwd_ms.tokens finds the backward by this name alone
         name="flash_bwd",
-    )(_pad_rows(q, pq), _pad_rows(k, pk), _pad_rows(v, pk),
+        **how,
+    )(*tables, _pad_rows(q, pq), _pad_rows(k, pk), _pad_rows(v, pk),
       _pad_rows(do, pq), lse, delta)  # zero do: padded rows add nothing
-    return (dq[:, :, :S_q].astype(q.dtype), dk[:, :, :S_k], dv[:, :, :S_k])
+    dk, dv = dk[:, :, :S_k], dv[:, :, :S_k]
+    if group > 1:
+        dk, dv = (a.reshape(B, H // group, group, S_k, D)
+                  .astype(jnp.float32).sum(2).astype(a.dtype)
+                  for a in (dk, dv))
+    return dq[:, :, :S_q].astype(q.dtype), dk, dv
 
 
 # ---------------------------------------------------------------------------
@@ -475,21 +700,22 @@ def _decode_flash(q, k, v, lengths, sm_scale, interpret):
 # ---------------------------------------------------------------------------
 # the differentiable entry
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, sm_scale, causal, impl):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, sm_scale, causal, impl, mask=None):
     if impl == "xla":
-        return _ref_attention(q, k, v, sm_scale, causal, k.shape[2])
+        return _ref_attention(q, k, v, sm_scale, causal, k.shape[2], mask)
     return _pallas_forward(q, k, v, sm_scale, causal,
-                           impl == "interpret")
+                           impl == "interpret", mask=mask)
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, impl):
+def _flash_fwd(q, k, v, sm_scale, causal, impl, mask=None):
     fits = impl != "xla" and choose_tiles(
         q.shape[2], k.shape[2], q.shape[3], q.dtype.itemsize, backward=True)
     if not fits:  # the scan backward recomputes from q, k, v alone
-        return _flash(q, k, v, sm_scale, causal, impl), (q, k, v, None, None)
+        return (_flash(q, k, v, sm_scale, causal, impl, mask),
+                (q, k, v, None, None))
     o, lse = _pallas_forward(q, k, v, sm_scale, causal,
-                             impl == "interpret", with_lse=True)
+                             impl == "interpret", with_lse=True, mask=mask)
     return o, (q, k, v, o, lse)
 
 
@@ -535,7 +761,7 @@ def _scan_backward(q, k, v, do, sm_scale, causal):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _flash_bwd(sm_scale, causal, impl, res, do):
+def _flash_bwd(sm_scale, causal, impl, mask, res, do):
     """Counts, at trace time, which backward it lowered: the kernel, or
     the scan (the ``xla`` path, and any shape whose dq the kernel cannot
     keep in VMEM — loudly, in ``kernels.counters()``)."""
@@ -543,21 +769,34 @@ def _flash_bwd(sm_scale, causal, impl, res, do):
     with jax.named_scope("flash_bwd"):
         if lse is None:
             _count("flash_bwd_scan")
-            return _scan_backward(q, k, v, do, sm_scale, causal)
+            if mask is None and q.shape[1] == k.shape[1]:
+                return _scan_backward(q, k, v, do, sm_scale, causal)
+            # a mask spec or grouped heads: the oracle's own derivative
+            return jax.vjp(lambda q, k, v: _ref_attention(
+                q, k, v, sm_scale, causal, k.shape[2], mask), q, k, v)[1](do)
         _count("flash_bwd_pallas")
         return _pallas_backward(q, k, v, o, lse, do, sm_scale, causal,
-                                impl == "interpret")
+                                impl == "interpret", mask=mask)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, sm_scale=None, causal=False, use_pallas=None):
-    """Scaled dot-product attention over (B, H, S, D) tensors.
+def flash_attention(q, k, v, sm_scale=None, causal=False, use_pallas=None,
+                    mask=None):
+    """Scaled dot-product attention over (B, H, S, D) tensors; k and v
+    may hold H / group heads, query head h then reads head h // group.
 
     use_pallas: None = pallas on TPU / XLA elsewhere; True forces the
     kernel (interpreted off-TPU — slow, for testing); False forces XLA.
+    mask: a static spec of which scores live (``BlockDiffusionMask``),
+    in place of ``causal``.
     """
+    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads over {k.shape[1]} key "
+                         f"and {v.shape[1]} value heads")
+    if mask is not None and causal:
+        raise ValueError("give either causal=True or a mask spec")
     if causal and q.shape[-2] > k.shape[-2]:
         # bottom-right-aligned causal with S_q > S_k gives query rows a
         # negative offset — rows with zero visible keys would come out of
@@ -574,4 +813,6 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, use_pallas=None):
     else:
         impl = "xla"
     with jax.named_scope("attn"):
-        return _flash(q, k, v, float(sm_scale), bool(causal), impl)
+        if mask is None:    # the call as it always was
+            return _flash(q, k, v, float(sm_scale), bool(causal), impl)
+        return _flash(q, k, v, float(sm_scale), False, impl, mask)

@@ -7,6 +7,9 @@ from ..gluon.model_zoo import vision, get_model
 from .transformer import TransformerLM, TransformerBlock, \
     MultiHeadSelfAttention
 from .decoder import DecoderBlockLM
+from .moe_decoder import MoEDecoderLM, MoEDecoderBlock, \
+    GroupedQueryAttention
 
 __all__ = ["vision", "get_model", "TransformerLM", "TransformerBlock",
-           "MultiHeadSelfAttention", "DecoderBlockLM"]
+           "MultiHeadSelfAttention", "DecoderBlockLM", "MoEDecoderLM",
+           "MoEDecoderBlock", "GroupedQueryAttention"]

@@ -20,12 +20,14 @@ from .spmd import (all_reduce, all_reduce_coalesced, group_all_reduce,
                    SPMDTrainer, shard_batch, replicate, shard_params)
 from .ring_attention import ring_attention
 from .ulysses import ulysses_attention
-from .moe import moe_ffn, switch_router
+from .moe import (moe_ffn, switch_router, top_k_router, expert_ffn,
+                  expert_parallel_ffn)
 from .pipeline import pipeline_apply
 from .checkpoint import (save_sharded, load_sharded, save_trainer,
                          load_trainer)
 
-__all__ = ["moe_ffn", "switch_router", "pipeline_apply",
+__all__ = ["moe_ffn", "switch_router", "top_k_router", "expert_ffn",
+           "expert_parallel_ffn", "pipeline_apply",
            "save_sharded", "load_sharded", "save_trainer", "load_trainer",
            "make_mesh", "current_mesh", "mesh_scope", "device_count",
            "all_reduce", "all_reduce_coalesced", "group_all_reduce",

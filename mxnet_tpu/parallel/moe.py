@@ -13,9 +13,24 @@ auxiliary loss is returned alongside the output.
 Composes with data parallelism on a ('dp', 'ep') mesh: the batch shards
 over BOTH axes, expert weights shard over 'ep' and replicate over 'dp',
 so the all-to-alls ride within each dp row.
+
+Beside it, the drop-free layer today's models train with
+(``top_k_router`` + ``expert_ffn``): top-k of all the experts with
+renormalised weights, no capacity and no dropped token, SiLU-gated
+experts, and a layer that is told which experts it holds
+(``experts_held=(first, count)``) and computes exactly their part of the
+result. The assignments that land on held experts are sorted by expert
+into a bounded buffer of rows and go through the grouped matrix product
+(``kernels/grouped_matmul.py``), whose tiles stop at the last routed
+row: memory follows the expected number of assignments (times
+``capacity_factor``), time the actual number, and what overflows the
+buffer takes further passes through it, so nothing is ever dropped.
+``expert_parallel_ffn`` is the same layer over an 'ep' mesh axis, each
+device holding its slice of the experts.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -25,7 +40,8 @@ from jax import lax
 from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["moe_ffn", "switch_router", "moe_specs"]
+__all__ = ["moe_ffn", "switch_router", "moe_specs", "top_k_router",
+           "expert_ffn", "expert_parallel_ffn", "note_expert_rows"]
 
 
 def moe_specs(mesh, axis_name="ep", batch_axes=None):
@@ -152,3 +168,292 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, mesh=None, axis_name="ep",
         out_specs=(bspec, rep))
     return fn(place(x, bspec), place(gate_w, rep), place(w1, espec),
               place(b1, espec), place(w2, espec), place(b2, espec))
+
+
+# ---------------------------------------------------------------------------
+# drop-free top-k routing over the experts held here
+
+def top_k_router(x, gate_w, k, norm_topk_prob=True):
+    """Top-k routing over ALL experts: ``(idx (T, k) int32, gates (T, k)
+    float32)``. Logits accumulate and the softmax runs in float32; with
+    ``norm_topk_prob`` the k probabilities are divided by their sum."""
+    logits = jnp.dot(x, gate_w, preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, idx = lax.top_k(lax.stop_gradient(probs), k)
+    # the k probabilities picked through a one-hot mask: its derivative
+    # is dense too, where top_k's own would scatter
+    picked = idx[..., None] == jnp.arange(probs.shape[-1])[None, None]
+    gates = jnp.sum(jnp.where(picked, probs[:, None, :], 0.0), axis=-1)
+    if norm_topk_prob:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), gates
+
+
+def _layout(idx, first, count):
+    """Where each (token, choice) slot goes when the slots are sorted by
+    held expert (those on experts not held here last): ``order`` (sorted
+    position -> slot), ``rank`` (slot -> sorted position), each held
+    expert's ``starts`` and ``sizes`` in that order."""
+    local = idx.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    onehot = key[:, None] == jnp.arange(count + 1, dtype=key.dtype)[None]
+    sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)
+    starts = jnp.cumsum(sizes) - sizes
+    within = jnp.cumsum(onehot, axis=0, dtype=jnp.int32)
+    rank = starts[key] + jnp.take_along_axis(
+        within, key[:, None], axis=1)[:, 0] - 1
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    return order, rank, starts[:count], sizes[:count]
+
+
+def _token_runs(tok, valid, t):
+    """The buffer's rows sorted by token, for ``_sum_by_token``: ``by_tok``
+    (sorted position -> row), the sorted tokens ``stok`` (rows that are
+    padding sort last, as token ``t``), and for every token the sorted
+    position of its last row (``last``; ``has`` says it has one)."""
+    key = jnp.where(valid, tok, t)
+    by_tok = jnp.argsort(key).astype(jnp.int32)
+    stok = key[by_tok]
+    tokens = jnp.arange(t, dtype=stok.dtype)
+    last = jnp.searchsorted(stok, tokens, side="right").astype(jnp.int32) - 1
+    has = (last >= 0) & (stok[jnp.maximum(last, 0)] == tokens)
+    return by_tok, stok, jnp.maximum(last, 0), has
+
+
+#: rows of a tile of ``_sum_by_token``: a token's rows (one a choice) must
+#: not span three tiles
+_RUN_TILE = 256
+
+
+def _sum_by_token(vals, runs, most):
+    """``out[i] = sum of vals[r] over the rows r of token i`` in float32,
+    for ``vals`` (cap, D) and ``runs`` from ``_token_runs``. The rows are
+    brought into token order; within a tile of ``_RUN_TILE`` rows one
+    product with the 0/1 matrix "same token, not later" gives every row
+    the sum of its token's rows so far; a token's run (at most ``most``
+    rows) may straddle one tile boundary, where the tile before hands
+    over its last row's sum; each token reads its run's last row.
+    Everything is the buffer's size or the tokens', never tokens x
+    choices, and nothing scatters."""
+    by_tok, stok, last, has = runs
+    cap, d = vals.shape
+    if most > _RUN_TILE or cap % _RUN_TILE:
+        raise ValueError(f"{most} choices a token, a buffer of {cap} rows")
+    tiles = cap // _RUN_TILE
+    s = jnp.take(vals, by_tok, axis=0).reshape(tiles, _RUN_TILE, d)
+    tk = stok.reshape(tiles, _RUN_TILE)
+    i = lax.broadcasted_iota(jnp.int32, (_RUN_TILE, _RUN_TILE), 0)
+    j = lax.broadcasted_iota(jnp.int32, (_RUN_TILE, _RUN_TILE), 1)
+    so_far = (tk[:, :, None] == tk[:, None, :]) & (j <= i)[None]
+    sums = jnp.einsum("gij,gjd->gid", so_far.astype(s.dtype), s,
+                      preferred_element_type=jnp.float32)
+    # the run that began in the tile before: its sum there, where the
+    # token is the same
+    before = jnp.pad(sums[:-1, -1], ((1, 0), (0, 0)))
+    tok_before = jnp.pad(tk[:-1, -1], (1, 0), constant_values=-1)
+    carried = tk == tok_before[:, None]
+    sums = sums + jnp.where(carried[:, :, None], before[:, None, :], 0.0)
+    out = jnp.take(sums.reshape(cap, d), last, axis=0)
+    return jnp.where(has[:, None], out, 0.0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, tok, runs, most):
+    """``x[tok]``: the buffer's rows. Its derivative sums each token's
+    rows (``_sum_by_token``), where autodiff would scatter."""
+    return jnp.take(x, tok, axis=0)
+
+
+def _dispatch_bwd(most, runs, dxg):
+    return _sum_by_token(dxg, runs, most).astype(dxg.dtype), None, None
+
+
+_dispatch.defvjp(
+    lambda x, tok, runs, most: (jnp.take(x, tok, axis=0), runs),
+    _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(yo, gates, tok, row_gate, rows, live, runs):
+    """``y[t] = sum_j gates[t, j] * yo[rows[t, j]]`` over the slots that
+    are live in this pass: each row times its slot's gate (``row_gate``,
+    0 on padding) in ``yo``'s type, summed by token in float32."""
+    weighted = (row_gate[:, None] * yo.astype(jnp.float32)).astype(yo.dtype)
+    return _sum_by_token(weighted, runs, gates.shape[1]).astype(yo.dtype)
+
+
+def _combine_fwd(yo, gates, tok, row_gate, rows, live, runs):
+    return (_combine(yo, gates, tok, row_gate, rows, live, runs),
+            (yo, tok, row_gate, rows, live))
+
+
+def _combine_bwd(res, dy):
+    yo, tok, row_gate, rows, live = res
+    dy_rows = jnp.take(dy, tok, axis=0).astype(jnp.float32)
+    dyo = (row_gate[:, None] * dy_rows).astype(yo.dtype)
+    # a slot's gate meets one row: its derivative is that row's
+    dgate_row = jnp.sum(dy_rows * yo.astype(jnp.float32), axis=-1)
+    dgates = jnp.where(live, jnp.take(dgate_row, rows.reshape(-1))
+                       .reshape(rows.shape), 0.0)
+    return dyo, dgates, None, None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _ffn_pass(p, x, gates, w13, w2, order, rank, starts, sizes, cap,
+              use_pallas):
+    """The part of the result that the held assignments at sorted
+    positions [p * cap, (p + 1) * cap) give: gather their tokens' rows,
+    the two grouped products around the SiLU gate, and each token's
+    weighted sum of its rows."""
+    from ..kernels.grouped_matmul import grouped_matmul
+
+    t, k = gates.shape
+    lo = p * cap
+    held = jnp.sum(sizes)
+    pos = lo + jnp.arange(cap, dtype=jnp.int32)
+    slot = order[jnp.minimum(pos, order.shape[0] - 1)]
+    valid = pos < held
+    tok = jnp.where(valid, slot // k, 0)
+    row_gate = jnp.where(valid, gates.reshape(-1)[slot], 0.0)
+    rows = rank.reshape(t, k) - lo
+    live = (rank.reshape(t, k) < held) & (rows >= 0) & (rows < cap)
+    rows = jnp.clip(rows, 0, cap - 1)
+    ends = starts + sizes
+    sizes_p = jnp.clip(jnp.minimum(ends, lo + cap) - jnp.maximum(starts, lo),
+                       0, cap)
+    f = w2.shape[1]
+    runs = _token_runs(tok, valid, t)
+    xg = _dispatch(x, tok, runs, k)
+    h = grouped_matmul(xg, w13, sizes_p, use_pallas)
+    a = (jax.nn.silu(h[:, :f].astype(jnp.float32))
+         * h[:, f:].astype(jnp.float32)).astype(x.dtype)
+    yo = grouped_matmul(a, w2, sizes_p, use_pallas)
+    return _combine(yo, gates, tok, row_gate, rows, live, runs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _ffn(x, gates, w13, w2, order, rank, starts, sizes, cap, use_pallas):
+    return _ffn_fwd(x, gates, w13, w2, order, rank, starts, sizes, cap,
+                    use_pallas)[0]
+
+
+def _passes(sizes, cap):
+    return (jnp.sum(sizes) + cap - 1) // cap
+
+
+def _ffn_fwd(x, gates, w13, w2, order, rank, starts, sizes, cap, use_pallas):
+    """Pass 0 in the open, its residuals kept; what overflows the buffer
+    (none, as a rule) in a loop that keeps nothing."""
+    ints = (order, rank, starts, sizes)
+
+    def one(p, x, gates, w13, w2):
+        return _ffn_pass(p, x, gates, w13, w2, *ints, cap, use_pallas)
+
+    y, vjp0 = jax.vjp(functools.partial(one, 0), x, gates, w13, w2)
+    y = lax.while_loop(
+        lambda c: c[0] < _passes(sizes, cap),
+        lambda c: (c[0] + 1, c[1] + one(c[0], x, gates, w13, w2)),
+        (jnp.int32(1), y))[1]
+    return y, (vjp0, x, gates, w13, w2, ints)
+
+
+def _ffn_bwd(cap, use_pallas, res, dy):
+    """Pass 0 from its residuals; the overflow passes recompute."""
+    vjp0, x, gates, w13, w2, ints = res
+
+    def more(c):
+        p, grads = c
+        _, vjp = jax.vjp(lambda *a: _ffn_pass(p, *a, *ints, cap, use_pallas),
+                         x, gates, w13, w2)
+        return p + 1, tuple(g + d for g, d in zip(grads, vjp(dy)))
+
+    grads = lax.while_loop(lambda c: c[0] < _passes(ints[3], cap), more,
+                           (jnp.int32(1), tuple(vjp0(dy))))[1]
+    return (*grads, None, None, None, None)
+
+
+_ffn.defvjp(_ffn_fwd, _ffn_bwd)
+
+
+def expert_ffn(x, idx, gates, w13, w2, experts_held=None, num_experts=None,
+               capacity_factor=1.5, use_pallas=None):
+    """The held experts' part of a top-k mixture: ``(y (T, D), rows
+    (count,) int32)``.
+
+    x (T, D) tokens; idx, gates (T, k) from ``top_k_router`` over all
+    ``num_experts``; w13 (count, D, 2F) each held expert's gate and up
+    projections side by side, w2 (count, F, D) its down projection;
+    ``experts_held=(first, count)`` (``first`` may be traced). Every
+    assignment that lands on a held expert is computed, none is dropped:
+    ``y[t] = sum over those of gate * (silu(x W_gate) * (x W_up)) W_down``.
+    The row buffer holds ``capacity_factor`` times the expected number of
+    held assignments; more than that take further passes. What moves the
+    rows around costs time in proportion to the buffer, a further pass
+    that of a whole buffer: at a quarter over the expected rows a layer's
+    load passed the buffer in one batch in a hundred of seeded weights
+    (PERF.md, PR 30), at half over it did not. ``rows`` counts the
+    assignments each held expert got."""
+    from ..kernels.grouped_matmul import ROWS
+
+    count = w13.shape[0]
+    first = 0 if experts_held is None else experts_held[0]
+    num_experts = num_experts or count
+    t, k = idx.shape
+    expect = t * k * count / num_experts
+    cap = max(1, math.ceil(capacity_factor * expect / ROWS)) * ROWS
+    cap = min(cap, -(-t * k // ROWS) * ROWS)
+    order, rank, starts, sizes = _layout(idx, first, count)
+    with jax.named_scope("moe"):
+        y = _ffn(x, gates.astype(jnp.float32), w13, w2, order, rank, starts,
+                 sizes, cap, use_pallas)
+    return y, sizes
+
+
+def expert_parallel_ffn(x, gate_w, w13, w2, k, mesh, axis_name="ep",
+                        norm_topk_prob=True, use_pallas=None):
+    """The same layer with the experts sharded over ``axis_name``: every
+    device gathers the axis's tokens, routes them over all the experts,
+    computes the part its own slice of ``w13`` / ``w2`` gives
+    (``experts_held`` from its place on the axis) and the parts are
+    summed back to the tokens' owners. x (T, D) sharded over the axis on
+    its rows; returns ``(y, rows (E,))``."""
+    n_experts = gate_w.shape[-1]
+
+    def local(xl, gw, w13l, w2l):
+        xa = lax.all_gather(xl, axis_name, axis=0, tiled=True)
+        idx, gates = top_k_router(xa, gw, k, norm_topk_prob)
+        held = (lax.axis_index(axis_name) * w13l.shape[0], w13l.shape[0])
+        y, rows = expert_ffn(xa, idx, gates, w13l, w2l, held, n_experts,
+                             use_pallas=use_pallas)
+        return lax.psum_scatter(y, axis_name, scatter_dimension=0,
+                                tiled=True), rows
+
+    espec = P(axis_name)
+    return _shard_map(local, mesh=mesh, in_specs=(espec, P(), espec, espec),
+                      out_specs=(espec, espec), check_vma=False)(
+        x, gate_w, w13, w2)
+
+
+# what the compiled step counted, published when its arrays are ready
+# (``SPMDTrainer`` polls; nothing waits for the device)
+_MOE_COUNTERS = None
+
+
+def note_expert_rows(rows_by_layer):
+    """Publish one step's per-expert row counts (one host array a layer)
+    as ``telemetry`` counters: ``moe/steps``, ``moe/assignments_held``
+    (summed over layers and steps), ``moe/max_expert_rows`` (the largest
+    group of the last step read)."""
+    global _MOE_COUNTERS
+    if _MOE_COUNTERS is None:
+        from ..telemetry import metrics
+
+        _MOE_COUNTERS = metrics.counter_family(
+            "moe", {"steps": 0, "assignments_held": 0, "max_expert_rows": 0})
+    _MOE_COUNTERS.add("steps")
+    _MOE_COUNTERS.add("assignments_held",
+                      int(sum(float(r.sum()) for r in rows_by_layer)))
+    _MOE_COUNTERS.set("max_expert_rows",
+                      int(max(float(r.max()) for r in rows_by_layer)))

@@ -12,6 +12,7 @@ PartitionSpecs (new capability vs the reference's __ctx_group__ placement).
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 
@@ -369,6 +370,13 @@ class SPMDTrainer:
         shardings = shard_params(
             dict(zip(names, self._params)), mesh, self._param_rules)
         self._pshard = [shardings[n] for n in names]
+        # state a layer wants published (``Parameter.step_stat``: TopKMoE's
+        # rows per expert): the step hands out a copy beside the loss,
+        # since the parameter itself is donated to the next step
+        stat_idx = [i for i, p in enumerate(self._params)
+                    if getattr(p, "step_stat", None) is not None]
+        self._stat_sinks = [self._params[i].step_stat for i in stat_idx]
+        self._stats_pending = collections.deque(maxlen=8)
         batch_shard = NamedSharding(mesh, P(self._axis))
         rep = NamedSharding(mesh, P())
         pnds = [p._ndarray for p in self._params]
@@ -435,7 +443,8 @@ class SPMDTrainer:
                         w2, s2 = update(w, g, s, t)
                         new_params.append(w2)
                         new_states.append(s2)
-            return lval, new_params, new_states, (key, t)
+            stats = [new_params[i] + 0 for i in stat_idx]
+            return lval, new_params, new_states, (key, t), stats
 
         with _telem.span("spmd.build.place", cat="train",
                          params=len(self._params)) as sp:
@@ -461,7 +470,8 @@ class SPMDTrainer:
             step, label="spmd_step",
             in_shardings=(self._pshard, state_shards, aux_shard,
                           batch_shard, batch_shard),
-            out_shardings=(rep, self._pshard, state_shards, aux_shard),
+            out_shardings=(rep, self._pshard, state_shards, aux_shard,
+                           [rep] * len(stat_idx)),
             donate_argnums=(0, 1, 2))
 
     # -- public -----------------------------------------------------------
@@ -473,7 +483,9 @@ class SPMDTrainer:
         """Run one sharded training step; returns the (replicated) loss."""
         self._ensure_built(x, y)
         if not _telem.tracing():  # MXNET_TELEMETRY=0: this one check
-            return NDArray(self._launch(*self._place(x, y)))
+            lval = self._launch(*self._place(x, y))
+            self._stats_pending.clear()     # counters are telemetry too
+            return NDArray(lval)
         with _telem.span("spmd.step", cat="train", step=self._t + 1):
             with _telem.span("spmd.step.place", cat="train") as sp:
                 xd, yd = self._place(x, y)
@@ -484,6 +496,7 @@ class SPMDTrainer:
                 lval = self._launch(xd, yd)
         _COUNTERS.add("steps")
         _COUNTERS.add("placed_bytes", nbytes)
+        self._publish_stats()
         return NDArray(lval)
 
     def _place(self, x, y):
@@ -492,9 +505,21 @@ class SPMDTrainer:
 
     def _launch(self, xd, yd):
         self._t += 1
-        lval, self._param_vals, self._states, self._aux = self._compiled(
-            self._param_vals, self._states, self._aux, xd, yd)
+        lval, self._param_vals, self._states, self._aux, stats = \
+            self._compiled(self._param_vals, self._states, self._aux, xd, yd)
+        if stats:
+            self._stats_pending.append(stats)
         return lval
+
+    def _publish_stats(self):
+        """Hand the steps' published state whose arrays the device has
+        finished to its sinks; never waits."""
+        pending = self._stats_pending
+        while pending and all(a.is_ready() for a in pending[0]):
+            host = jax.device_get(pending.popleft())
+            for sink in dict.fromkeys(self._stat_sinks):
+                sink([h for h, s in zip(host, self._stat_sinks)
+                      if s is sink])
 
     def param_arrays(self):
         """``{name: jax.Array}`` — the device-resident parameter values

@@ -117,6 +117,114 @@ def test_flash_backward_compiles(one_chip, shape, dtype):
     assert after.get("flash_bwd_scan", 0) == before.get("flash_bwd_scan", 0)
 
 
+@pytest.mark.parametrize("b,hq,hkv,seq,d", [
+    (2, 32, 4, 4096, 128),     # the cell sdar30b-train-bd-s4096
+    (1, 8, 8, 1024, 64),       # no grouping, D=64
+])
+def test_masked_grouped_flash_compiles(one_chip, b, hq, hkv, seq, d):
+    """The block-diffusion mask over 2 x seq positions with grouped
+    heads, under ``jax.grad`` at the chooser's tiles: both kernels keep
+    their names (the table of tile kinds rides in as a prefetched
+    scalar), and the cell's counter counts the lowering."""
+    mask = fa.BlockDiffusionMask(seq, 4)
+    dtype = jnp.bfloat16
+
+    def loss(q, k, v):
+        with jax.named_scope("attn"):
+            o = _flash(q, k, v, d ** -0.5, False, "pallas", mask)
+        return o.astype(jnp.float32).sum()
+
+    before = kernels.counters()
+    c = _compile(jax.grad(loss, (0, 1, 2)), one_chip,
+                 ((b, hq, 2 * seq, d), dtype), ((b, hkv, 2 * seq, d), dtype),
+                 ((b, hkv, 2 * seq, d), dtype))
+    _assert_kernel(c, "flash_fwd")
+    _assert_kernel(c, "flash_bwd")
+    assert not re.search(r"\bwhile\(", c.as_text())
+    after = kernels.counters()
+    assert after["flash_mask_pallas"] > before.get("flash_mask_pallas", 0)
+    assert after.get("flash_bwd_scan", 0) == before.get("flash_bwd_scan", 0)
+
+
+@pytest.mark.parametrize("m,k,n,g,dtype", [
+    (16384, 2048, 1536, 16, jnp.bfloat16),  # the cell's gate + up product
+    (16384, 768, 2048, 16, jnp.bfloat16),   # and its down product
+    (512, 128, 128, 3, jnp.bfloat16),
+    # float32 blocks are twice as large: at 512 columns dlhs's block of
+    # rhs and its transpose overran VMEM on the chip (my chip run, PR 30)
+    (4096, 768, 2048, 16, jnp.float32),
+    (4096, 2048, 1536, 16, jnp.float32),
+])
+def test_grouped_matmul_compiles(one_chip, m, k, n, g, dtype):
+    """The three grouped products under ``jax.grad`` at the tiles the
+    budget allows, each under the instruction name ``moe_gmm_ms.tokens``
+    finds it by."""
+    from mxnet_tpu.kernels import grouped_matmul as gm
+
+    assert gm.eligible(m, k, n, jnp.dtype(dtype).itemsize)
+
+    def loss(lhs, rhs, sizes):
+        with jax.named_scope("moe_gmm"):
+            out = gm._gmm(lhs, rhs, sizes, "pallas")
+        return out.astype(jnp.float32).sum()
+
+    # the value too: a sum's gradient alone needs no forward product;
+    # float32 as chip_smoke.py runs it, every product at ``highest``,
+    # whose three bfloat16 parts of each operand are VMEM too
+    with jax.default_matmul_precision(
+            "highest" if dtype == jnp.float32 else "default"):
+        c = _compile(jax.value_and_grad(loss, (0, 1)), one_chip,
+                     ((m, k), dtype), ((g, k, n), dtype), ((g,), jnp.int32))
+    for name in ("moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"):
+        _assert_kernel(c, name)
+
+
+def test_kernels_keep_their_names_inside_the_trainers_step(one_chip,
+                                                           monkeypatch):
+    """``SPMDTrainer``'s own step function of a small ``MoEDecoderLM``
+    under block diffusion, compiled for the chip: the two flash kernels
+    and the three grouped products are there under the instruction names
+    the benchmark's readers go by, with ``jvp(fwd)`` and its transpose
+    around them, and the counters count kernels and no plain twin. The
+    trainer is built on the CPU as ever; only the traced step goes to
+    the described chip, the kernels chosen as on a TPU."""
+    import numpy as onp
+
+    from mxnet_tpu import models, parallel
+    from mxnet_tpu.gluon.loss import L2Loss
+
+    net = models.MoEDecoderLM(
+        vocab_size=256, embed_dim=128, num_layers=2, num_heads=2,
+        num_kv_heads=1, head_dim=128, num_experts=8, expert_dim=128,
+        top_k=2, experts_held=(0, 4), attention={"block_length": 4})
+    net.initialize()
+    trainer = parallel.SPMDTrainer(
+        net, L2Loss(), optimizer="adamw",
+        optimizer_params={"learning_rate": 1e-3},
+        mesh=parallel.make_mesh({"dp": 1}, devices=jax.devices()[:1]),
+        compute_dtype="bfloat16")
+    x = onp.zeros((2, 128), "int32")
+    y = onp.zeros((2, 64, 256), "float32")
+    trainer._ensure_built(x, y)
+    step = trainer._compiled.__wrapped__
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (trainer._param_vals, trainer._states, trainer._aux,
+         jnp.asarray(x), jnp.asarray(y)))
+    before = kernels.counters()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = jax.jit(step).lower(*shapes).compile()  # graft-lint: allow(jit-nocache)
+    monkeypatch.undo()
+    for name in ("flash_fwd", "flash_bwd", "moe_gmm_fwd", "moe_gmm_dlhs",
+                 "moe_gmm_drhs"):
+        _assert_kernel(c, name)
+    after = kernels.counters()
+    for name in ("flash_mask_pallas", "flash_bwd_pallas", "moe_gmm_pallas"):
+        assert after.get(name, 0) > before.get(name, 0), name
+    for name in ("flash_bwd_scan", "moe_gmm_plain"):
+        assert after.get(name, 0) == before.get(name, 0), name
+
+
 @pytest.mark.parametrize("b,h,s,d,dtype", [
     (8, 12, 1024, 64, jnp.float32),
     (32, 16, 4096, 128, jnp.float32),
