@@ -179,6 +179,24 @@ def test_grouped_matmul_compiles(one_chip, m, k, n, g, dtype):
         _assert_kernel(c, name)
 
 
+def _compile_trainer_step(trainer, x, y, one_chip, monkeypatch):
+    """``SPMDTrainer``'s own step function for the batch (x, y), compiled
+    for the described chip with its parameters, optimizer state and
+    counters donated as the trainer donates them. The trainer is built
+    on the CPU as ever; only the traced step goes to the chip, the
+    kernels chosen as on a TPU."""
+    trainer._ensure_built(x, y)
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (trainer._param_vals, trainer._states, trainer._aux,
+         jnp.asarray(x), jnp.asarray(y)))
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        return jax.jit(  # graft-lint: allow(jit-nocache)
+            trainer._compiled.__wrapped__,
+            donate_argnums=(0, 1, 2)).lower(*shapes).compile()
+
+
 def test_kernels_keep_their_names_inside_the_trainers_step(one_chip,
                                                            monkeypatch):
     """``SPMDTrainer``'s own step function of a small ``MoEDecoderLM``
@@ -205,16 +223,9 @@ def test_kernels_keep_their_names_inside_the_trainers_step(one_chip,
         compute_dtype="bfloat16")
     x = onp.zeros((2, 128), "int32")
     y = onp.zeros((2, 64, 256), "float32")
-    trainer._ensure_built(x, y)
-    step = trainer._compiled.__wrapped__
-    shapes = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        (trainer._param_vals, trainer._states, trainer._aux,
-         jnp.asarray(x), jnp.asarray(y)))
+    trainer._ensure_built(x, y)     # its eager forward takes the CPU's twins
     before = kernels.counters()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    c = jax.jit(step).lower(*shapes).compile()  # graft-lint: allow(jit-nocache)
-    monkeypatch.undo()
+    c = _compile_trainer_step(trainer, x, y, one_chip, monkeypatch)
     for name in ("flash_fwd", "flash_bwd", "moe_gmm_fwd", "moe_gmm_dlhs",
                  "moe_gmm_drhs"):
         _assert_kernel(c, name)
@@ -223,6 +234,121 @@ def test_kernels_keep_their_names_inside_the_trainers_step(one_chip,
         assert after.get(name, 0) > before.get(name, 0), name
     for name in ("flash_bwd_scan", "moe_gmm_plain"):
         assert after.get(name, 0) == before.get(name, 0), name
+
+
+@pytest.fixture(scope="module")
+def opt_layer():
+    """One ``TransformerLM`` layer at the widths of the cell
+    ``opt1.3b-train-s2048`` (hidden 2048, ffn 8192, 32 heads, 2 x 2048
+    tokens); the vocabulary cut to 4096 to keep the compile quick."""
+    from mxnet_tpu import models, nd
+
+    net = models.TransformerLM(
+        vocab_size=4096, embed_dim=2048, num_layers=1, num_heads=32,
+        ffn_dim=8192, max_len=2048, tie_weights=True)
+    net.initialize()
+    net(nd.zeros((1, 8), dtype="int32"))        # deferred shapes
+    return net
+
+
+def _computations(text):
+    """``{name: body}`` of an optimised HLO module's computations."""
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text,
+        re.M | re.S)}
+
+
+def test_no_norm_is_recomputed_inside_a_weight_gradient(one_chip, opt_layer,
+                                                        monkeypatch):
+    """``SPMDTrainer``'s step of the OPT cell's layer (bf16 on float32
+    masters, adamw), compiled for the chip. XLA fuses each dense
+    weight's gradient product with its Adam update (``convolution`` and
+    the update's ``sqrt`` / ``divide`` in one computation); a norm's
+    output reaches that product as a stored array
+    (``ops_nn.stored_residual``), so no such computation holds a
+    ``rsqrt`` or, as a nested fusion, the cloned normalise-scale-shift.
+    (The activation's ``maximum`` may be cloned in: it costs the product
+    nothing.) Without the helper the q|k|v and ffn1 gradients hold the
+    clone, which this test's second half shows."""
+    import numpy as onp
+
+    from mxnet_tpu import parallel
+    from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
+    from mxnet_tpu.ndarray import ops_nn
+
+    def compiled_step():
+        trainer = parallel.SPMDTrainer(
+            opt_layer, SoftmaxCrossEntropyLoss(), optimizer="adamw",
+            optimizer_params={"learning_rate": 1e-4},
+            mesh=parallel.make_mesh({"dp": 1}, devices=jax.devices()[:1]),
+            compute_dtype="bfloat16")
+        x = onp.zeros((2, 2048), "int32")
+        return _compile_trainer_step(trainer, x, x, one_chip,
+                                     monkeypatch).as_text()
+
+    def cloned_norms(text):
+        comps = _computations(text)
+        found = []
+        for name, body in comps.items():
+            if not ("convolution(" in body and " sqrt(" in body
+                    and " divide(" in body):
+                continue
+            nested = re.findall(r" fusion\([^\n]*calls=%([\w.\-]+)", body)
+            if " rsqrt(" in body or any(
+                    " multiply(" in comps[n] or " rsqrt(" in comps[n]
+                    for n in nested):
+                found.append(name)
+        return found
+
+    before = kernels.counters().get("norm_out_stored", 0)
+    text = compiled_step()
+    assert kernels.counters()["norm_out_stored"] == before + 3  # ln1 ln2 ln_f
+    assert "convolution(" in text and cloned_norms(text) == []
+    monkeypatch.setattr(ops_nn, "stored_residual", lambda y: y)
+    assert len(cloned_norms(compiled_step())) == 2
+
+
+def test_forward_only_trace_compiles_as_without_the_stored_residual(
+        one_chip, opt_layer, monkeypatch):
+    """A forward-only ``jax.jit`` of the same layer (what ``hybridize``
+    and ``serving.InferenceSession`` trace): the helper's primal is the
+    identity, so the optimised HLO is the one the layer compiles to
+    without it, instruction for instruction (source lines in the
+    metadata aside), and nothing is counted."""
+    from mxnet_tpu import autograd, nd
+    from mxnet_tpu.ndarray import ops_nn
+
+    net = opt_layer
+    params = [p._ndarray for _, p in sorted(net.collect_params().items())]
+
+    def forward(values, tokens):
+        saved = [p._data for p in params]
+        try:
+            for p, v in zip(params, values):
+                p._data = v.astype(jnp.bfloat16)
+            with autograd.pause(train_mode=False):
+                return net.forward(nd.NDArray(tokens)).data
+        finally:
+            for p, v in zip(params, saved):
+                p._data = v
+
+    shapes = ([jax.ShapeDtypeStruct(p.shape, jnp.float32, sharding=one_chip)
+               for p in params],
+              jax.ShapeDtypeStruct((2, 2048), jnp.int32, sharding=one_chip))
+
+    def hlo():
+        with monkeypatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            text = jax.jit(forward).lower(  # graft-lint: allow(jit-nocache)
+                *shapes).compile().as_text()
+        return re.sub(r", metadata=\{[^}]*\}", "", text)
+
+    before = kernels.counters().get("norm_out_stored", 0)
+    with_helper = hlo()
+    assert kernels.counters().get("norm_out_stored", 0) == before
+    monkeypatch.setattr(ops_nn, "stored_residual", lambda y: y)
+    assert with_helper == hlo()
+    assert "flash_fwd" in with_helper
 
 
 @pytest.mark.parametrize("b,h,s,d,dtype", [

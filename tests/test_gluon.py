@@ -109,6 +109,75 @@ def test_layernorm():
                                 atol=1e-5)
 
 
+def _plain_norm(kind, x, gamma, beta, eps):
+    """LayerNorm / RMSNorm written out: the statistics of RMSNorm in
+    float32, those of LayerNorm in the input's type, as the blocks keep
+    them."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    if kind == "rms":
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+        return (xf * lax.rsqrt(ms + eps)
+                * gamma.astype(jnp.float32)).astype(x.dtype)
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.var(x, axis=-1, keepdims=True)
+    return (x - mean) * lax.rsqrt(var + eps) * gamma + beta
+
+
+@pytest.mark.parametrize("kind", ["layer", "rms"])
+@pytest.mark.parametrize("dtype,rtol,atol", [
+    ("float32", 1e-5, 1e-5), ("bfloat16", 6e-2, 2e-2)])
+def test_norm_blocks_value_and_grad_vs_plain_formula(kind, dtype, rtol,
+                                                     atol):
+    """``nn.LayerNorm`` and ``nn.RMSNorm`` under ``autograd.record``:
+    the output and the gradients of input and gamma against ``jax.grad``
+    of the formula written out. ``LayerNorm``'s output is a stored
+    residual under differentiation (``ops_nn.stored_residual``): counted
+    once for the recorded call, not at all for the forward-only one.
+    ``RMSNorm`` does not call the helper (the one cell that trains with
+    it lost 0.3% by it on the chip, PERF.md section 6, PR 31) and counts
+    nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import kernels
+
+    rs = onp.random.RandomState(7)
+    eps = 1e-5 if kind == "layer" else 1e-6
+    layer = (nn.LayerNorm(in_channels=6, epsilon=eps) if kind == "layer"
+             else nn.RMSNorm(epsilon=eps, in_channels=6))
+    layer.initialize()
+    layer.cast(dtype)
+    gamma = (rs.rand(6) + 0.5).astype("f")
+    layer.gamma.set_data(nd.array(gamma, dtype=dtype))
+    x = nd.array((rs.rand(3, 4, 6) * 4 - 2).astype("f"), dtype=dtype)
+    w = jnp.asarray((rs.rand(3, 4, 6) * 2 - 1).astype("f"), dtype)
+
+    before = kernels.counters().get("norm_out_stored", 0)
+    out = layer(x)
+    assert kernels.counters().get("norm_out_stored", 0) == before
+    x.attach_grad()
+    with autograd.record():
+        y = nd.sum((layer(x) * nd.array(w, dtype=dtype)).astype("float32"))
+    y.backward()
+    assert kernels.counters().get("norm_out_stored", 0) == \
+        before + (kind == "layer")
+
+    xj, gj = jnp.asarray(x.asnumpy(), dtype), jnp.asarray(gamma, dtype)
+    bj = jnp.zeros((6,), dtype)
+    ref = _plain_norm(kind, xj, gj, bj, eps)
+    dx, dg = jax.grad(lambda a, g: jnp.sum(
+        (_plain_norm(kind, a, g, bj, eps) * w).astype(jnp.float32)),
+        (0, 1))(xj, gj)
+    for got, want in ((out, ref), (x.grad, dx), (layer.gamma.grad(), dg)):
+        want = onp.asarray(want, "f")
+        onp.testing.assert_allclose(
+            got.asnumpy().astype("f"), want, rtol=rtol,
+            atol=atol * max(1.0, onp.abs(want).max()))
+
+
 def test_embedding():
     layer = nn.Embedding(10, 4)
     layer.initialize()

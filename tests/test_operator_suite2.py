@@ -321,6 +321,75 @@ def test_op_layer_norm_vs_numpy():
     assert_almost_equal(out.asnumpy(), expect, rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("dtype,rtol,atol", [
+    ("float32", 1e-4, 1e-5),    # this file's layer_norm tolerance
+    ("bfloat16", 6e-2, 2e-2),   # test_op_dtype_sweep.py's bfloat16 rung
+])
+def test_op_layer_norm_value_and_grad_vs_plain_formula(dtype, rtol, atol):
+    """The op hands its output on as a stored residual under
+    differentiation (``ops_nn.stored_residual``); value and every
+    gradient are those of ``jax.grad`` of the formula written out."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from mxnet_tpu.ndarray.registry import get_op
+
+    op = get_op("layer_norm").fn
+
+    def plain(x, g, b):
+        mean = jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.var(x, axis=-1, keepdims=True)
+        return (x - mean) * lax.rsqrt(var + 1e-5) * g + b
+
+    x, g, b = (jnp.asarray(a, dtype) for a in (_x((4, 6)), _x((6,)),
+                                                _x((6,))))
+    w = jnp.asarray(_x((4, 6)), dtype)    # a cotangent that is not all ones
+
+    def loss(fn):
+        return lambda *a: jnp.sum((fn(*a) * w).astype(jnp.float32))
+
+    got, got_grads = jax.value_and_grad(loss(op), (0, 1, 2))(x, g, b)
+    ref, ref_grads = jax.value_and_grad(loss(plain), (0, 1, 2))(x, g, b)
+    assert_almost_equal(onp.asarray(op(x, g, b), "f"),
+                        onp.asarray(plain(x, g, b), "f"), rtol=rtol, atol=atol)
+    assert_almost_equal(float(got), float(ref), rtol=rtol, atol=atol)
+    for a, r in zip(got_grads, ref_grads):
+        assert a.dtype == r.dtype
+        r = onp.asarray(r, "f")
+        assert_almost_equal(onp.asarray(a, "f"), r, rtol=rtol,
+                            atol=atol * max(1.0, onp.abs(r).max()))
+
+
+def test_op_layer_norm_counts_stored_outputs_only_when_differentiated():
+    """``kernels.counters()["norm_out_stored"]``: nothing for a forward
+    call, eager or jitted; one for each norm output inside one
+    differentiated trace."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import kernels
+    from mxnet_tpu.ndarray.registry import get_op
+
+    op = get_op("layer_norm").fn
+    x, g, b = jnp.asarray(_x((4, 6))), jnp.asarray(_x((6,))), \
+        jnp.asarray(_x((6,)))
+    before = kernels.counters().get("norm_out_stored", 0)
+    nd.layer_norm(nd.array(_x((4, 6))), nd.array(_x((6,))),
+                  nd.array(_x((6,)))).asnumpy()
+    jax.jit(op)(x, g, b).block_until_ready()
+    assert kernels.counters().get("norm_out_stored", 0) == before
+    jax.grad(lambda *a: op(*a).sum())(x, g, b)
+    assert kernels.counters()["norm_out_stored"] == before + 1
+    xv = nd.array(_x((4, 6)))
+    xv.attach_grad()
+    with autograd.record():
+        y = nd.sum(nd.layer_norm(xv, nd.array(_x((6,))), nd.array(_x((6,)))))
+    y.backward()
+    assert xv.grad.shape == (4, 6)
+    assert kernels.counters()["norm_out_stored"] == before + 2
+
+
 def test_op_instance_group_norm():
     x = _x((2, 4, 3, 3))
     g, b = _x((4,)), _x((4,))
