@@ -3,7 +3,7 @@
 Any process that should run on the host CPU (tests, dryruns, host-only
 benches) sets ``JAX_PLATFORMS=cpu`` — and, for mesh tests, the
 ``--xla_force_host_platform_device_count`` virtual-device flag — before
-first backend use. Shared by ``bench.py``, ``__graft_entry__.py`` and
+first backend use. Shared by ``__graft_entry__.py`` and
 ``tests/conftest.py`` so it lives in exactly one place. Lives at the repo
 root (not inside ``mxnet_tpu``) because it must be importable before the
 package's heavy ``__init__`` touches jax.

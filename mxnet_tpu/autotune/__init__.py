@@ -15,8 +15,8 @@ Pieces (each in its module):
   declare ``THRESHOLD = declare_decision(name, candidates, default)``.
 - :mod:`.records` — TuningRecord store: memory/disk/remote tiers keyed
   by artifact fingerprints, plus the ``autotune`` salt provider.
-- :mod:`.tuner` — budgeted candidate sweep over the shared
-  paired-median harness (``benchmark/_measure.py``).
+- :mod:`.tuner` — budgeted candidate sweep over the paired-median
+  harness (:mod:`._measure`).
 - here — the knob, the counters, and :func:`lookup`, the
   consult-before-heuristic hook the cost models call.
 

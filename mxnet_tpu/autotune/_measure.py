@@ -1,10 +1,6 @@
-"""Shared paired-median measurement methodology.
+"""Paired-median measurement: how the tuner prices a candidate.
 
-Round 18's telemetry bench and round 22's lock-witness bench each
-carried a private copy of the same discipline; round 24's autotuner is
-a third consumer, so the implementation lives HERE once:
-
-measure back-to-back (test, base) pairs and take the MEDIAN of the
+Measure back-to-back (test, base) pairs and take the MEDIAN of the
 per-pair ratios. CPU-frequency/scheduler drift moves on a scale of
 seconds, so it hits both halves of an adjacent pair equally and
 cancels in the ratio — where best-of-independent-runs would credit
@@ -20,16 +16,15 @@ from __future__ import annotations
 
 import statistics
 
-__all__ = ["paired_overhead", "paired_speedup"]
+__all__ = ["paired_speedup"]
 
 
-def paired_overhead(measure_base, measure_test, pairs, reps=1):
-    """Median of per-pair (test / base) ratios over adjacent
+def paired_speedup(measure_base, measure_test, pairs, reps=1):
+    """Median of per-pair (base / test) cost ratios over adjacent
     alternating pairs; each half is the min of ``reps`` windows. Both
     callables return a seconds-like cost (lower is better). Returns
-    ``(best_base, best_test, overhead_pct)`` where ``overhead_pct`` is
-    ``(median ratio - 1) * 100`` — positive means the test side is
-    slower."""
+    ``(best_base, best_test, speedup)`` — ``speedup`` > 1 means the
+    test config beats the base config."""
     best = {"base": float("inf"), "test": float("inf")}
     ratios = []
     for i in range(pairs):
@@ -40,15 +35,4 @@ def paired_overhead(measure_base, measure_test, pairs, reps=1):
             got[side] = min(fn() for _ in range(reps))
             best[side] = min(best[side], got[side])
         ratios.append(got["test"] / got["base"])
-    overhead = (statistics.median(ratios) - 1.0) * 100
-    return best["base"], best["test"], overhead
-
-
-def paired_speedup(measure_base, measure_test, pairs, reps=1):
-    """:func:`paired_overhead` reframed for the autotuner: returns
-    ``(best_base, best_test, speedup)`` where ``speedup`` is the
-    median per-pair base/test cost ratio — > 1 means the test config
-    beats the base config."""
-    best_base, best_test, overhead = paired_overhead(
-        measure_base, measure_test, pairs, reps)
-    return best_base, best_test, 100.0 / (100.0 + overhead)
+    return best["base"], best["test"], 1.0 / statistics.median(ratios)

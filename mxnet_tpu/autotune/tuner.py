@@ -3,10 +3,9 @@ persist the winner as a TuningRecord.
 
 The sweep is deliberately boring — the value is in the harness it
 reuses. Candidates are priced against the heuristic-default workload
-with the shared paired-median discipline (``benchmark/_measure.py``:
-adjacent alternating pairs, median of per-pair ratios), the same
-methodology the telemetry and lock-witness benches trust, so a 2%
-effect survives a noisy CPU box. Each candidate's workload is built
+with the paired-median discipline of :mod:`._measure` (adjacent
+alternating pairs, median of per-pair ratios), so a small effect
+survives a noisy host. Each candidate's workload is built
 under a :func:`~.records.trial` override — the candidate value is
 actually consulted during graph optimization AND folded into the
 autotune salt, so a trial executable never collides with the
@@ -33,9 +32,9 @@ import time
 
 from .. import telemetry
 from ..base import MXNetError
-from ..benchmark._measure import paired_speedup
 from ..resilience import faults as _faults
 from . import _count, mode, records, registry
+from ._measure import paired_speedup
 
 __all__ = ["tune", "budget_default_ms"]
 

@@ -2,7 +2,7 @@
 ``run_performance_test`` + the category sweeps of opperf.py, which the
 reference drives through its profiler to catch op-level regressions).
 
-TPU-native measurement rules (the same ones bench.py follows):
+TPU-native measurement rules:
 - one warmup call compiles (jit caches by shape/dtype);
 - timing syncs through ``jax.device_get`` of a scalar reduced from the
   output: the window ends when the result has reached the host;

@@ -458,8 +458,7 @@ class Trainer:
         inference forward) first for strict parity; (2) warming shifts
         which step is the first *compiled* execution of each recording
         entry, which on fusion-sensitive graphs can differ from the
-        uncached first run by an ulp (same class of caveat as
-        BENCH_NOTES_r07). Best effort by design: executables keyed off
+        uncached first run by an ulp. Best effort by design: executables keyed off
         the real loss head still compile on first use. Returns the
         number of shapes warmed."""
         if (block is None) != (shapes is None):

@@ -120,10 +120,10 @@ def _pallas_refusal(pattern, out_shape, itemsize=4, act_type=None):
 
 
 #: sequence length at which a lax attention cluster goes compute-bound:
-#: BENCH_FUSION_r17 measured the fused lax replay at 0.92x of the 1:1
-#: lowering once both score dims reach 64 — the QK^T/PV matmuls dominate
-#: and the fused executable only denies XLA its own gemm scheduling.
-#: r17 also measured 1.74x at seq 16: the crossover is really a function
+#: round 17 measured (CPU, toy width) the fused lax replay at 0.92x of
+#: the 1:1 lowering once both score dims reach 64 — the QK^T/PV matmuls
+#: dominate and the fused executable only denies XLA its own gemm
+#: scheduling — and at 1.74x at seq 16: the crossover is really a function
 #: of feature width (narrow heads stay dispatch-dominated far past
 #: seq 64), which is why the consult key carries a feat bucket — the
 #: candidate 4096 effectively means "never compute-bound".

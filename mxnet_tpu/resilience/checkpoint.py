@@ -35,8 +35,7 @@ the buffers the fused step donates (``fused_step.state_copy``; donation
 deletes the original even while Python references it). The
 device→host transfer, pickling, hashing and file IO then run on a
 background writer thread (``MXNET_CKPT_ASYNC``, default on), so the
-step loop pays only the capture (benchmark/resilience_bench.py gates
-the overhead at <5% of an epoch). ``wait()`` joins the writer; a
+step loop pays only the capture. ``wait()`` joins the writer; a
 writer failure surfaces on the next ``save``/``wait``.
 
 Retention: ``keep`` newest checkpoints are kept (``MXNET_CKPT_KEEP``,

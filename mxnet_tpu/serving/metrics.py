@@ -441,8 +441,7 @@ class ServingMetrics:
 
     def snapshot(self):
         """Flat numeric dict — the ``profiler.serving_counters()``
-        surface. Latencies are reported in milliseconds (matching the
-        ``*_ms`` lower-is-better convention of bench_compare)."""
+        surface. Latencies are reported in milliseconds."""
         now = time.monotonic()
         with self._lock:
             st = dict(self.counters)

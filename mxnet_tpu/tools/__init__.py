@@ -12,8 +12,7 @@ from __future__ import annotations
 import importlib
 
 _SUBMODULES = ("im2rec", "launch", "bandwidth", "parse_log", "diagnose",
-               "flakiness_checker", "kill_mxnet", "amalgamate",
-               "trace_top")
+               "flakiness_checker", "kill_mxnet", "amalgamate")
 
 __all__ = list(_SUBMODULES)
 

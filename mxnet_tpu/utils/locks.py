@@ -11,7 +11,7 @@ a lint error, so the registry cannot rot.
 
 - ``0`` (default): the factories return the raw ``threading`` object
   — one env read at import, then literal passthrough; production pays
-  nothing (bench-gated by ``BENCH_LOCKCHECK_r22.json``).
+  nothing.
 - ``warn`` / ``error``: the factories return checked wrappers and the
   witness runs on every acquire. The tier-1 conftest exports ``warn``
   before importing the package, so **every test doubles as a

@@ -3,7 +3,7 @@
 Runs the suite on a virtual 8-device CPU mesh (like the reference's
 multi-process single-host distributed tests, SURVEY §4) so sharding paths
 are exercised without TPU hardware. The platform forcing lives in
-``_cpu_platform.force_cpu_platform`` (shared with bench.py and
+``_cpu_platform.force_cpu_platform`` (shared with
 __graft_entry__.py) — it must run before any backend initializes.
 """
 import os

@@ -300,6 +300,48 @@ def test_salt_content_and_graph_opt_tag_form(tuned):
 
 
 # ---------------------------------------------------------------------------
+# the paired-median harness the tuner prices candidates with
+
+def _scripted(costs, calls, side):
+    it = iter(costs)
+
+    def measure():
+        calls.append(side)
+        return next(it)
+    return measure
+
+
+@pytest.mark.parametrize("case", ["median_over_pairs",
+                                  "orders_alternate", "raise_propagates"])
+def test_paired_speedup(case):
+    from mxnet_tpu.autotune._measure import paired_speedup
+
+    calls = []
+    if case == "median_over_pairs":
+        # per-pair base/test ratios 2, 4, 1: the median pair decides,
+        # not the best window of either side
+        got = paired_speedup(_scripted([2.0, 4.0, 3.0], calls, "base"),
+                             _scripted([1.0, 1.0, 3.0], calls, "test"),
+                             pairs=3)
+        assert got == (2.0, 1.0, 2.0)
+    elif case == "orders_alternate":
+        # test first, then base first: drift inside a pair cancels in
+        # the median; each half is the min of its ``reps`` windows
+        got = paired_speedup(_scripted([4.0, 2.0, 2.0, 6.0], calls, "base"),
+                             _scripted([9.0, 1.0, 1.0, 5.0], calls, "test"),
+                             pairs=2, reps=2)
+        assert calls == ["test", "test", "base", "base",
+                         "base", "base", "test", "test"]
+        assert got == (2.0, 1.0, 2.0)
+    else:
+        def broken():
+            raise RuntimeError("candidate blew up")
+
+        with pytest.raises(RuntimeError, match="blew up"):
+            paired_speedup(lambda: 1.0, broken, pairs=2)
+
+
+# ---------------------------------------------------------------------------
 # tuner: sweep, no-win pin, budget, fault seam
 
 def _fake_measure(costs):

@@ -209,15 +209,3 @@ def test_tracer_and_adhoc_bypass():
     xx, yy = np.meshgrid(np.arange(3), np.arange(4))
     assert xi.shape == (3, 4) and xx.shape == (4, 3)
     assert registry.dispatch_cache_stats()["bypasses"] >= 1
-
-
-def test_smoke_bench_runs(tmp_path):
-    from mxnet_tpu.benchmark import dispatch_bench
-
-    out = tmp_path / "bench.json"
-    doc = dispatch_bench.run(smoke=True, iters=20, out_path=str(out))
-    assert out.exists()
-    assert set(doc["results"]) == {"nograd", "recorded"}
-    for r in doc["results"].values():
-        assert r["speedup"] > 0
-    assert doc["counters"]["hits"] > 0

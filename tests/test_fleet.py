@@ -6,9 +6,9 @@ Most tests run the router against FAKE replica HTTP servers (stdlib,
 in-process) so routing/affinity/drain/ejection logic is exercised in
 milliseconds; one tier-1 smoke spawns two REAL replica subprocesses
 (bundle-warm via the shared disk cache) and routes through the full
-stack. The N-replica drain/join/canary e2e lives in the slow-marked
-fleet_bench smoke (tests/test_bench_smoke.py)."""
+stack."""
 import json
+import os
 import pickle
 import threading
 import time
@@ -470,11 +470,13 @@ def test_router_http_surface_and_metrics(fakes):
 # tier-1 smoke: two REAL replica subprocesses behind the router
 
 def test_two_real_replicas_smoke(tmp_path):
-    from mxnet_tpu.benchmark.fleet_bench import DENSE
+    from _fleet_replica import DENSE
     from mxnet_tpu.serving import spawn_replica
 
-    env = {"MXNET_FLEET_BENCH_HIDDEN": "16",
-           "MXNET_FLEET_BENCH_ROWS": "4",
+    # the children import the factory's module from tests/
+    env = {"PYTHONPATH": os.pathsep.join(
+               [os.path.dirname(os.path.abspath(__file__)),
+                os.environ.get("PYTHONPATH", "")]),
            "MXNET_COMPILE_CACHE_DIR": str(tmp_path / "cache"),
            "MXNET_COMPILE_CACHE": "1"}
     r0 = spawn_replica(DENSE, env=env)

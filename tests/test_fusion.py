@@ -95,8 +95,8 @@ def test_cost_model_goldens():
     assert (d.fuse, d.reason) == (False, "cost_model_never")
     d = cost_model.decide("attention", 1, mode="always")
     assert d.fuse
-    # BENCH_FUSION_r17: lax attention at seq>=64 is compute-bound
-    # (0.92x) — reject; below the floor or on either short axis, fuse
+    # lax attention at seq>=64 is compute-bound (0.92x: CPU, toy
+    # width, round 17) — reject; below the floor or on either short axis, fuse
     d = cost_model.decide("attention", 3, score_shape=(2, 64, 64))
     assert (d.fuse, d.reason) == (False, "compute_bound_attention")
     d = cost_model.decide("attention", 3, score_shape=(2, 63, 64))
@@ -155,8 +155,8 @@ def test_attention_golden_and_bitwise(scale_op):
 
 
 def test_attention_compute_bound_seq_not_fused():
-    """seq>=64 lax attention is compute-bound (BENCH_FUSION_r17 showed
-    the fused replay at 0.92x): the shape-aware cost model must keep
+    """seq>=64 lax attention is compute-bound (CPU, toy width, round
+    17: the fused replay at 0.92x): the shape-aware cost model must keep
     the 1:1 lowering and count the fallback."""
     out = _attention("mul")
     shapes = {k: (2, 64, 8) for k in ("q", "k", "v")}
@@ -198,6 +198,25 @@ def test_batch_norm_act_rejected_as_effectful():
 
 # ---------------------------------------------------------------------------
 # knobs
+
+def test_model_zoo_graph_forms_clusters_and_counts_its_fallbacks():
+    """A traced model-zoo graph (resnet18_v1): the matchers form at
+    least one cluster, every cost-model decision lands in a
+    ``clusters_*`` or a ``fallback_*`` counter, and post-verify rejects
+    nothing."""
+    from mxnet_tpu.gluon.model_zoo.vision import get_model
+
+    traced = get_model("resnet18_v1")(sym.var("data"))
+    _, st = optimize_symbol(traced, shapes={"data": (1, 3, 32, 32)},
+                            level=2)
+    c = kernels.counters()
+    clusters = sum(v for k, v in c.items() if k.startswith("clusters_"))
+    fallbacks = sum(v for k, v in c.items() if k.startswith("fallback_"))
+    assert clusters >= 1, c
+    assert fallbacks >= 1, c  # batch_norm + act: effectful, counted
+    assert not st["rejected"]
+    assert st["nodes_after"] < st["nodes_before"]
+
 
 def test_kill_switch_disables_all_patterns(monkeypatch):
     monkeypatch.setenv("MXNET_FUSION", "0")

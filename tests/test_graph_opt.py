@@ -195,6 +195,35 @@ def test_per_pass_stats_and_counters():
     assert profiler.graph_opt_counters()["graphs_optimized"] == 1
 
 
+def test_redundant_graph_gives_every_pass_work_and_none_is_rejected():
+    """A graph with a transpose pair, three identical ``t*t + x``
+    chains, a chain of literal ones and a reshape round trip: the node
+    count falls, fold, cse, transpose_elision and dce each rewrite
+    something, and post-verify rejects no pass."""
+    x = sym.var("x")
+    t = x.transpose((1, 0)).transpose((1, 0))
+    body = c = None
+    for _ in range(3):
+        v = t * t + x
+        body = v if body is None else body + v
+        c = sym.ones((4, 4)) if c is None else c + sym.ones((4, 4))
+    r = x.reshape((-1,)).reshape((16,)).reshape((4, 4))
+    out = (body + c) + r
+    opt, st = optimize_symbol(out, shapes={"x": (4, 4)}, level=2)
+    assert st["nodes_after"] < st["nodes_before"]
+    assert not st["rejected"]
+    rewrites = {}
+    for row in st["passes"]:
+        rewrites[row["pass"]] = rewrites.get(row["pass"], 0) \
+            + row["rewrites"]
+    assert all(rewrites[p] > 0 for p in
+               ("fold", "cse", "transpose_elision", "dce")), rewrites
+    assert graph_opt.counters()["graphs_rejected"] == 0
+    feed = {"x": nd.array(onp.arange(16, dtype="f").reshape(4, 4))}
+    assert onp.array_equal(out.eval_with(dict(feed)).asnumpy(),
+                           opt.eval_with(dict(feed)).asnumpy())
+
+
 def test_level0_is_passthrough():
     x = sym.var("x")
     out = (x * x) + (x * x)

@@ -209,6 +209,36 @@ def test_page_pressure_evicts_whole_lru_session(net):
             assert onp.array_equal(row, rows["b"][i])
 
 
+def test_paged_store_holds_3x_the_sessions_of_row_slots_at_one_budget():
+    """One byte budget, sessions holding a 16-token prefix of a
+    64-token cache: the row-slot store reserves every session's whole
+    cache, the paged store only the pages the prefix touches — at least
+    3x the resident sessions, and int8 pages more again. Every session
+    opened is still resident: no eviction made the room."""
+    mx.random.seed(23)
+    net64 = DecoderBlockLM(VOCAB, embed_dim=EMBED, num_layers=LAYERS,
+                           num_heads=HEADS, max_len=64, impl="lax")
+    net64.initialize()
+    zero_rows = [onp.zeros(s, dt) for s, dt in
+                 zip(net64.state_row_shapes(), net64.state_row_dtypes())]
+    prefix, held = 16, {}
+    for key, kw in (("rowslot", dict(page_tokens=0)),
+                    ("paged", dict(page_tokens=8)),
+                    ("int8", dict(page_tokens=8, kv_int8=True))):
+        store = _store(net64, max_sessions=4096, byte_budget=64 * 1024,
+                       **kw)
+        n = store.num_slots
+        if store.paged:
+            n = min(n, store.num_pages // -(-prefix // store.page_tokens))
+        for i in range(n):
+            store.open(f"cap-{i}", init_states=zero_rows, tokens=prefix)
+        assert len(store.live_sessions()) == n
+        assert serving.serving_stats().get("evictions", 0) == 0
+        held[key] = n
+    assert held["paged"] >= 3 * held["rowslot"], held
+    assert held["int8"] > held["paged"], held
+
+
 # ---------------------------------------------------------------------------
 # checkpoint mid-stream, restore across geometries
 

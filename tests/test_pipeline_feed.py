@@ -548,6 +548,80 @@ def test_dataloader_timeout_disabled_with_nonpositive():
 
 
 # ---------------------------------------------------------------------------
+# an epoch through the feed is the synchronous loop, bit for bit
+
+
+def _train_epoch(depth, amp, poison_at=None, steps=6):
+    """One seeded epoch of a toy MLP under the fused Trainer.step;
+    ``depth=None`` is the classic loop (nd.array on the step thread, the
+    loss read back every step), otherwise the batches come through
+    ``DeviceFeed(depth)`` and the losses stay on the device until the
+    end. Returns (param bytes, losses, loss-scale trace)."""
+    from mxnet_tpu import gluon
+    from mxnet_tpu.contrib.amp.loss_scaler import LossScaler
+    from mxnet_tpu.gluon import nn
+
+    mx.random.seed(7)
+    net = nn.HybridSequential()
+    net.add(nn.Dense(16, activation="relu"), nn.Dense(10))
+    net.initialize()
+    net(nd.zeros((1, 8)))
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.05, "momentum": 0.9})
+    if amp:
+        trainer._amp_loss_scaler = LossScaler(init_scale=2.0 ** 10,
+                                              scale_window=64)
+    rs = onp.random.RandomState(123)
+    raw = [(rs.rand(4, 8).astype("f"), rs.rand(4, 10).astype("f"))
+           for _ in range(steps)]
+    if poison_at is not None:
+        raw[poison_at] = (onp.full((4, 8), onp.inf, "f"),
+                          raw[poison_at][1])
+    losses, scales = [], []
+
+    def step(xb, yb):
+        with autograd.record():
+            loss = ((net(xb) - yb) ** 2).mean()
+        loss.backward()
+        trainer.step(4)
+        if amp:
+            scales.append(trainer._amp_loss_scaler.loss_scale)
+        return loss
+
+    if depth is None:
+        for x, y in raw:
+            losses.append(float(step(nd.array(x), nd.array(y)).asnumpy()))
+    else:
+        feed = DeviceFeed(iter(raw), depth=depth)
+        try:
+            on_device = [step(xb, yb) for xb, yb in feed]
+        finally:
+            feed.close()
+        losses = [float(l.asnumpy()) for l in on_device]
+    params = [p.data().asnumpy().tobytes()
+              for p in net.collect_params().values()]
+    return params, onp.asarray(losses, "f").tobytes(), scales
+
+
+@pytest.mark.parametrize("depth,amp", [(2, False), (0, False), (2, True)],
+                         ids=["prefetch2", "depth0_fallback",
+                              "amp_skip_episode"])
+def test_epoch_through_feed_is_bitwise_the_sync_loop(depth, amp):
+    """Final parameters, the per-step loss trace and (under AMP, through
+    an all-inf batch that forces a fused skip-step) the loss-scale trace
+    are identical whether batches are staged ahead by the feed's worker
+    or placed on the step thread."""
+    poison = 3 if amp else None
+    p_sync, l_sync, s_sync = _train_epoch(None, amp, poison)
+    p_feed, l_feed, s_feed = _train_epoch(depth, amp, poison)
+    assert p_feed == p_sync
+    assert l_feed == l_sync
+    assert s_feed == s_sync
+    if amp:  # the episode really happened: the scale backed off once
+        assert any(b < a for a, b in zip(s_sync, s_sync[1:]))
+
+
+# ---------------------------------------------------------------------------
 # observability wiring
 
 

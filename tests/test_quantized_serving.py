@@ -100,6 +100,26 @@ def test_session_serves_quantized_graph_accurately():
             assert dev < 0.1, dev
 
 
+def test_quantized_block_stores_its_weights_in_under_half_the_bytes():
+    """The bytes a request moves: the quantized block keeps its Dense
+    weights as int8 (scales and biases stay float32), so its parameters
+    take less than half the float32 block's bytes, and the pass counted
+    the graph it rewrote."""
+    from mxnet_tpu.analysis import quantize
+
+    def param_bytes(block):
+        return sum(int(p.data().size) * onp.dtype(p.data().dtype).itemsize
+                   for p in block.collect_params().values())
+
+    quantize.reset_counters()
+    net = _mlp(5)
+    qb = _quantized(net)
+    assert quantize.counters()["graphs_quantized"] >= 1
+    assert any(onp.dtype(p.data().dtype) == onp.int8
+               for p in qb.collect_params().values())
+    assert 2 * param_bytes(qb) < param_bytes(net)
+
+
 def test_int8_fp32_fingerprints_coexist(monkeypatch):
     """The AOT disk keys for the fp32 and int8 versions of the SAME
     model must never collide, int8 keys are salted per lowering mode,

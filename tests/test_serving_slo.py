@@ -316,6 +316,49 @@ def test_close_drain_honors_deadlines_per_class():
     assert onp.array_equal(fc.result(timeout=1), _ref(net, x))
 
 
+def test_backed_up_queue_sheds_best_effort_and_never_critical():
+    """Overload against a real queue, no fault seam: with the worker
+    pinned the lanes fill, queue headroom falls under the shed knob,
+    best_effort is refused at the door while critical keeps being
+    admitted, and once the worker runs again every critical request is
+    answered with its own rows."""
+    net = _mlp()
+    sess = _GatedSession(_session(net))
+    bat = serving.DynamicBatcher(sess, max_batch_size=4, max_queue=8,
+                                 max_latency_ms=1.0, timeout_ms=60_000)
+    ctl = bat.admission
+    xs = [onp.random.RandomState(i).rand(1, 8).astype("float32")
+          for i in range(9)]
+    critical = []
+    try:
+        sess.gate.clear()
+        critical.append(bat.submit(xs[0], slo_class="critical"))
+        time.sleep(0.15)  # the worker is pinned inside predict
+        # 7 a lane = 21 of 24 slots: headroom 0.125, between the
+        # best_effort and the standard thresholds
+        for i in range(1, 8):
+            critical.append(bat.submit(xs[i], slo_class="critical"))
+            bat.submit(xs[i], slo_class="standard")
+            bat.submit(xs[i], slo_class="best_effort")
+        assert ctl.shed_threshold("standard") < ctl.headroom() \
+            < ctl.shed_threshold("best_effort")
+        with pytest.raises(serving.ShedLoad):
+            bat.submit(xs[8], slo_class="best_effort")
+        critical.append(bat.submit(xs[8], slo_class="critical"))
+    finally:
+        sess.gate.set()
+        bat.close()
+    for fut, x in zip(critical, xs):  # rows of a batch of 4 against a
+        # batch of 1: float32 agreement, not bits
+        assert onp.allclose(fut.result(timeout=1), _ref(net, x),
+                            rtol=1e-5, atol=1e-6)
+    stats = serving.serving_stats()
+    assert stats["shed:best_effort"] == 1
+    assert stats.get("shed:critical", 0) == 0
+    assert stats.get("failures:critical", 0) == 0
+    assert stats["responses:critical"] == 9
+
+
 # ---------------------------------------------------------------------------
 # observability
 

@@ -103,6 +103,30 @@ def test_step_bitwise_vs_hybridized_block_across_buckets():
         sess.close()
 
 
+def test_incremental_steps_bitwise_vs_replaying_the_prefix():
+    """T steps threading the state forward end on the same bits as
+    serving token T with no state kept: the whole prefix replayed from
+    the zero state through the same executable; both match the offline
+    unroll of the hybridized block."""
+    net = _gru()
+    sess = _session(net)
+    xs = [_x(40 + t) for t in range(6)]
+    try:
+        states = [nd.zeros((1, HID))]
+        for x in xs:
+            inc, states = sess.step(nd.array(x), states=states)
+        replay_h = [nd.zeros((1, HID))]  # token T with no state kept
+        for x in xs:
+            replay, replay_h = sess.step(nd.array(x), states=replay_h)
+    finally:
+        sess.close()
+    ref_o, ref_h = _unroll(net, xs)
+    assert onp.array_equal(inc.asnumpy(), replay.asnumpy())
+    assert onp.array_equal(states[0].asnumpy(), replay_h[0].asnumpy())
+    assert onp.array_equal(inc.asnumpy(), ref_o)
+    assert onp.array_equal(states[0].asnumpy(), ref_h)
+
+
 def test_step_and_predict_guardrails():
     net = _gru()
     sess = _session(net, buckets=[1, 2])

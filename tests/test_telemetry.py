@@ -424,3 +424,22 @@ def test_prefetch_stage_span_carries_the_bytes_it_placed(monkeypatch):
     feed.close()
     staged = _named("pipeline.prefetch_stage")
     assert [e["args"]["bytes"] for e in staged] == [64, 64]
+
+
+def test_each_fused_trainer_step_leaves_one_execute_span(monkeypatch):
+    """``gluon.Trainer.step`` on the fused path: one
+    ``fused_step.execute`` span a step, on the train lane."""
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    monkeypatch.setenv("MXNET_FUSED_STEP", "1")
+    net = _mlp()
+    trainer = mx.gluon.Trainer(net.collect_params(), "sgd",
+                               {"learning_rate": 0.05})
+    x, y = nd.ones((2, 8)), nd.zeros((2, 4))
+    for _ in range(3):
+        with autograd.record():
+            loss = ((net(x) - y) ** 2).mean()
+        loss.backward()
+        trainer.step(2)
+    spans = _named("fused_step.execute")
+    assert len(spans) == 3
+    assert all(e["ph"] == "X" and e["cat"] == "train" for e in spans)
