@@ -35,9 +35,18 @@ gradients once. Design:
   ``BlockDiffusionMask``: at trace time the spec gives a table of tile
   kinds (dead, whole, partly masked) per (q tile, k tile), which rides
   into both kernels as a prefetched scalar array and drives the skipping
-  and the index maps exactly as the diagonal does; a partly masked tile
-  evaluates the spec's element rule from its own indices. No (S, S)
-  array exists in HBM either way.
+  and the index maps exactly as the diagonal does. A partly masked tile
+  is computed by strips: the table also gives it the number of its
+  *pattern*, which says for each strip of queries (512, or the largest
+  halving of it that divides both tiles) the hull of the strip-sized
+  sub-tiles of its keys that hold a live score. A regular spec has few
+  patterns (the block-diffusion band and its block-causal diagonal),
+  static data each kernel gets one unrolled branch for: a strip does its
+  products, its statistics and its rows of the gradients on its hull
+  alone, the spec's element rule on a column of its query ids and a row
+  of its key ids; a pattern's strips go through each stage together, and
+  a strip with no live key does nothing. No (S, S) array exists in HBM
+  either way.
 - grouped heads: q of H heads reads k, v of H / group heads in place
   through the index map. The backward writes dk, dv per query head and
   the group is summed after the kernel (a grid order that kept one k
@@ -76,8 +85,20 @@ _BWD_CAPS = (512, 512)
 # mask specs
 
 #: kinds of a (q tile, k tile) pair in a mask's table; FIRST is added to
-#: the first live k tile of a q tile (the backward's dq assigns there)
-DEAD, WHOLE, PARTIAL, FIRST = 0, 1, 2, 4
+#: the first live k tile of a q tile (the backward's dq assigns there),
+#: and a PARTIAL tile's pattern number counts in units of PATTERN
+DEAD, WHOLE, PARTIAL, FIRST, PATTERN = 0, 1, 2, 4, 8
+
+#: rows of a strip of a partly masked tile, halved until it divides both
+#: tiles. On the chip at (2, 32 over 4, 8192, 128) bf16 under the
+#: block-diffusion mask the backward's (256, 512) tiles are fastest in
+#: strips of 256 and the forward's (1024, 1024) in strips of 128, then
+#: 256, then 512 (0.7 ms of a call's 6.1 apart), but every strip is an
+#: unrolled body each layer's trace and lowering pays for in the step's
+#: set-up (PERF.md section 6, PR 33). And how many patterns of strips a
+#: kernel gets a branch for
+_STRIP = 512
+_MAX_PATTERNS = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,9 +135,16 @@ class BlockDiffusionMask:
         q_noisy, k_noisy = qid < L, kid < L
         qb = self._block(jnp.where(q_noisy, qid, qid - L))
         kb = self._block(jnp.where(k_noisy, kid, kid - L))
-        # logical ops only: Mosaic has no select between masks
-        return (k_noisy & q_noisy & (qb == kb)) | (
-            ~k_noisy & ((kb < qb) | (~q_noisy & (kb == qb))))
+        # everything a query or a key decides alone first, as integers
+        # (cheap where the ids are a column and a row), so that two
+        # comparisons are all that broadcasts: a noised key lives for the
+        # noised queries of its block, a clean one for the queries whose
+        # block comes later, or is its own if they are clean
+        k_noised = jnp.where(k_noisy, kb, -2)
+        k_clean = jnp.where(k_noisy, self.size, kb)
+        q_own = jnp.where(q_noisy, qb, -1)
+        q_below = jnp.where(q_noisy, qb, qb + 1)
+        return (k_noised == q_own) | (k_clean < q_below)
 
     def row_intervals(self):
         """(S, 2, 2) int: every query row's live keys as two [start, end)
@@ -131,22 +159,49 @@ class BlockDiffusionMask:
         return onp.concatenate([noisy, clean], 0)
 
 
+def _live_counts(mask, unit):
+    """(S / unit, S / unit) live scores in each unit x unit square, from
+    the spec's row intervals."""
+    S = mask.size
+    iv = mask.row_intervals()                       # (S, n, 2)
+    edges = onp.arange(S // unit + 1) * unit
+    lo = onp.maximum(iv[:, :, :1], edges[None, None, :-1])
+    hi = onp.minimum(iv[:, :, 1:], edges[None, None, 1:])
+    live = onp.maximum(hi - lo, 0).sum(1)           # (S, S / unit) per row
+    return live.reshape(S // unit, unit, S // unit).sum(1)
+
+
+def _strip_size(bq, bk):
+    """The strip size of partly masked tiles (bq, bk): ``_STRIP``, halved
+    until it divides both (128 divides every tile)."""
+    sub = _STRIP
+    while bq % sub or bk % sub:
+        sub //= 2
+    return sub
+
+
 @functools.lru_cache(maxsize=None)
-def mask_tile_table(mask, bq, bk):
-    """(nq, nk) int32 table of tile kinds for ``mask`` at tiles
-    (bq, bk): DEAD, WHOLE or PARTIAL by the count of live scores in the
-    tile, plus FIRST on each q tile's first live k tile."""
+def mask_tile_table(mask, bq, bk, sub):
+    """``(table, patterns)`` of ``mask`` at tiles (bq, bk) in strips of
+    ``sub``, which divides both.
+
+    ``table`` is (nq, nk) int32: DEAD, WHOLE or PARTIAL by the count of
+    live scores in the tile, plus FIRST on each q tile's first live k
+    tile, plus, from ``PATTERN`` up, a PARTIAL tile's pattern number.
+    ``patterns[n - 1]`` is pattern n: for each strip of ``sub`` queries
+    of the tile the hull ``(lo, hi)`` of the sub x sub sub-tiles of its
+    keys in which any score lives, ``(0, 0)`` where none does. Number 0
+    is the tile computed whole: one whose every hull is all of it, and
+    what is left once ``_MAX_PATTERNS`` patterns, those that spare the
+    most sub-tiles, have their numbers."""
     S = mask.size
     if S % bq or S % bk:
         raise ValueError(f"tiles ({bq}, {bk}) do not divide the mask's "
                          f"{S} positions")
     nq, nk = S // bq, S // bk
-    iv = mask.row_intervals()                       # (S, n, 2)
-    edges = onp.arange(nk + 1) * bk                 # (nk + 1,)
-    lo = onp.maximum(iv[:, :, :1], edges[None, None, :-1])
-    hi = onp.minimum(iv[:, :, 1:], edges[None, None, 1:])
-    live = onp.maximum(hi - lo, 0).sum(1)           # (S, nk) per row
-    count = live.reshape(nq, bq, nk).sum(1)
+    rq, rk = bq // sub, bk // sub
+    live = _live_counts(mask, sub).reshape(nq, rq, nk, rk)
+    count = live.sum((1, 3))
     kinds = onp.where(count == 0, DEAD,
                       onp.where(count == bq * bk, WHOLE, PARTIAL))
     if (kinds == DEAD).all(1).any():
@@ -154,7 +209,22 @@ def mask_tile_table(mask, bq, bk):
                          "would never be written")
     first = (kinds != DEAD).argmax(1)
     kinds[onp.arange(nq), first] += FIRST
-    return kinds.astype(onp.int32)
+
+    spared = {}         # pattern -> [sub-tiles it spares in all, its tiles]
+    for i, j in zip(*onp.nonzero(count % (bq * bk))):
+        any_live = live[i, :, j] > 0                # (rq, rk)
+        lo = any_live.argmax(1)
+        hi = onp.where(any_live.any(1), rk - any_live[:, ::-1].argmax(1), 0)
+        pattern = tuple(zip(lo.tolist(), hi.tolist()))
+        entry = spared.setdefault(pattern, [0, []])
+        entry[0] += rq * rk - int((hi - lo).sum())
+        entry[1].append((i, j))
+    kept = sorted((p for p in spared if spared[p][0]),
+                  key=lambda p: (-spared[p][0], p))[:_MAX_PATTERNS]
+    for n, pattern in enumerate(kept, 1):
+        rows, cols = zip(*spared[pattern][1])
+        kinds[rows, cols] += n * PATTERN
+    return kinds.astype(onp.int32), tuple(kept)
 
 
 def _fetch_table(kinds, axis):
@@ -281,12 +351,35 @@ def _tile_mask(shape, q0, k0, q_axis, causal, s_k_real):
     return mask
 
 
-def _spec_mask(mask, shape, q0, k0, q_axis):
-    """A partly masked tile under a mask spec: its element rule on the
-    tile's own indices, queries along ``q_axis``."""
-    qid = q0 + lax.broadcasted_iota(jnp.int32, shape, q_axis)
-    kid = k0 + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-    return mask.element(qid, kid)
+def _spec_where(mask, s, q0, k0, q_axis):
+    """The scores ``s`` of a partly masked tile, or of a strip of one,
+    under a mask spec: -1e30 where its element rule says dead, on the
+    scores' own indices, queries along ``q_axis`` from ``q0`` and keys
+    from ``k0``. The ids are a column and a row (or a row and a column):
+    what the rule does to either alone costs a vector, not a tile."""
+    along = lambda axis: tuple(n if d == axis else 1
+                               for d, n in enumerate(s.shape))
+    qid = q0 + lax.broadcasted_iota(jnp.int32, along(q_axis), q_axis)
+    kid = k0 + lax.broadcasted_iota(jnp.int32, along(1 - q_axis), 1 - q_axis)
+    return jnp.where(mask.element(qid, kid), s, _NEG)
+
+
+#: the parts of a tile computed whole: all its rows by all its columns
+_WHOLE_TILE = ((slice(None), slice(None)),)
+
+
+def _strips(pattern, sub):
+    """A pattern's strips as slices of the tile: ``(rows, cols)`` of each
+    strip with a live key, and the rows of each with none."""
+    rows = [slice(r * sub, (r + 1) * sub) for r in range(len(pattern))]
+    return (tuple((at, slice(lo * sub, hi * sub))
+                  for at, (lo, hi) in zip(rows, pattern) if hi > lo),
+            tuple(at for at, (lo, hi) in zip(rows, pattern) if hi == lo))
+
+
+def _from(origin, part):
+    """Where the slice ``part`` of a tile starts, the tile at ``origin``."""
+    return origin + part.start if part.start else origin
 
 
 def _tile_kinds(i, kb, bq, bk, causal, s_k_real, causal_off):
@@ -304,14 +397,15 @@ def _tile_kinds(i, kb, bq, bk, causal, s_k_real, causal_off):
 # forward
 
 def _fa_kernel(*refs, bq, bk, nk, sm_scale, causal, s_k_real, causal_off,
-               mask=None):
+               mask=None, strips=()):
     """Grid (B, H, nq, nk), kb innermost: one (bq, bk) tile per step. Only
     a q tile, one k/v tile and the (m, l, acc) scratch live in VMEM — true
     streaming, O(bq·D + bk·D) on-chip whatever the sequence length. The
     scratch carries the online softmax across the kb sweep (TPU grid steps
     run sequentially, scratch persists). ``rest`` is (m, l, acc), led by
     the lse output block when the backward will want it. Under a ``mask``
-    spec its two prefetched tables lead the refs."""
+    spec its two prefetched tables lead the refs and ``strips`` are those
+    of each pattern number its partly masked tiles bear."""
     if mask is not None:
         kinds_ref, _, *refs = refs
     q_ref, k_ref, v_ref, o_ref, *rest = refs
@@ -325,29 +419,45 @@ def _fa_kernel(*refs, bq, bk, nk, sm_scale, causal, s_k_real, causal_off,
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    def _tile(masked):
-        v = v_ref[:]
-        s = lax.dot_general(q_ref[:], k_ref[:], _NT,
-                            preferred_element_type=jnp.float32) * sm_scale
-        if masked and mask is not None:
-            s = jnp.where(_spec_mask(mask, (bq, bk), i * bq, kb * bk, 0),
-                          s, _NEG)
-        elif masked:
-            s = jnp.where(_tile_mask((bq, bk), i * bq + causal_off, kb * bk,
-                                     0, causal, s_k_real), s, _NEG)
-        m = m_s[:]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        m_s[:] = m_new
-        l_s[:] = l_s[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_s[:] = acc_s[:] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    def _tile(masked, parts=_WHOLE_TILE):
+        """The online-softmax update of the tile: of its ``parts``, each a
+        strip's query rows by the key columns of its hull, or the one
+        part that is all of it. Every part's scores, then every part's
+        statistics, then its products with v: the parts share no row, so
+        one's latencies hide behind another's work."""
+        vs, ss = [], []
+        for rows, cols in parts:
+            vs.append(v_ref[cols])
+            s = lax.dot_general(q_ref[rows], k_ref[cols], _NT,
+                                preferred_element_type=jnp.float32) * sm_scale
+            if masked and mask is not None:
+                s = _spec_where(mask, s, _from(i * bq, rows),
+                                _from(kb * bk, cols), 0)
+            elif masked:
+                s = jnp.where(_tile_mask((bq, bk), i * bq + causal_off,
+                                         kb * bk, 0, causal, s_k_real),
+                              s, _NEG)
+            ss.append(s)
+        steps = []
+        for (rows, _), s in zip(parts, ss):
+            m = m_s[rows]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            steps.append((m_new, jnp.exp(s - m_new), jnp.exp(m - m_new)))
+        for (rows, _), v, (m_new, p, alpha) in zip(parts, vs, steps):
+            m_s[rows] = m_new
+            l_s[rows] = l_s[rows] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_s[rows] = acc_s[rows] * alpha + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
     if mask is not None:
-        kind = kinds_ref[i * nk + kb] & (WHOLE | PARTIAL)
-        pl.when(kind == PARTIAL)(functools.partial(_tile, True))
+        kind = kinds_ref[i * nk + kb] & ~FIRST
         pl.when(kind == WHOLE)(functools.partial(_tile, False))
+
+        @pl.when(kind > WHOLE)  # a dead step pays two tests, as it did
+        def _partly():
+            for n, parts, _ in strips:  # a strip with no live key adds nothing
+                pl.when(kind == PARTIAL + n * PATTERN)(
+                    functools.partial(_tile, True, parts))
     else:
         live, masked = _tile_kinds(i, kb, bq, bk, causal, s_k_real,
                                    causal_off)
@@ -372,10 +482,13 @@ def _fa_kernel(*refs, bq, bk, nk, sm_scale, causal, s_k_real, causal_off,
 
 
 def _mask_tiles(mask, q, k, backward, bq, bk):
-    """Tiles and the two prefetched tables of a mask spec's pass: the
-    kinds, flattened row-major over (q tile, k tile), and per tile the
-    block to name where it is dead (k blocks forward, q blocks
-    backward)."""
+    """Tiles, strips and the two prefetched tables of a mask spec's pass:
+    ``(number, parts, empty)`` for each pattern number a partly masked
+    tile bears, cut as ``_strips`` cuts them (number 0: the tile whole);
+    the kinds, flattened row-major over (q tile, k tile), and per tile
+    the block to name where it is dead (k blocks forward, q blocks
+    backward). Counts the strip-sized sub-tiles the pass's partly masked
+    tiles hold, and those of them it computes."""
     S = q.shape[2]
     if mask.size != S or k.shape[2] != S:
         raise ValueError(f"mask over {mask.size} positions, q {q.shape} "
@@ -386,9 +499,19 @@ def _mask_tiles(mask, q, k, backward, bq, bk):
     if bq is None or bk is None:
         bq, bk = choose_tiles(S, S, q.shape[3], q.dtype.itemsize,
                               backward=backward)
-    kinds = mask_tile_table(mask, bq, bk)
+    sub = _strip_size(bq, bk)
+    kinds, patterns = mask_tile_table(mask, bq, bk, sub)
     fetch = _fetch_table(kinds, 0 if backward else 1)
-    return bq, bk, jnp.asarray(kinds.reshape(-1)), \
+    held = (bq // sub) * (bk // sub)
+    number = kinds[(kinds & PARTIAL) != 0] // PATTERN
+    computed = onp.array([held] + [sum(hi - lo for lo, hi in pattern)
+                                   for pattern in patterns])
+    _count("flash_mask_subtiles_live", int(computed[number].sum()))
+    _count("flash_mask_subtiles_tile", held * number.size)
+    strips = tuple(
+        (n,) + (_strips(patterns[n - 1], sub) if n else (_WHOLE_TILE, ()))
+        for n in onp.unique(number).tolist())
+    return bq, bk, strips, jnp.asarray(kinds.reshape(-1)), \
         jnp.asarray(fetch.reshape(-1))
 
 
@@ -403,9 +526,9 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
     B, H, S_q, D = q.shape
     S_k = k.shape[2]
     group = H // k.shape[1]
-    tables = ()
+    tables = strips = ()
     if mask is not None:
-        bq, bk, *tables = _mask_tiles(mask, q, k, False, bq, bk)
+        bq, bk, strips, *tables = _mask_tiles(mask, q, k, False, bq, bk)
         _count("flash_mask_pallas")
     if bq is None or bk is None:
         bq, bk = choose_tiles(S_q, S_k, D, q.dtype.itemsize)
@@ -416,7 +539,8 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
     off = S_k - S_q
     kern = functools.partial(_fa_kernel, bq=bq, bk=bk, nk=nk,
                              sm_scale=sm_scale, causal=causal,
-                             s_k_real=S_k, causal_off=off, mask=mask)
+                             s_k_real=S_k, causal_off=off, mask=mask,
+                             strips=strips)
 
     def kv_map(b, h, i, kb, *tabs):
         # a dead tile (above the diagonal, or by the mask's table) is
@@ -474,13 +598,14 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
 # backward
 
 def _fa_bwd_kernel(*refs, bq, bk, nq, sm_scale, causal, s_k_real,
-                   causal_off, mask=None):
+                   causal_off, mask=None, strips=()):
     """Grid (B, H, nk, nq), i innermost: one transposed (bk, bq) tile per
     step. dk_s/dv_s accumulate one k tile's gradients over the q sweep;
     dq_ref is the whole head's (S_q, D) float32 block, resident until the
     head changes, and takes each tile's rows as they come: the first live
     k tile of a q tile assigns (kb == 0 under ``causal``, the table's
-    FIRST under a ``mask`` spec, whose two tables lead the refs)."""
+    FIRST under a ``mask`` spec, whose two tables lead the refs and whose
+    ``strips`` are those of each pattern number its tiles bear)."""
     if mask is not None:
         kinds_ref, _, *refs = refs
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
@@ -495,41 +620,70 @@ def _fa_bwd_kernel(*refs, bq, bk, nq, sm_scale, causal, s_k_real,
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
 
-    def _tile(masked):
-        q, k, v, do = q_ref[:], k_ref[:], v_ref[:], do_ref[:]
-        sT = lax.dot_general(k, q, _NT,
-                             preferred_element_type=jnp.float32) * sm_scale
-        if masked and mask is not None:
-            sT = jnp.where(_spec_mask(mask, (bk, bq), i * bq, kb * bk, 1),
-                           sT, _NEG)
-        elif masked:
-            sT = jnp.where(_tile_mask((bk, bq), i * bq + causal_off,
-                                      kb * bk, 1, causal, s_k_real),
-                           sT, _NEG)
-        pT = jnp.exp(sT - lse_ref[:])  # (1, bq) rows along sublanes
-        dpT = lax.dot_general(v, do, _NT,
-                              preferred_element_type=jnp.float32)
-        dsT = pT * (dpT - delta_ref[:])
-        dv_s[:] += jnp.dot(pT.astype(do.dtype), do,
-                           preferred_element_type=jnp.float32)
-        dk_s[:] += jnp.dot(dsT.astype(q.dtype), q,
-                           preferred_element_type=jnp.float32)
-        dq = jnp.dot(dsT.T.astype(k.dtype), k,
-                     preferred_element_type=jnp.float32) * sm_scale
-        rows = pl.ds(pl.multiple_of(i * bq, bq), bq)
+    def _to_dq(rows, dq=None):
+        """``dq`` (None: zeros) into the head's dq at the tile's query
+        ``rows``: assigned by a q tile's first live k tile, added by the
+        rest."""
+        n_rows = len(range(*rows.indices(bq)))
+        at = pl.ds(pl.multiple_of(_from(i * bq, rows), n_rows), n_rows)
 
         @pl.when(kb == 0 if mask is None else (kind & FIRST) != 0)
         def _first():
-            dq_ref[rows, :] = dq
+            dq_ref[at, :] = jnp.zeros((n_rows, dq_ref.shape[1]),
+                                      dq_ref.dtype) if dq is None else dq
 
-        @pl.when(kb > 0 if mask is None else (kind & FIRST) == 0)
-        def _rest():
-            dq_ref[rows, :] += dq
+        if dq is not None:
+            @pl.when(kb > 0 if mask is None else (kind & FIRST) == 0)
+            def _rest():
+                dq_ref[at, :] += dq
+
+    def _tile(masked, parts=_WHOLE_TILE, empty=()):
+        """The five products of the tile: of its ``parts``, each a
+        strip's query rows by the key columns of its hull, or the one
+        part that is all of it; the strips with no live key are the
+        ``empty`` rows. Every part's scores, then its p and ds, then its
+        gradients, as in the forward."""
+        loaded, scores = [], []
+        for rows, cols in parts:
+            q, k, v, do = q_ref[rows], k_ref[cols], v_ref[cols], do_ref[rows]
+            sT = lax.dot_general(k, q, _NT,
+                                 preferred_element_type=jnp.float32) * sm_scale
+            if masked and mask is not None:
+                sT = _spec_where(mask, sT, _from(i * bq, rows),
+                                 _from(kb * bk, cols), 1)
+            elif masked:
+                sT = jnp.where(_tile_mask((bk, bq), i * bq + causal_off,
+                                          kb * bk, 1, causal, s_k_real),
+                               sT, _NEG)
+            loaded.append((q, k, v, do))
+            scores.append(sT)
+        grads = []
+        for (rows, _), (q, k, v, do), sT in zip(parts, loaded, scores):
+            pT = jnp.exp(sT - lse_ref[:, rows])  # (1, bq) rows along sublanes
+            dpT = lax.dot_general(v, do, _NT,
+                                  preferred_element_type=jnp.float32)
+            grads.append((pT, pT * (dpT - delta_ref[:, rows])))
+        for (rows, cols), (q, k, v, do), (pT, dsT) in zip(parts, loaded,
+                                                          grads):
+            dv_s[cols] += jnp.dot(pT.astype(do.dtype), do,
+                                  preferred_element_type=jnp.float32)
+            dk_s[cols] += jnp.dot(dsT.astype(q.dtype), q,
+                                  preferred_element_type=jnp.float32)
+            _to_dq(rows, jnp.dot(dsT.T.astype(k.dtype), k,
+                                 preferred_element_type=jnp.float32)
+                   * sm_scale)
+        for rows in empty:      # only a first tile's dq rows
+            _to_dq(rows)
 
     if mask is not None:
-        live_kind = kind & (WHOLE | PARTIAL)
-        pl.when(live_kind == PARTIAL)(functools.partial(_tile, True))
+        live_kind = kind & ~FIRST
         pl.when(live_kind == WHOLE)(functools.partial(_tile, False))
+
+        @pl.when(live_kind > WHOLE)     # a dead step pays two tests
+        def _partly():
+            for n, parts, empty in strips:
+                pl.when(live_kind == PARTIAL + n * PATTERN)(
+                    functools.partial(_tile, True, parts, empty))
     else:
         live, masked = _tile_kinds(i, kb, bq, bk, causal, s_k_real,
                                    causal_off)
@@ -553,9 +707,9 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
     B, H, S_q, D = q.shape
     S_k = k.shape[2]
     group = H // k.shape[1]
-    tables = ()
+    tables = strips = ()
     if mask is not None:
-        bq, bk, *tables = _mask_tiles(mask, q, k, True, bq, bk)
+        bq, bk, strips, *tables = _mask_tiles(mask, q, k, True, bq, bk)
     if bq is None or bk is None:
         bq, bk = choose_tiles(S_q, S_k, D, q.dtype.itemsize, backward=True)
     pq = (-S_q) % bq
@@ -571,7 +725,8 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
     off = S_k - S_q
     kern = functools.partial(_fa_bwd_kernel, bq=bq, bk=bk, nq=nq,
                              sm_scale=sm_scale, causal=causal,
-                             s_k_real=S_k, causal_off=off, mask=mask)
+                             s_k_real=S_k, causal_off=off, mask=mask,
+                             strips=strips)
 
     def _live_i(i, kb, tabs):
         # q tiles that are dead for k tile kb (above the diagonal, or by

@@ -117,15 +117,23 @@ def test_flash_backward_compiles(one_chip, shape, dtype):
     assert after.get("flash_bwd_scan", 0) == before.get("flash_bwd_scan", 0)
 
 
-@pytest.mark.parametrize("b,hq,hkv,seq,d", [
-    (2, 32, 4, 4096, 128),     # the cell sdar30b-train-bd-s4096
-    (1, 8, 8, 1024, 64),       # no grouping, D=64
+@pytest.mark.parametrize("b,hq,hkv,seq,d,subtiles", [
+    # the cell sdar30b-train-bd-s4096: forward at (1024, 1024) in strips
+    # of 512 the band's 4 x 2 and the diagonal's 8 x 3 sub-tiles of
+    # 12 x 4, backward at (256, 512) in strips of 256 16 + 16 x (1 + 2)
+    # of 48 x 2
+    (2, 32, 4, 4096, 128, (32 + 64, 48 + 96)),
+    # no grouping, D=64: (1024, 1024) 2 + 2 x 3 of 3 x 4; at (512, 512) a
+    # tile is one strip, its 6 computed whole
+    (1, 8, 8, 1024, 64, (8 + 6, 12 + 6)),
 ])
-def test_masked_grouped_flash_compiles(one_chip, b, hq, hkv, seq, d):
+def test_masked_grouped_flash_compiles(one_chip, b, hq, hkv, seq, d,
+                                       subtiles):
     """The block-diffusion mask over 2 x seq positions with grouped
     heads, under ``jax.grad`` at the chooser's tiles: both kernels keep
     their names (the table of tile kinds rides in as a prefetched
-    scalar), and the cell's counter counts the lowering."""
+    scalar), the strips of the partly masked tiles lower with them, and
+    the cell's counters count the lowering and its sub-tiles."""
     mask = fa.BlockDiffusionMask(seq, 4)
     dtype = jnp.bfloat16
 
@@ -144,6 +152,9 @@ def test_masked_grouped_flash_compiles(one_chip, b, hq, hkv, seq, d):
     after = kernels.counters()
     assert after["flash_mask_pallas"] > before.get("flash_mask_pallas", 0)
     assert after.get("flash_bwd_scan", 0) == before.get("flash_bwd_scan", 0)
+    for name, n in zip(("live", "tile"), subtiles):
+        name = "flash_mask_subtiles_" + name
+        assert after[name] - before.get(name, 0) == n, name
 
 
 @pytest.mark.parametrize("m,k,n,g,dtype", [

@@ -1,9 +1,10 @@
 """The flash kernels under a static mask spec and with grouped heads:
 ``BlockDiffusionMask`` over ``[xt ; x0]`` against a dense mask built from
 the three rules, forward and backward in interpret mode on the CPU, at
-tiles that make dead, whole and partly masked tiles all occur; the table
-of tile kinds against brute force over the element rule; and
-``causal=True`` left as it was. (``tests/test_chip_compile.py`` compiles
+tiles that make dead, whole and partly masked tiles all occur, and at
+tiles that hold several strips; the table of tile kinds and the patterns
+of strips against brute force over the element rule; and ``causal=True``
+left as it was. (``tests/test_chip_compile.py`` compiles
 the same kernels for the chip at the cell's shape.)
 """
 import dataclasses
@@ -16,8 +17,8 @@ import pytest
 from mxnet_tpu import kernels
 from mxnet_tpu.kernels import flash_attention as fa
 from mxnet_tpu.kernels.flash_attention import (
-    DEAD, FIRST, PARTIAL, WHOLE, BlockDiffusionMask, flash_attention,
-    mask_tile_table)
+    DEAD, FIRST, PARTIAL, PATTERN, WHOLE, BlockDiffusionMask,
+    flash_attention, mask_tile_table)
 
 TOL = {"float32": 2e-5, "bfloat16": 8e-2}
 
@@ -44,10 +45,16 @@ def dense_attention(q, k, v, mask):
 
 @pytest.fixture
 def cap_tiles(monkeypatch):
-    def cap(n):
-        monkeypatch.setattr(fa, "_FWD_CAPS", (n, n))
-        monkeypatch.setattr(fa, "_BWD_CAPS", (n, n))
+    def cap(nq, nk=None):
+        monkeypatch.setattr(fa, "_FWD_CAPS", (nq, nk or nq))
+        monkeypatch.setattr(fa, "_BWD_CAPS", (nq, nk or nq))
     return cap
+
+
+@pytest.fixture
+def strips_of(monkeypatch):
+    """Strips of the given size for the test."""
+    return lambda sub: monkeypatch.setattr(fa, "_STRIP", sub)
 
 
 @pytest.mark.parametrize("L,b", [(256, 4), (128, 1), (384, 32), (96, 96)])
@@ -66,17 +73,22 @@ def test_element_rule_is_the_three_rules(L, b):
     assert (rows == want).all()
 
 
-@pytest.mark.parametrize("L,b,bq,bk", [
-    (256, 4, 128, 128), (256, 4, 256, 128), (256, 4, 128, 256),
-    (512, 4, 512, 512), (384, 32, 128, 128), (128, 1, 128, 128),
-    (512, 8, 256, 512), (4096, 4, 1024, 1024), (4096, 4, 512, 512)])
+TILES = [(256, 4, 128, 128), (256, 4, 256, 128), (256, 4, 128, 256),
+         (512, 4, 512, 512), (384, 32, 128, 128), (128, 1, 128, 128),
+         (512, 8, 256, 512), (4096, 4, 1024, 1024), (4096, 4, 512, 512),
+         (4096, 4, 256, 512), (512, 4, 256, 256), (512, 128, 512, 256),
+         (384, 32, 384, 256)]
+
+
+@pytest.mark.parametrize("L,b,bq,bk", TILES)
 def test_tile_table_against_brute_force(L, b, bq, bk):
-    table = mask_tile_table(BlockDiffusionMask(L, b), bq, bk)
+    table, _ = mask_tile_table(BlockDiffusionMask(L, b), bq, bk, 128)
     S = 2 * L
     count = dense_mask(L, b).reshape(S // bq, bq, S // bk, bk).sum((1, 3))
     want = onp.where(count == 0, DEAD,
                      onp.where(count == bq * bk, WHOLE, PARTIAL))
     assert ((table & (WHOLE | PARTIAL)) == want).all()
+    assert table.max() < (fa._MAX_PATTERNS + 1) * PATTERN
     live = want != DEAD
     # FIRST sits on each q tile's first live k tile and nowhere else
     assert (((table & FIRST) != 0).sum(1) == 1).all()
@@ -92,8 +104,101 @@ def test_tile_table_against_brute_force(L, b, bq, bk):
         assert named.all()
 
 
+def brute_patterns(L, b, bq, bk, sub=128):
+    """Per PARTIAL tile (i, j), from the dense mask: which of its
+    sub x sub sub-tiles hold a live score, (bq / sub, bk / sub) bool."""
+    S = 2 * L
+    live = dense_mask(L, b).reshape(S // bq, bq // sub, sub,
+                                    S // bk, bk // sub, sub).any((2, 5))
+    count = dense_mask(L, b).reshape(S // bq, bq, S // bk, bk).sum((1, 3))
+    return {(i, j): live[i, :, j]
+            for i, j in zip(*onp.nonzero(count % (bq * bk)))}
+
+
+#: distinct patterns a kernel gets a branch for under blocks of 4, by
+#: (bq, bk, sub): the band and the block-causal diagonal at square tiles,
+#: each at two offsets where a k tile holds two q tiles (at one strip a
+#: tile the diagonal's lower one is the band's, and its upper one is the
+#: whole tile), the band's two halves where a q tile holds two k tiles
+#: (the diagonal's hulls are all of it), none where a tile is one sub-tile
+N_PATTERNS = {(128, 128, 128): 0, (256, 128, 128): 2, (128, 256, 128): 2,
+              (512, 512, 128): 2, (256, 512, 128): 4, (1024, 1024, 128): 2,
+              (256, 256, 128): 2, (256, 256, 256): 0, (512, 512, 256): 2,
+              (256, 512, 256): 2, (1024, 1024, 256): 2}
+
+
+@pytest.mark.parametrize("sub", [128, 256])
+@pytest.mark.parametrize("L,b,bq,bk", TILES)
+def test_patterns_against_brute_force(strips_of, L, b, bq, bk, sub):
+    """Every live score lies inside its strip's hull, every sub-tile
+    outside a hull is dead, a hull is tight, and the numbered patterns are
+    the few that spare work."""
+    strips_of(sub)
+    if bq % sub or bk % sub:
+        assert fa._strip_size(bq, bk) == 128
+        return
+    assert fa._strip_size(bq, bk) == sub
+    table, patterns = mask_tile_table(BlockDiffusionMask(L, b), bq, bk, sub)
+    want = brute_patterns(L, b, bq, bk, sub)
+    number = table // PATTERN
+    assert ((number > 0) <= ((table & PARTIAL) != 0)).all()
+    assert number.max() == len(patterns) <= fa._MAX_PATTERNS
+    assert len(set(patterns)) == len(patterns)
+    if b == 4:
+        assert len(patterns) == N_PATTERNS[bq, bk, sub]
+    at = onp.arange(bk // sub)
+    for (i, j), live in want.items():
+        n = number[i, j]
+        if n == 0:      # computed whole
+            if len(patterns) < fa._MAX_PATTERNS:    # for nothing is spared
+                assert live[:, [0, -1]].all()
+            continue
+        hulls = patterns[n - 1]
+        assert len(hulls) == bq // sub
+        for r, (lo, hi) in enumerate(hulls):
+            assert not (live[r] & ~((at >= lo) & (at < hi))).any()
+            assert (lo, hi) == (0, 0) if not live[r].any() \
+                else live[r, lo] and live[r, hi - 1]
+
+
+def test_a_spec_with_many_patterns_keeps_the_whole_tile_for_the_rest(
+        monkeypatch):
+    """At (256, 512) in strips of 128 the spec has four patterns; with
+    room for two the two that spare the most keep their branches and the
+    other tiles are computed whole."""
+    spec = BlockDiffusionMask(4096, 4)
+    table4, four = mask_tile_table(spec, 256, 512, 128)
+    monkeypatch.setattr(fa, "_MAX_PATTERNS", 2)
+    mask_tile_table.cache_clear()
+    try:
+        table2, two = mask_tile_table(spec, 256, 512, 128)
+    finally:
+        mask_tile_table.cache_clear()
+    assert len(four) == 4 and two == four[:2]
+    assert (table2 % PATTERN == table4 % PATTERN).all()
+    n4, n2 = table4 // PATTERN, table2 // PATTERN
+    assert (n2 == onp.where(n4 <= 2, n4, 0)).all() and (n4 > 2).any()
+
+
+def subtile_counts(L, b, bq, bk, sub):
+    """(computed, held) sub-tiles of ``sub`` in the PARTIAL tiles of one
+    pass, from brute force: a strip computes its hull."""
+    want = brute_patterns(L, b, bq, bk, sub)
+    held = len(want) * (bq // sub) * (bk // sub)
+    hull = lambda row: (row.size - row[::-1].argmax() - row.argmax()
+                        if row.any() else 0)
+    return sum(hull(row) for live in want.values() for row in live), held
+
+
+def test_the_cell_shape_counts_what_the_issue_states():
+    assert subtile_counts(4096, 4, 1024, 1024, 128) == (32 + 288, 768)
+    assert subtile_counts(4096, 4, 256, 512, 128) == (32 + 48 + 112, 384)
+    assert subtile_counts(4096, 4, 1024, 1024, 256) == (16 + 80, 192)
+    assert subtile_counts(4096, 4, 256, 512, 256) == (16 + 16 + 32, 96)
+
+
 def test_all_three_kinds_occur_at_the_tested_tiles():
-    kinds = mask_tile_table(BlockDiffusionMask(256, 4), 128, 128) \
+    kinds = mask_tile_table(BlockDiffusionMask(256, 4), 128, 128, 128)[0] \
         & (WHOLE | PARTIAL)
     assert {DEAD, WHOLE, PARTIAL} == set(kinds.reshape(-1).tolist())
     # the dead quadrant: no clean query sees a noised key
@@ -102,12 +207,24 @@ def test_all_three_kinds_occur_at_the_tested_tiles():
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("hq,hkv,d", [(8, 2, 128), (4, 4, 64)])
-def test_masked_flash_matches_the_dense_mask(cap_tiles, hq, hkv, d, dtype):
+@pytest.mark.parametrize("L,caps,sub", [
+    (256, (128, 128), 128),     # a tile is one sub-tile: no strips
+    (512, (256, 256), 128),     # two strips a tile: the band, the diagonal
+    (512, (512, 512), 128),     # four
+    (512, (256, 128), 128),     # the band's strips with no live key, in
+    (512, (512, 256), 256),     # the tile that assigns dq
+    (512, (512, 512), 256),
+    (512, (1024, 1024), 512),   # one tile, dead quadrant and all
+])
+def test_masked_flash_matches_the_dense_mask(cap_tiles, strips_of, L, caps,
+                                             sub, hq, hkv, d, dtype):
     """8 query heads over 2 key/value heads of 128 (and no grouping at
-    64), L=256 in blocks of 4 at tiles of 128: 4 x 4 tiles of all three
-    kinds, forward and the fused backward."""
-    L, b = 256, 4
-    cap_tiles(128)
+    64), blocks of 4: L=256 at tiles of 128, 4 x 4 tiles of all three
+    kinds, and L=512 at tiles that hold several strips of 128 to 512;
+    forward and the fused backward, and the sub-tiles both counted."""
+    strips_of(sub)
+    b = 4
+    cap_tiles(*caps)
     spec = BlockDiffusionMask(L, b)
     rs = onp.random.RandomState(0)
     mk = lambda h: jnp.asarray(rs.randn(1, h, 2 * L, d).astype("f"), dtype)
@@ -126,6 +243,17 @@ def test_masked_flash_matches_the_dense_mask(cap_tiles, hq, hkv, d, dtype):
     assert after["flash_mask_pallas"] > before.get("flash_mask_pallas", 0)
     assert after["flash_bwd_pallas"] == before.get("flash_bwd_pallas", 0) + 1
     assert after.get("flash_bwd_scan", 0) == before.get("flash_bwd_scan", 0)
+    # two forward traces and one backward, each at the tiles the chooser
+    # gives under the caps (the budget may hold the backward's below them)
+    S, size = 2 * L, q.dtype.itemsize
+    fwd, bwd = (subtile_counts(L, b, *tiles, fa._strip_size(*tiles))
+                for tiles in (fa.choose_tiles(S, S, d, size, backward=back)
+                              for back in (False, True)))
+    for at, name in enumerate(("live", "tile")):
+        name = "flash_mask_subtiles_" + name
+        assert after.get(name, 0) - before.get(name, 0) \
+            == 2 * fwd[at] + bwd[at], name
+    assert (fwd[0] < fwd[1]) == (caps != (128, 128))
 
     mask = jnp.asarray(dense_mask(L, b))
     ref = lambda q, k, v: dense_attention(q, k, v, mask)
