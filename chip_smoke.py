@@ -388,21 +388,24 @@ def phase_kernels(cfg):
 
 
 def phase_masked(cfg):
-    """The kernels of the block-diffusion mixture-of-experts step against
-    their plain twins: the flash forward and backward under
-    ``BlockDiffusionMask`` with grouped heads, and the grouped matrix
-    products. Float32 at ``highest`` to the tolerances ``phase_kernels``
-    holds the causal kernels to; then bfloat16 at the cell's own shapes,
-    (2, 32 over 4, 8192, 128) and (16384, 2048) x (16, 2048, 1536),
-    (16384, 768) x (16, 768, 2048), to bfloat16's rounding. The kernels
-    are forced (``use_pallas=True``): what the chip's compiler refuses
-    fails here, nothing falls back."""
+    """The kernels of the mixture-of-experts steps against their plain
+    twins: the flash forward and backward under ``BlockDiffusionMask``
+    and under ``SlidingWindowMask`` with grouped heads, and the grouped
+    matrix products. Float32 at ``highest`` to the tolerances
+    ``phase_kernels`` holds the causal kernels to; then bfloat16 at the
+    cells' own shapes, (2, 32 over 4, 8192, 128) and (16384, 2048) x
+    (16, 2048, 1536), (16384, 768) x (16, 768, 2048), to bfloat16's
+    rounding, and at 16,384 positions of one key/value head's group of 7,
+    window and causal, where a head's dq is past one block and the
+    backward walks it in segments. The kernels are forced
+    (``use_pallas=True``): what the chip's compiler refuses fails here,
+    nothing falls back."""
     import jax
     import jax.numpy as jnp
 
     from mxnet_tpu import kernels
-    from mxnet_tpu.kernels.flash_attention import (BlockDiffusionMask,
-                                                   flash_attention)
+    from mxnet_tpu.kernels.flash_attention import (
+        BlockDiffusionMask, SlidingWindowMask, flash_attention)
     from mxnet_tpu.kernels.grouped_matmul import grouped_matmul
 
     on_chip = not cfg.rehearse
@@ -417,15 +420,19 @@ def phase_masked(cfg):
         log(f"masked: {name} max |pallas - plain| = {err:.3e} (tol {tol})")
         assert bool(jnp.isfinite(got).all()) and err < tol, (name, err)
 
-    def attention(dtype, b, hq, hkv, seq, d, tols):
-        mask = BlockDiffusionMask(seq, 4)
-        q, do = (randn(dtype, b, hq, 2 * seq, d) for _ in range(2))
-        k, v = (randn(dtype, b, hkv, 2 * seq, d) for _ in range(2))
+    def attention(dtype, b, hq, hkv, size, d, tols, window=None):
+        """Over ``size`` positions: causal within ``window`` positions (0:
+        causal alone), or block diffusion over two halves by default."""
+        mask = BlockDiffusionMask(size // 2, 4) if window is None else \
+            SlidingWindowMask(size, window) if window else None
+        q, do = (randn(dtype, b, hq, size, d) for _ in range(2))
+        k, v = (randn(dtype, b, hkv, size, d) for _ in range(2))
 
         def run(pallas):
             def f(q, k, v, do):
                 o, vjp = jax.vjp(lambda *a: flash_attention(
-                    *a, mask=mask, use_pallas=pallas), q, k, v)
+                    *a, mask=mask, causal=mask is None, use_pallas=pallas),
+                    q, k, v)
                 return (o,) + vjp(do)
             return jax.jit(f)
 
@@ -452,8 +459,8 @@ def phase_masked(cfg):
                 want[3] = want[3].at[kv].add(dv)
         for name, g, w, tol in zip(("forward", "dq", "dk", "dv"), got, want,
                                    tols):
-            close(f"flash {jnp.dtype(dtype).name} {(b, hq, hkv, 2 * seq, d)}"
-                  f" {name}", g, w, tol)
+            close(f"flash {jnp.dtype(dtype).name} {(b, hq, hkv, size, d)}"
+                  f" {mask or 'causal'} {name}", g, w, tol)
 
     def products(dtype, m, k, n, groups, tol):
         lhs, dout = randn(dtype, m, k), randn(dtype, m, n)
@@ -482,16 +489,25 @@ def phase_masked(cfg):
                   scale=float(jnp.abs(jnp.asarray(w, jnp.float32)).max()))
 
     with jax.default_matmul_precision("highest"):
-        attention(jnp.float32, *((1, 4, 2, 128, 32) if cfg.rehearse
-                                 else (1, 8, 2, 1024, 128)),
+        attention(jnp.float32, *((1, 4, 2, 256, 32) if cfg.rehearse
+                                 else (1, 8, 2, 2048, 128)),
                   (1e-5, 1e-4, 1e-4, 1e-4))
+        attention(jnp.float32, *((1, 4, 2, 256, 32) if cfg.rehearse
+                                 else (1, 14, 2, 2048, 128)),
+                  (1e-5, 1e-4, 1e-4, 1e-4),
+                  window=128 if cfg.rehearse else 512)
         products(jnp.float32, *((512, 128, 256, 4) if cfg.rehearse
                                 else (4096, 768, 2048, 16)), 1e-5)
     if cfg.rehearse:
         return
     # bfloat16 carries 8 bits: results of size ~1 and gradients of size
     # ~10 round by up to 4e-3 and 4e-2 of a unit on either side
-    attention(jnp.bfloat16, 2, 32, 4, 4096, 128, (2e-2, 1e-1, 2e-1, 2e-1))
+    attention(jnp.bfloat16, 2, 32, 4, 8192, 128, (2e-2, 1e-1, 2e-1, 2e-1))
+    walked = kernels.counters().get("flash_bwd_q_segments", 0)
+    for window in (4096, 0):    # the head past one dq block, in segments
+        attention(jnp.bfloat16, 1, 7, 1, 16384, 128,
+                  (2e-2, 1e-1, 2e-1, 2e-1), window=window)
+    assert kernels.counters()["flash_bwd_q_segments"] >= walked + 2 * 2
     products(jnp.bfloat16, 16384, 2048, 1536, 16, 1e-2)
     products(jnp.bfloat16, 16384, 768, 2048, 16, 1e-2)
     counted = kernels.counters()

@@ -131,8 +131,9 @@ class SwitchMoE(HybridBlock):
 
 
 class TopKMoE(HybridBlock):
-    """Drop-free top-k mixture of SiLU-gated experts
-    (``parallel.moe.top_k_router`` + ``expert_ffn``).
+    """Drop-free top-k mixture of gated experts
+    (``parallel.moe.top_k_router`` + ``expert_ffn``), the gate's
+    ``activation`` ``"silu"`` or ``"relu"`` (ReGLU).
 
     The router is ``num_experts`` wide and takes the ``top_k`` largest
     probabilities, renormalised with ``norm_topk_prob``. The layer holds
@@ -140,7 +141,11 @@ class TopKMoE(HybridBlock):
     default) and computes exactly the part of the result that those
     give, for every assignment that lands on them: what the experts held
     elsewhere would add is not in ``out``. forward(x (B, S, D)) -> out
-    (B, S, D) without the residual. ``expert_rows`` (no gradient) holds
+    (B, S, D) without the residual; forward(x, router_input) routes by
+    ``router_input`` (B, S, D) instead of ``x`` (a router placed before
+    the attention of its block): the experts and their weights come
+    from it, the rows they compute on from ``x``, and the router's
+    gradient flows to it. ``expert_rows`` (no gradient) holds
     the rows each held expert got in the last training forward; a
     compiled step carries it back like BatchNorm's running statistics,
     and ``SPMDTrainer`` publishes it as the ``moe/*`` telemetry counters.
@@ -152,9 +157,14 @@ class TopKMoE(HybridBlock):
 
     def __init__(self, num_experts, hidden_size, top_k, in_units=0,
                  experts_held=None, norm_topk_prob=True, axis_name="ep",
-                 mesh=None, prefix=None, params=None):
+                 mesh=None, activation="silu", prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
-        from ....parallel.moe import note_expert_rows
+        from ....parallel.moe import ACTIVATIONS, note_expert_rows
+
+        if activation not in ACTIVATIONS:
+            raise ValueError(f"activation {activation!r}: one of "
+                             f"{sorted(ACTIVATIONS)}")
+        self._act = activation
 
         self._E, self._F, self._k = int(num_experts), int(hidden_size), \
             int(top_k)
@@ -186,8 +196,8 @@ class TopKMoE(HybridBlock):
         self.expert_w13.shape = (count, D, 2 * self._F)
         self.expert_w2.shape = (count, self._F, D)
 
-    def hybrid_forward(self, F, x, gate_weight, expert_w13, expert_w2,
-                       expert_rows):
+    def hybrid_forward(self, F, x, router_input=None, *, gate_weight,
+                       expert_w13, expert_w2, expert_rows):
         from .... import autograd
         from ....ndarray.registry import apply_pure
         from ....parallel import moe
@@ -197,18 +207,26 @@ class TopKMoE(HybridBlock):
         sharded = mesh is not None and self._axis in mesh.axis_names \
             and mesh.shape[self._axis] > 1 and self._held[1] == self._E
         k, held, n, norm = self._k, self._held, self._E, self._norm
+        act = self._act
 
-        def pure(xv, gw, w13, w2):
+        def pure(xv, gw, w13, w2, rv=None):
             flat = xv.reshape(-1, xv.shape[-1])
+            by = flat if rv is None else rv.reshape(flat.shape)
             if sharded:
                 y, rows = moe.expert_parallel_ffn(
-                    flat, gw, w13, w2, k, mesh, self._axis, norm)
+                    flat, gw, w13, w2, k, mesh, self._axis, norm,
+                    activation=act,
+                    router_input=None if rv is None else by)
             else:
-                idx, gates = moe.top_k_router(flat, gw, k, norm)
-                y, rows = moe.expert_ffn(flat, idx, gates, w13, w2, held, n)
+                idx, gates = moe.top_k_router(by, gw, k, norm)
+                y, rows = moe.expert_ffn(flat, idx, gates, w13, w2, held, n,
+                                         activation=act)
             return y.reshape(xv.shape), rows.astype("float32")
 
-        out, rows = apply_pure(pure, [x, gate_weight, expert_w13, expert_w2])
+        inputs = [x, gate_weight, expert_w13, expert_w2]
+        if router_input is not None:
+            inputs.append(router_input)
+        out, rows = apply_pure(pure, inputs)
         if autograd.is_training():
             expert_rows._data = rows.data
         return out

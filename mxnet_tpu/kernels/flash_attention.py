@@ -27,12 +27,21 @@ gradients once. Design:
   p^T = exp(s^T - lse) is recomputed, ds^T = p^T (dp^T - delta) stays
   float32 in VMEM; dk and dv tiles are float32 scratch resident over the
   q sweep; the (S_q, D) float32 dq of one head is the resident output
-  block of the whole sweep. A shape whose dq does not fit the budget
-  takes the ``lax.scan`` backward and counts ``flash_bwd_scan`` in
-  ``kernels.counters()`` (the kernel counts ``flash_bwd_pallas``).
+  block of the whole sweep. A head too long for that (16,384 x 128:
+  8 MiB, twice that double-buffered) is cut into equal *segments* of
+  query rows whose dq block fits (``choose_backward``): the grid gains
+  the segment as an outer dimension, (B, H, segments, k tiles, q tiles
+  of a segment), dk and dv leave once per segment and are summed over
+  the segments where the group is summed, after the kernel; under a
+  mask spec a segment walks only the k tiles of its own band. The kernel
+  counts ``flash_bwd_pallas`` and its segments ``flash_bwd_q_segments``
+  in ``kernels.counters()``; a shape beside whose smallest segment no
+  tile fits takes the ``lax.scan`` backward and counts
+  ``flash_bwd_scan``.
 - which scores live: ``causal`` (the diagonal, decided from the grid
-  indices as it always was), or a static ``mask`` spec such as
-  ``BlockDiffusionMask``: at trace time the spec gives a table of tile
+  indices as it always was), or a static ``mask`` spec
+  (``BlockDiffusionMask``, ``SlidingWindowMask``: dead tiles may lie on
+  either side of a query tile's live ones): at trace time the spec gives a table of tile
   kinds (dead, whole, partly masked) per (q tile, k tile), which rides
   into both kernels as a prefetched scalar array and drives the skipping
   and the index maps exactly as the diagonal does. A partly masked tile
@@ -40,8 +49,8 @@ gradients once. Design:
   *pattern*, which says for each strip of queries (512, or the largest
   halving of it that divides both tiles) the hull of the strip-sized
   sub-tiles of its keys that hold a live score. A regular spec has few
-  patterns (the block-diffusion band and its block-causal diagonal),
-  static data each kernel gets one unrolled branch for: a strip does its
+  patterns (the block-diffusion band and its block-causal diagonal; a
+  window's diagonal and trailing edge), static data each kernel gets one unrolled branch for: a strip does its
   products, its statistics and its rows of the gradients on its hull
   alone, the spec's element rule on a column of its query ids and a row
   of its key ids; a pattern's strips go through each stage together, and
@@ -157,6 +166,39 @@ class BlockDiffusionMask:
         clean = onp.stack([onp.stack([L + empty, L + (blk + 1) * b], -1),
                            onp.stack([empty, empty], -1)], 1)
         return onp.concatenate([noisy, clean], 0)
+
+
+@dataclasses.dataclass(frozen=True)
+class SlidingWindowMask:
+    """Which scores live under causal sliding-window attention over
+    ``seq_len`` positions: a query sees its own position and the
+    ``window - 1`` before it (``0 <= i - j < window``). Dead tiles lie on
+    both sides of a query tile's band; the diagonal's triangle and the
+    band's trailing edge, its complement, are the two partly masked
+    patterns at square tiles. Static and hashable, as the other spec."""
+
+    seq_len: int
+    window: int
+
+    def __post_init__(self):
+        if self.window < 1:
+            raise ValueError(f"a window of {self.window} positions")
+
+    @property
+    def size(self):
+        return self.seq_len
+
+    def element(self, qid, kid):
+        """The element rule on int32 index arrays that broadcast: the
+        window's edge is the query's own to work out, two comparisons
+        broadcast."""
+        return (kid <= qid) & (kid > qid - self.window)
+
+    def row_intervals(self):
+        """(S, 1, 2) int: every query row's live keys, one interval."""
+        i = onp.arange(self.seq_len)
+        return onp.stack([onp.maximum(i - self.window + 1, 0), i + 1],
+                         -1)[:, None]
 
 
 def _live_counts(mask, unit):
@@ -286,13 +328,15 @@ def tile_vmem_bytes(bq, bk, s_q, d, itemsize, backward=False):
     """Upper estimate of the VMEM one grid step holds at tiles (bq, bk):
     every operand and result block double-buffered by the pipeline (the
     head dimension padded to the 128 lanes), the float32 scratch, and the
-    float32 (bq, bk) working tiles with their operand-dtype casts. The
-    v5e compiler takes about twice the tile this admits and refuses four
-    times (tests/test_chip_compile.py holds both sides)."""
+    float32 (bq, bk) working tiles with their operand-dtype casts.
+    ``s_q`` is the query rows whose float32 dq the backward keeps
+    resident: a whole head's, or a segment's of it. The v5e compiler
+    takes about twice the tile this admits and refuses four times
+    (tests/test_chip_compile.py holds both sides)."""
     lanes = _pad128(d)
     row = lanes * itemsize
     if backward:
-        # q, do, k, v in; dk, dv out; lse, delta rows; dq of a whole head
+        # q, do, k, v in; dk, dv out; lse, delta rows; dq of s_q rows
         blocks = 2 * bq * row + 4 * bk * row + 2 * 8 * bq * 4 \
             + _pad128(s_q) * lanes * 4
         scratch = 2 * bk * lanes * 4
@@ -304,17 +348,16 @@ def tile_vmem_bytes(bq, bk, s_q, d, itemsize, backward=False):
     return 2 * blocks + scratch + work
 
 
-def choose_tiles(s_q, s_k, d, itemsize, backward=False,
-                 budget=_VMEM_BUDGET_BYTES):
-    """(bq, bk) for one pass of the kernel, or None when not even a
-    128 x 128 tile fits ``budget``: a pure function of the shape. Tiles
-    are multiples of 128 dividing the sequence padded to 128, as large as
-    the pass's cap and the budget allow."""
-    sq_p, sk_p = _pad128(s_q), _pad128(s_k)
+def _tiles_within(rows_q, s_k, d, itemsize, backward, budget):
+    """(bq, bk) as large as the pass's cap and ``budget`` allow, bq
+    dividing ``rows_q`` (the padded query rows of the pass, or of one
+    segment of the backward, whose dq is priced) and bk the padded keys;
+    None when not even 128 x 128 fits."""
+    sk_p = _pad128(s_k)
     cq, ck = _BWD_CAPS if backward else _FWD_CAPS
     while True:
-        bq, bk = _tile_under(sq_p, cq), _tile_under(sk_p, ck)
-        if tile_vmem_bytes(bq, bk, s_q, d, itemsize,
+        bq, bk = _tile_under(rows_q, cq), _tile_under(sk_p, ck)
+        if tile_vmem_bytes(bq, bk, rows_q, d, itemsize,
                            backward) <= budget:
             return bq, bk
         if bq == bk == _TILE_COLS:
@@ -325,15 +368,57 @@ def choose_tiles(s_q, s_k, d, itemsize, backward=False,
             ck = bk - _TILE_COLS
 
 
+def choose_backward(s_q, s_k, d, itemsize, budget=_VMEM_BUDGET_BYTES):
+    """``(bq, bk, rows)`` of the backward kernel, or None when nothing
+    fits ``budget``: its tiles and the query rows of one *segment*, the
+    part of a head whose float32 dq is the resident output block. A head
+    whose whole dq fits beside some tile is one segment, at the tiles it
+    always had. A longer head is cut into the fewest equal segments
+    (multiples of 128 rows) beside which the tiles reach the pass's caps
+    as far as the shape lets them; where none is, into the fewest beside
+    which anything fits."""
+    sq_p, sk_p = _pad128(s_q), _pad128(s_k)
+    whole = _tiles_within(sq_p, s_k, d, itemsize, True, budget)
+    if whole:
+        return (*whole, sq_p)
+    fallback = None
+    for n in range(2, sq_p // _TILE_COLS + 1):
+        rows = sq_p // n
+        if sq_p % n or rows % _TILE_COLS:
+            continue
+        tiles = _tiles_within(rows, s_k, d, itemsize, True, budget)
+        if tiles == (_tile_under(rows, _BWD_CAPS[0]),
+                     _tile_under(sk_p, _BWD_CAPS[1])):
+            return (*tiles, rows)
+        if tiles and fallback is None:
+            fallback = (*tiles, rows)
+    return fallback
+
+
+def choose_tiles(s_q, s_k, d, itemsize, backward=False,
+                 budget=_VMEM_BUDGET_BYTES):
+    """(bq, bk) for one pass of the kernel, or None when not even a
+    128 x 128 tile fits ``budget``: a pure function of the shape. Tiles
+    are multiples of 128 dividing the sequence padded to 128, as large as
+    the pass's cap and the budget allow (the backward's beside the dq of
+    the segment ``choose_backward`` gives it)."""
+    if backward:
+        chosen = choose_backward(s_q, s_k, d, itemsize, budget)
+        return chosen and chosen[:2]
+    return _tiles_within(_pad128(s_q), s_k, d, itemsize, False, budget)
+
+
 def vmem_bytes(s_q, s_k, d, itemsize):
     """What the gate prices: the larger of the two passes' footprints at
-    the tiles the chooser gives them — at the 128 floor where nothing
-    fits, so that the answer is over the budget then."""
+    the tiles (and the backward's segment) the chooser gives them — at
+    the 128 floor where nothing fits, so that the answer is over the
+    budget then."""
     floor = (_TILE_COLS, _TILE_COLS)
+    bq, bk, rows = choose_backward(s_q, s_k, d, itemsize) or (*floor, s_q)
     return max(
-        tile_vmem_bytes(*(choose_tiles(s_q, s_k, d, itemsize, bwd)
-                          or floor), s_q, d, itemsize, bwd)
-        for bwd in (False, True))
+        tile_vmem_bytes(*(choose_tiles(s_q, s_k, d, itemsize) or floor),
+                        s_q, d, itemsize),
+        tile_vmem_bytes(bq, bk, rows, d, itemsize, True))
 
 
 def _pad_rows(x, n):
@@ -481,14 +566,32 @@ def _fa_kernel(*refs, bq, bk, nk, sm_scale, causal, s_k_real, causal_off,
             lse_out[0][:] = lse.T[:1]
 
 
-def _mask_tiles(mask, q, k, backward, bq, bk):
-    """Tiles, strips and the two prefetched tables of a mask spec's pass:
-    ``(number, parts, empty)`` for each pattern number a partly masked
-    tile bears, cut as ``_strips`` cuts them (number 0: the tile whole);
-    the kinds, flattened row-major over (q tile, k tile), and per tile
-    the block to name where it is dead (k blocks forward, q blocks
-    backward). Counts the strip-sized sub-tiles the pass's partly masked
-    tiles hold, and those of them it computes."""
+def _segment_bands(kinds, nseg):
+    """``(first, width)`` in k tiles of the band each of ``nseg`` equal
+    segments of q tiles walks in the backward: ``width`` holds the live
+    k tiles of the segment that has most, ``first[s]`` is where segment
+    s's band starts (pulled back where it would pass the last k tile).
+    One segment walks every k tile, as it always did."""
+    nk = kinds.shape[1]
+    if nseg == 1:
+        return onp.zeros(1, onp.int32), nk
+    live = ((kinds & (WHOLE | PARTIAL)) != 0).reshape(nseg, -1, nk).any(1)
+    lo = live.argmax(1)
+    hi = nk - live[:, ::-1].argmax(1)
+    width = int((hi - lo).max())
+    return onp.minimum(lo, nk - width).astype(onp.int32), width
+
+
+def _mask_tiles(mask, q, k, backward, bq, bk, nseg=1):
+    """Strips, band and the prefetched tables of a mask spec's pass at
+    tiles (bq, bk): ``(number, parts, empty)`` for each pattern number a
+    partly masked tile bears, cut as ``_strips`` cuts them (number 0: the
+    tile whole); the k tiles a segment's band holds; the kinds, flattened
+    row-major over (q tile, k tile), per tile the block to name where it
+    is dead (k blocks forward; backward q blocks of the tile's own
+    segment), and, where the backward has ``nseg`` > 1 segments, each
+    segment's first k tile. Counts the strip-sized sub-tiles the pass's
+    partly masked tiles hold, and those of them it computes."""
     S = q.shape[2]
     if mask.size != S or k.shape[2] != S:
         raise ValueError(f"mask over {mask.size} positions, q {q.shape} "
@@ -496,12 +599,16 @@ def _mask_tiles(mask, q, k, backward, bq, bk):
     if S % _TILE_COLS:
         raise ValueError(f"a mask spec needs a multiple of {_TILE_COLS} "
                          f"positions, got {S}")
-    if bq is None or bk is None:
-        bq, bk = choose_tiles(S, S, q.shape[3], q.dtype.itemsize,
-                              backward=backward)
     sub = _strip_size(bq, bk)
     kinds, patterns = mask_tile_table(mask, bq, bk, sub)
-    fetch = _fetch_table(kinds, 0 if backward else 1)
+    if backward:
+        per = kinds.shape[0] // nseg
+        fetch = onp.concatenate([
+            _fetch_table(kinds[s * per:(s + 1) * per], 0) + s * per
+            for s in range(nseg)])
+    else:
+        fetch = _fetch_table(kinds, 1)
+    first, width = _segment_bands(kinds, nseg)
     held = (bq // sub) * (bk // sub)
     number = kinds[(kinds & PARTIAL) != 0] // PATTERN
     computed = onp.array([held] + [sum(hi - lo for lo, hi in pattern)
@@ -511,8 +618,10 @@ def _mask_tiles(mask, q, k, backward, bq, bk):
     strips = tuple(
         (n,) + (_strips(patterns[n - 1], sub) if n else (_WHOLE_TILE, ()))
         for n in onp.unique(number).tolist())
-    return bq, bk, strips, jnp.asarray(kinds.reshape(-1)), \
-        jnp.asarray(fetch.reshape(-1))
+    tables = [jnp.asarray(kinds.reshape(-1)), jnp.asarray(fetch.reshape(-1))]
+    if nseg > 1:
+        tables.append(jnp.asarray(first))
+    return strips, (first, width), tables
 
 
 def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
@@ -527,11 +636,11 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
     S_k = k.shape[2]
     group = H // k.shape[1]
     tables = strips = ()
-    if mask is not None:
-        bq, bk, strips, *tables = _mask_tiles(mask, q, k, False, bq, bk)
-        _count("flash_mask_pallas")
     if bq is None or bk is None:
         bq, bk = choose_tiles(S_q, S_k, D, q.dtype.itemsize)
+    if mask is not None:
+        strips, _, tables = _mask_tiles(mask, q, k, False, bq, bk)
+        _count("flash_mask_pallas")
     pq = (-S_q) % bq
     pk = (-S_k) % bk
     Sq_p, Sk_p = S_q + pq, S_k + pk
@@ -598,34 +707,46 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
 # backward
 
 def _fa_bwd_kernel(*refs, bq, bk, nq, sm_scale, causal, s_k_real,
-                   causal_off, mask=None, strips=()):
+                   causal_off, mask=None, strips=(), nseg=1):
     """Grid (B, H, nk, nq), i innermost: one transposed (bk, bq) tile per
     step. dk_s/dv_s accumulate one k tile's gradients over the q sweep;
-    dq_ref is the whole head's (S_q, D) float32 block, resident until the
-    head changes, and takes each tile's rows as they come: the first live
-    k tile of a q tile assigns (kb == 0 under ``causal``, the table's
-    FIRST under a ``mask`` spec, whose two tables lead the refs and whose
-    ``strips`` are those of each pattern number its tiles bear)."""
+    dq_ref is the (rows, D) float32 block of ``nq`` q tiles, resident
+    until the head changes, and takes each tile's rows as they come: the
+    first live k tile of a q tile assigns (kb == 0 under ``causal``, the
+    table's FIRST under a ``mask`` spec, whose tables lead the refs and
+    whose ``strips`` are those of each pattern number its tiles bear).
+    A head too long for its dq to be one block has ``nseg`` > 1 such
+    *segments*: the grid is (B, H, nseg, k tiles of a segment's band,
+    nq), dk and dv leave once per segment, and under a mask spec a third
+    table gives each segment's first k tile."""
     if mask is not None:
         kinds_ref, _, *refs = refs
+        if nseg > 1:
+            first_ref, *refs = refs
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
      dv_ref, dk_s, dv_s) = refs
-    kb = pl.program_id(2)
-    i = pl.program_id(3)
+    lead = 3 if nseg > 1 else 2         # grid dimensions before the k tile's
+    kb = pl.program_id(lead)
+    il = i = pl.program_id(lead + 1)    # in the segment, in the head
+    if nseg > 1:
+        seg = pl.program_id(2)
+        i = seg * nq + il
+        if mask is not None:
+            kb = first_ref[seg] + kb
     if mask is not None:
         kind = kinds_ref[i * (mask.size // bk) + kb]
 
-    @pl.when(i == 0)
+    @pl.when(il == 0)
     def _init():
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
 
     def _to_dq(rows, dq=None):
-        """``dq`` (None: zeros) into the head's dq at the tile's query
+        """``dq`` (None: zeros) into the segment's dq at the tile's query
         ``rows``: assigned by a q tile's first live k tile, added by the
         rest."""
         n_rows = len(range(*rows.indices(bq)))
-        at = pl.ds(pl.multiple_of(_from(i * bq, rows), n_rows), n_rows)
+        at = pl.ds(pl.multiple_of(_from(il * bq, rows), n_rows), n_rows)
 
         @pl.when(kb == 0 if mask is None else (kind & FIRST) != 0)
         def _first():
@@ -690,71 +811,114 @@ def _fa_bwd_kernel(*refs, bq, bk, nq, sm_scale, causal, s_k_real,
         pl.when(live & masked)(functools.partial(_tile, True))
         pl.when(live & ~masked)(functools.partial(_tile, False))
 
-    @pl.when(i == nq - 1)
+    @pl.when(il == nq - 1)
     def _finalize():
         dk_ref[:] = (dk_s[:] * sm_scale).astype(dk_ref.dtype)
         dv_ref[:] = dv_s[:].astype(dv_ref.dtype)
 
 
+def _sum_segments(a, group, first, bk, s_k):
+    """dk or dv as the kernel left it, (B, H, segments, a band's rows, D)
+    with segment s's band starting at k tile ``first[s]``, summed over
+    the segments and the group into (B, H / group, s_k, D), in
+    float32."""
+    B, H, nseg, band, D = a.shape
+    a = a.reshape(B, H // group, group, nseg, band, D)
+    out = jnp.zeros((B, H // group, max(s_k, int(first.max()) * bk + band),
+                     D), jnp.float32)
+    for s, at in enumerate(first.tolist()):
+        out = out.at[:, :, at * bk:at * bk + band].add(
+            a[:, :, :, s].astype(jnp.float32).sum(2))
+    return out[:, :, :s_k].astype(a.dtype)
+
+
 def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
-                     bq=None, bk=None, mask=None):
+                     bq=None, bk=None, mask=None, rows=None):
     """(dq, dk, dv) by the fused backward kernel at the chooser's tiles
-    (``bq``/``bk`` override them for tests), from the forward's o and
-    lse. With grouped heads the kernel writes dk, dv per query head and
-    the group is summed here, in float32."""
+    and segment (``bq``/``bk``/``rows`` override them for tests), from
+    the forward's o and lse. The kernel writes dk, dv per query head and
+    per segment; the group and the segments are summed here, in
+    float32."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S_q, D = q.shape
     S_k = k.shape[2]
     group = H // k.shape[1]
-    tables = strips = ()
-    if mask is not None:
-        bq, bk, strips, *tables = _mask_tiles(mask, q, k, True, bq, bk)
     if bq is None or bk is None:
-        bq, bk = choose_tiles(S_q, S_k, D, q.dtype.itemsize, backward=True)
+        bq, bk, chosen = choose_backward(S_q, S_k, D, q.dtype.itemsize)
+        rows = rows or chosen
     pq = (-S_q) % bq
     pk = (-S_k) % bk
     Sq_p, Sk_p = S_q + pq, S_k + pk
+    rows = rows or Sq_p
+    if Sq_p % rows or rows % bq:
+        raise ValueError(f"segments of {rows} rows, {Sq_p} query rows in "
+                         f"tiles of {bq}")
+    nseg = Sq_p // rows
+    _count("flash_bwd_q_segments", nseg)
+    nq = rows // bq                     # q tiles a segment
+    nk = Sk_p // bk
+    first, band = onp.zeros(nseg, onp.int32), nk
+    tables = strips = ()
+    if mask is not None:
+        strips, (first, band), tables = _mask_tiles(mask, q, k, True, bq, bk,
+                                                    nseg)
     if lse.shape != (B, H, 1, Sq_p):
         raise ValueError(f"lse {lse.shape} is not the forward's for q "
                          f"{q.shape} padded to tiles of {bq}")
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = jnp.pad(delta, ((0, 0), (0, 0), (0, pq)))[:, :, None]
-    nq = Sq_p // bq
-    nk = Sk_p // bk
     off = S_k - S_q
     kern = functools.partial(_fa_bwd_kernel, bq=bq, bk=bk, nq=nq,
                              sm_scale=sm_scale, causal=causal,
                              s_k_real=S_k, causal_off=off, mask=mask,
-                             strips=strips)
+                             strips=strips, nseg=nseg)
 
-    def _live_i(i, kb, tabs):
+    def ids(args):
+        """(b, h, segment, k tile, q tile of the head, tables) of a grid
+        step; the segment is the number 0 where there is one."""
+        b, h, *rest = args
+        if nseg == 1:
+            kb, i, *tabs = rest
+            return b, h, 0, kb, i, tabs
+        seg, kb, i, *tabs = rest
+        if tabs:
+            kb = tabs[2][seg] + kb
+        return b, h, seg, kb, seg * nq + i, tabs
+
+    def _live_i(args):
         # q tiles that are dead for k tile kb (above the diagonal, or by
-        # the mask's table) are skipped: name a live one and the pipeline
-        # copies nothing
+        # the mask's table) are skipped: name a live one of the segment
+        # and the pipeline copies nothing
+        b, h, seg, kb, i, tabs = ids(args)
         if tabs:
             i = tabs[1][i * nk + kb]
         elif causal:
             i = jnp.maximum(i, jnp.maximum(kb * bk - off, 0) // bq)
-        return i
+            if nseg > 1:
+                i = jnp.minimum(i, (seg + 1) * nq - 1)
+        return b, h, i
 
-    def kv_map(b, h, kb, i, *_):
+    def kv_map(*args):
+        b, h, _, kb, _, _ = ids(args)
         return b, (h // group if group > 1 else h), kb, 0
 
-    q_spec = pl.BlockSpec(
-        (None, None, bq, D),
-        lambda b, h, kb, i, *tabs: (b, h, _live_i(i, kb, tabs), 0))
-    row_spec = pl.BlockSpec(
-        (None, None, 1, bq),
-        lambda b, h, kb, i, *tabs: (b, h, 0, _live_i(i, kb, tabs)))
+    def row_map(*args):
+        b, h, i = _live_i(args)
+        return b, h, 0, i
+
+    q_spec = pl.BlockSpec((None, None, bq, D),
+                          lambda *args: (*_live_i(args), 0))
+    row_spec = pl.BlockSpec((None, None, 1, bq), row_map)
     kv_spec = pl.BlockSpec((None, None, bk, D), kv_map)
-    dkv_spec = pl.BlockSpec((None, None, bk, D),
-                            lambda b, h, kb, i, *_: (b, h, kb, 0))
-    grid = (B, H, nk, nq)
+    grid = (B, H) + ((nseg,) if nseg > 1 else ()) + (band, nq)
+    # dk, dv: a block for every grid step but the q sweep's
+    dkv_spec = pl.BlockSpec((None,) * (len(grid) - 2) + (bk, D),
+                            lambda *args: (*args[:len(grid) - 1], 0))
     in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
     out_specs = [
-        pl.BlockSpec((None, None, Sq_p, D),
-                     lambda b, h, kb, i, *_: (b, h, 0, 0)),
+        pl.BlockSpec((None, None, rows, D),
+                     lambda *args: (args[0], args[1], ids(args)[2], 0)),
         dkv_spec, dkv_spec,
     ]
     scratch = [
@@ -763,31 +927,35 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
     ]
     if tables:
         how = {"grid_spec": pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            num_scalar_prefetch=len(tables), grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch)}
     else:
         how = {"grid": grid, "in_specs": in_specs, "out_specs": out_specs,
                "scratch_shapes": scratch}
+    dkv_shape = (B, H) + ((nseg,) if nseg > 1 else ()) + (band * bk, D)
     dq, dk, dv = pl.pallas_call(
         kern,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq_p, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, Sk_p, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, Sk_p, D), v.dtype),
+            jax.ShapeDtypeStruct(dkv_shape, k.dtype),
+            jax.ShapeDtypeStruct(dkv_shape, v.dtype),
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "arbitrary", "arbitrary")),
+            ("parallel",) * (len(grid) - 2) + ("arbitrary", "arbitrary"))),
         interpret=interpret,
         # attn_bwd_ms.tokens finds the backward by this name alone
         name="flash_bwd",
         **how,
     )(*tables, _pad_rows(q, pq), _pad_rows(k, pk), _pad_rows(v, pk),
       _pad_rows(do, pq), lse, delta)  # zero do: padded rows add nothing
-    dk, dv = dk[:, :, :S_k], dv[:, :, :S_k]
-    if group > 1:
-        dk, dv = (a.reshape(B, H // group, group, S_k, D)
-                  .astype(jnp.float32).sum(2).astype(a.dtype)
-                  for a in (dk, dv))
+    if nseg > 1:
+        dk, dv = (_sum_segments(a, group, first, bk, S_k) for a in (dk, dv))
+    else:
+        dk, dv = dk[:, :, :S_k], dv[:, :, :S_k]
+        if group > 1:
+            dk, dv = (a.reshape(B, H // group, group, S_k, D)
+                      .astype(jnp.float32).sum(2).astype(a.dtype)
+                      for a in (dk, dv))
     return dq[:, :, :S_q].astype(q.dtype), dk, dv
 
 

@@ -16,8 +16,8 @@ so the all-to-alls ride within each dp row.
 
 Beside it, the drop-free layer today's models train with
 (``top_k_router`` + ``expert_ffn``): top-k of all the experts with
-renormalised weights, no capacity and no dropped token, SiLU-gated
-experts, and a layer that is told which experts it holds
+renormalised weights, no capacity and no dropped token, gated experts
+(``activation``: SiLU, or ReLU for ReGLU), and a layer that is told which experts it holds
 (``experts_held=(first, count)``) and computes exactly their part of the
 result. The assignments that land on held experts are sorted by expert
 into a bounded buffer of rows and go through the grouped matrix product
@@ -41,7 +41,8 @@ from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["moe_ffn", "switch_router", "moe_specs", "top_k_router",
-           "expert_ffn", "expert_parallel_ffn", "note_expert_rows"]
+           "expert_ffn", "expert_parallel_ffn", "note_expert_rows",
+           "ACTIVATIONS"]
 
 
 def moe_specs(mesh, axis_name="ep", batch_axes=None):
@@ -301,12 +302,16 @@ def _combine_bwd(res, dy):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+#: the gate's activation by the name the layer is given
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def _ffn_pass(p, x, gates, w13, w2, order, rank, starts, sizes, cap,
-              use_pallas):
+              use_pallas, activation):
     """The part of the result that the held assignments at sorted
     positions [p * cap, (p + 1) * cap) give: gather their tokens' rows,
-    the two grouped products around the SiLU gate, and each token's
-    weighted sum of its rows."""
+    the two grouped products around the gate's ``activation``, and each
+    token's weighted sum of its rows."""
     from ..kernels.grouped_matmul import grouped_matmul
 
     t, k = gates.shape
@@ -327,29 +332,32 @@ def _ffn_pass(p, x, gates, w13, w2, order, rank, starts, sizes, cap,
     runs = _token_runs(tok, valid, t)
     xg = _dispatch(x, tok, runs, k)
     h = grouped_matmul(xg, w13, sizes_p, use_pallas)
-    a = (jax.nn.silu(h[:, :f].astype(jnp.float32))
+    a = (ACTIVATIONS[activation](h[:, :f].astype(jnp.float32))
          * h[:, f:].astype(jnp.float32)).astype(x.dtype)
     yo = grouped_matmul(a, w2, sizes_p, use_pallas)
     return _combine(yo, gates, tok, row_gate, rows, live, runs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def _ffn(x, gates, w13, w2, order, rank, starts, sizes, cap, use_pallas):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _ffn(x, gates, w13, w2, order, rank, starts, sizes, cap, use_pallas,
+         activation):
     return _ffn_fwd(x, gates, w13, w2, order, rank, starts, sizes, cap,
-                    use_pallas)[0]
+                    use_pallas, activation)[0]
 
 
 def _passes(sizes, cap):
     return (jnp.sum(sizes) + cap - 1) // cap
 
 
-def _ffn_fwd(x, gates, w13, w2, order, rank, starts, sizes, cap, use_pallas):
+def _ffn_fwd(x, gates, w13, w2, order, rank, starts, sizes, cap, use_pallas,
+             activation):
     """Pass 0 in the open, its residuals kept; what overflows the buffer
     (none, as a rule) in a loop that keeps nothing."""
     ints = (order, rank, starts, sizes)
 
     def one(p, x, gates, w13, w2):
-        return _ffn_pass(p, x, gates, w13, w2, *ints, cap, use_pallas)
+        return _ffn_pass(p, x, gates, w13, w2, *ints, cap, use_pallas,
+                         activation)
 
     y, vjp0 = jax.vjp(functools.partial(one, 0), x, gates, w13, w2)
     y = lax.while_loop(
@@ -359,13 +367,14 @@ def _ffn_fwd(x, gates, w13, w2, order, rank, starts, sizes, cap, use_pallas):
     return y, (vjp0, x, gates, w13, w2, ints)
 
 
-def _ffn_bwd(cap, use_pallas, res, dy):
+def _ffn_bwd(cap, use_pallas, activation, res, dy):
     """Pass 0 from its residuals; the overflow passes recompute."""
     vjp0, x, gates, w13, w2, ints = res
 
     def more(c):
         p, grads = c
-        _, vjp = jax.vjp(lambda *a: _ffn_pass(p, *a, *ints, cap, use_pallas),
+        _, vjp = jax.vjp(lambda *a: _ffn_pass(p, *a, *ints, cap, use_pallas,
+                                              activation),
                          x, gates, w13, w2)
         return p + 1, tuple(g + d for g, d in zip(grads, vjp(dy)))
 
@@ -378,7 +387,7 @@ _ffn.defvjp(_ffn_fwd, _ffn_bwd)
 
 
 def expert_ffn(x, idx, gates, w13, w2, experts_held=None, num_experts=None,
-               capacity_factor=1.5, use_pallas=None):
+               capacity_factor=1.5, use_pallas=None, activation="silu"):
     """The held experts' part of a top-k mixture: ``(y (T, D), rows
     (count,) int32)``.
 
@@ -387,7 +396,10 @@ def expert_ffn(x, idx, gates, w13, w2, experts_held=None, num_experts=None,
     projections side by side, w2 (count, F, D) its down projection;
     ``experts_held=(first, count)`` (``first`` may be traced). Every
     assignment that lands on a held expert is computed, none is dropped:
-    ``y[t] = sum over those of gate * (silu(x W_gate) * (x W_up)) W_down``.
+    ``y[t] = sum over those of gate * (act(x W_gate) * (x W_up)) W_down``,
+    ``activation`` naming act: ``"silu"``, or ``"relu"`` (ReGLU). The
+    router that gave ``idx`` and ``gates`` may have read another input
+    than ``x``.
     The row buffer holds ``capacity_factor`` times the expected number of
     held assignments; more than that take further passes. What moves the
     rows around costs time in proportion to the buffer, a further pass
@@ -397,6 +409,9 @@ def expert_ffn(x, idx, gates, w13, w2, experts_held=None, num_experts=None,
     assignments each held expert got."""
     from ..kernels.grouped_matmul import ROWS
 
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"activation {activation!r}: one of "
+                         f"{sorted(ACTIVATIONS)}")
     count = w13.shape[0]
     first = 0 if experts_held is None else experts_held[0]
     num_experts = num_experts or count
@@ -407,33 +422,38 @@ def expert_ffn(x, idx, gates, w13, w2, experts_held=None, num_experts=None,
     order, rank, starts, sizes = _layout(idx, first, count)
     with jax.named_scope("moe"):
         y = _ffn(x, gates.astype(jnp.float32), w13, w2, order, rank, starts,
-                 sizes, cap, use_pallas)
+                 sizes, cap, use_pallas, activation)
     return y, sizes
 
 
 def expert_parallel_ffn(x, gate_w, w13, w2, k, mesh, axis_name="ep",
-                        norm_topk_prob=True, use_pallas=None):
+                        norm_topk_prob=True, use_pallas=None,
+                        activation="silu", router_input=None):
     """The same layer with the experts sharded over ``axis_name``: every
-    device gathers the axis's tokens, routes them over all the experts,
+    device gathers the axis's tokens, routes them over all the experts
+    (from ``router_input``'s rows where given, sharded as ``x``),
     computes the part its own slice of ``w13`` / ``w2`` gives
     (``experts_held`` from its place on the axis) and the parts are
     summed back to the tokens' owners. x (T, D) sharded over the axis on
     its rows; returns ``(y, rows (E,))``."""
     n_experts = gate_w.shape[-1]
 
-    def local(xl, gw, w13l, w2l):
+    def local(xl, rl, gw, w13l, w2l):
         xa = lax.all_gather(xl, axis_name, axis=0, tiled=True)
-        idx, gates = top_k_router(xa, gw, k, norm_topk_prob)
+        ra = xa if router_input is None else lax.all_gather(
+            rl, axis_name, axis=0, tiled=True)
+        idx, gates = top_k_router(ra, gw, k, norm_topk_prob)
         held = (lax.axis_index(axis_name) * w13l.shape[0], w13l.shape[0])
         y, rows = expert_ffn(xa, idx, gates, w13l, w2l, held, n_experts,
-                             use_pallas=use_pallas)
+                             use_pallas=use_pallas, activation=activation)
         return lax.psum_scatter(y, axis_name, scatter_dimension=0,
                                 tiled=True), rows
 
     espec = P(axis_name)
-    return _shard_map(local, mesh=mesh, in_specs=(espec, P(), espec, espec),
+    return _shard_map(local, mesh=mesh,
+                      in_specs=(espec, espec, P(), espec, espec),
                       out_specs=(espec, espec), check_vma=False)(
-        x, gate_w, w13, w2)
+        x, x if router_input is None else router_input, gate_w, w13, w2)
 
 
 # what the compiled step counted, published when its arrays are ready

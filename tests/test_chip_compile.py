@@ -157,6 +157,41 @@ def test_masked_grouped_flash_compiles(one_chip, b, hq, hkv, seq, d,
         assert after[name] - before.get(name, 0) == n, name
 
 
+@pytest.mark.parametrize("window", [4096, None], ids=["window", "causal"])
+def test_long_head_backward_compiles_in_segments(one_chip, window):
+    """The cell smallthinker21b-train-s16384: (1, 28 over 4, 16384, 128)
+    bf16, a window layer and the global one. A head's float32 dq is 8 MiB,
+    past any block the budget admits, so the backward walks the head in
+    four segments of 4,096 rows at (512, 512) tiles (a window layer's
+    segment only the 16 k tiles of its band): one ``flash_bwd``, no
+    ``while``, and no (S, S) array anywhere in the program."""
+    seq, d, dtype = 16384, 128, jnp.bfloat16
+    mask = fa.SlidingWindowMask(seq, window) if window else None
+
+    def loss(q, k, v):
+        with jax.named_scope("attn"):
+            o = _flash(q, k, v, d ** -0.5, mask is None, "pallas", mask)
+        return o.astype(jnp.float32).sum()
+
+    before = kernels.counters()
+    c = _compile(jax.grad(loss, (0, 1, 2)), one_chip,
+                 ((1, 28, seq, d), dtype), ((1, 4, seq, d), dtype),
+                 ((1, 4, seq, d), dtype))
+    _assert_kernel(c, "flash_fwd")
+    _assert_kernel(c, "flash_bwd")
+    text = c.as_text()
+    assert not re.search(r"\bwhile\(", text)
+    assert f"{seq},{seq}]" not in text
+    after = kernels.counters()
+    assert after["flash_bwd_pallas"] == before.get("flash_bwd_pallas", 0) + 1
+    assert after.get("flash_bwd_scan", 0) == before.get("flash_bwd_scan", 0)
+    assert after["flash_bwd_q_segments"] == before.get(
+        "flash_bwd_q_segments", 0) + 4
+    if window:
+        assert after["flash_mask_pallas"] > before.get("flash_mask_pallas", 0)
+    assert cost_model.pallas_fits_vmem("attention", (seq, d), 2)
+
+
 @pytest.mark.parametrize("m,k,n,g,dtype", [
     (16384, 2048, 1536, 16, jnp.bfloat16),  # the cell's gate + up product
     (16384, 768, 2048, 16, jnp.bfloat16),   # and its down product

@@ -135,15 +135,19 @@ def test_chooser_shrinks_with_the_budget():
     assert choose_tiles(768, 768, 64, 2, backward=True) == (384, 384)
 
 
-def test_backward_that_cannot_fit_takes_the_scan_and_counts():
-    """The (S_q, D) float32 dq of one head is the kernel's resident
-    block: at S 16384, D 128 it alone is over the budget. The backward
-    then lowers the scan and says so in the counters; the gate refuses
-    the shape too. Traced only: nothing this large runs here."""
+def test_backward_past_a_whole_heads_dq_lowers_the_kernel_in_segments():
+    """The (S_q, D) float32 dq of one head at S 16384, D 128 is alone over
+    the budget (8 MiB, double-buffered): the backward cuts the head into
+    segments whose dq fits, lowers the kernel and says so in the
+    counters; the gate admits the shape. Traced only: nothing this large
+    runs here. (Until PR 34 this shape took the scan.)"""
     s, d = 16384, 128
-    assert choose_tiles(s, s, d, 2, backward=True) is None
-    assert choose_tiles(s, s, d, 2) is not None  # the forward still fits
-    assert cost_model._pallas_refusal("attention", (s, d), 2) == "vmem_bound"
+    assert fa._tiles_within(s, s, d, 2, True,
+                            cost_model._VMEM_BUDGET_BYTES) is None
+    assert choose_tiles(s, s, d, 2, backward=True) == (512, 512)
+    assert fa.choose_backward(s, s, d, 2) == (512, 512, 4096)
+    assert choose_tiles(s, s, d, 2) is not None
+    assert cost_model._pallas_refusal("attention", (s, d), 2) is None
     x = jax.ShapeDtypeStruct((1, 1, s, d), jnp.bfloat16)
     before = kernels.counters()
     text = str(jax.make_jaxpr(jax.grad(
@@ -151,12 +155,33 @@ def test_backward_that_cannot_fit_takes_the_scan_and_counts():
             q, k, v, causal=True, use_pallas=True).sum().astype(jnp.float32),
         (0, 1, 2)))(x, x, x))
     after = kernels.counters()
+    assert after.get("flash_bwd_scan", 0) == before.get("flash_bwd_scan", 0)
+    assert after["flash_bwd_pallas"] == before.get("flash_bwd_pallas", 0) + 1
+    assert after["flash_bwd_q_segments"] == before.get(
+        "flash_bwd_q_segments", 0) + 4
+    assert "name=flash_fwd" in text and "name=flash_bwd" in text
+    assert "scan[" not in text
+
+
+def test_backward_that_fits_no_tile_takes_the_scan_and_counts(monkeypatch):
+    """Where not even a 128 x 128 tile fits beside a segment of 128 rows
+    the chooser gives nothing, the forward keeps no lse and the backward
+    lowers the scan, loudly."""
+    monkeypatch.setattr(fa, "choose_tiles",
+                        lambda *a, backward=False, **kw: None if backward
+                        else choose_tiles(*a, **kw))
+    assert fa.choose_backward(256, 256, 64, 4, 1 << 16) is None
+    q, k, v, _, _ = _qkv("ragged_100", "float32")
+    before = kernels.counters()
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, use_pallas=True).sum(), (0, 1, 2)))(q, k, v))
+    after = kernels.counters()
     assert after.get("flash_bwd_scan", 0) == before.get("flash_bwd_scan",
                                                         0) + 1
     assert after.get("flash_bwd_pallas", 0) == before.get(
         "flash_bwd_pallas", 0)
-    assert "scan[" in text and "name=flash_fwd" in text
-    assert "name=flash_bwd" not in text
+    assert "scan[" in text and "name=flash_bwd" not in text
 
 
 def test_xla_path_keeps_the_scan_and_counts_it():

@@ -245,3 +245,118 @@ def test_trainer_publishes_the_counts_the_step_made(monkeypatch, telemetry):
     if published:
         assert after["max_expert_rows"] == counts.max()
     assert not trainer._stats_pending or telemetry == "1"
+
+
+# ---------------------------------------------------------------------------
+# ReGLU experts routed from another input than their rows (PR 34)
+
+def _dense_act(x, idx, gates, w13, w2, act, first=0):
+    """``_dense`` with the gate's activation ``act``."""
+    h = jnp.einsum("td,edf->tef", x, w13)
+    y = jnp.einsum("tef,efd->ted", act(h[..., :F]) * h[..., F:], w2)
+    held = first + jnp.arange(w13.shape[0])
+    weight = jnp.sum(jnp.where(idx[:, :, None] == held[None, None],
+                               gates[:, :, None], 0.0), axis=1)
+    return jnp.einsum("te,ted->td", weight, y)
+
+
+@pytest.mark.parametrize("activation,act,capacity_factor", [
+    ("relu", jax.nn.relu, 1.5), ("relu", jax.nn.relu, 0.3),
+    ("silu", jax.nn.silu, 1.5)])
+def test_activation_and_a_separate_router_input(activation, act,
+                                                capacity_factor):
+    """idx and gates from the router's own input ``r``, the rows from
+    ``x``: forward and every gradient (x, r through the gates, the gate
+    weights, both expert weights) against a dense loop, the overflow
+    passes' recomputation included."""
+    x, gate_w, w13, w2 = _weights(11)
+    r = jnp.asarray(onp.random.RandomState(12).randn(T, D).astype("f"))
+    cot = jnp.asarray(onp.random.RandomState(13).randn(T, D).astype("f"))
+
+    def layer(x, r, gate_w, w13, w2):
+        idx, gates = top_k_router(r, gate_w, K)
+        return jnp.sum(expert_ffn(x, idx, gates, w13[2:6], w2[2:6], (2, 4),
+                                  E, capacity_factor,
+                                  activation=activation)[0] * cot)
+
+    def dense(x, r, gate_w, w13, w2):
+        probs = jax.nn.softmax(r @ gate_w, axis=-1)
+        gates, idx = jax.lax.top_k(probs, K)
+        gates = gates / gates.sum(-1, keepdims=True)
+        return jnp.sum(_dense_act(x, idx, gates, w13[2:6], w2[2:6], act, 2)
+                       * cot)
+
+    args = (x, r, gate_w, w13, w2)
+    assert abs(float(layer(*args)) - float(dense(*args))) \
+        < 1e-5 * abs(float(dense(*args)))
+    got = jax.grad(layer, range(5))(*args)
+    want = jax.grad(dense, range(5))(*args)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(w).max()) > 0
+        assert float(jnp.abs(g - w).max()) < 2e-5 * float(jnp.abs(w).max())
+    # routed by x itself the layer is not this one
+    idx_x, gates_x = top_k_router(x, gate_w, K)
+    idx_r, _ = top_k_router(r, gate_w, K)
+    assert (onp.asarray(idx_x) != onp.asarray(idx_r)).any()
+
+
+def test_unknown_activation_is_refused():
+    x, gate_w, w13, w2 = _weights(14)
+    idx, gates = top_k_router(x, gate_w, K)
+    with pytest.raises(ValueError, match="activation"):
+        expert_ffn(x, idx, gates, w13, w2, activation="gelu")
+    with pytest.raises(ValueError, match="activation"):
+        TopKMoE(E, F, K, activation="gelu")
+
+
+def test_expert_parallel_layer_routes_by_the_router_input():
+    x, gate_w, w13, w2 = _weights(15)
+    r = jnp.asarray(onp.random.RandomState(16).randn(T, D).astype("f"))
+    idx, gates = top_k_router(r, gate_w, K)
+    want, want_rows = expert_ffn(x, idx, gates, w13, w2, activation="relu")
+    mesh = parallel.make_mesh({"ep": 4}, devices=jax.devices()[:4])
+    y, rows = expert_parallel_ffn(x, gate_w, w13, w2, K, mesh,
+                                  activation="relu", router_input=r)
+    assert float(jnp.abs(y - want).max()) < 1e-5 * float(jnp.abs(want).max())
+    assert (onp.asarray(rows) == onp.asarray(want_rows)).all()
+
+
+def test_gluon_block_routes_by_its_second_input_and_differentiates_it():
+    """``TopKMoE(activation="relu")(x, router_input)``: the output is the
+    dense ReGLU sum under the router's choices on ``router_input``, and
+    the tape carries the router's gradient back to it."""
+    x, gate_w, w13, w2 = _weights(17)
+    r = onp.random.RandomState(18).randn(T, D).astype("f")
+    blk = TopKMoE(E, F, K, experts_held=(2, 4), activation="relu")
+    blk.initialize()
+    xin = nd.array(onp.asarray(x).reshape(4, T // 4, D))
+    rin = nd.array(r.reshape(4, T // 4, D))
+    blk(xin, rin)
+    for p, v in zip(blk.collect_params().values(),
+                    (gate_w, w13[2:6], w2[2:6])):
+        p.set_data(nd.array(onp.asarray(v)))
+    idx, gates = top_k_router(jnp.asarray(r), gate_w, K)
+    want = _dense_act(x, idx, gates, w13[2:6], w2[2:6], jax.nn.relu, 2)
+    rin.attach_grad()
+    xin.attach_grad()
+    with autograd.record():
+        out = blk(xin, rin)
+        loss = (out * out).sum()
+    loss.backward()
+    assert float(jnp.abs(out.data.reshape(T, D) - want).max()) \
+        < 1e-5 * float(jnp.abs(want).max())
+
+    def dense(x, r):
+        probs = jax.nn.softmax(r @ gate_w, axis=-1)
+        gates, idx = jax.lax.top_k(probs, K)
+        gates = gates / gates.sum(-1, keepdims=True)
+        return jnp.sum(_dense_act(x, idx, gates, w13[2:6], w2[2:6],
+                                  jax.nn.relu, 2) ** 2)
+
+    want_dx, want_dr = jax.grad(dense, (0, 1))(x, jnp.asarray(r))
+    for got, w in ((xin.grad, want_dx), (rin.grad, want_dr)):
+        assert float(jnp.abs(w).max()) > 0
+        assert float(jnp.abs(got.data.reshape(T, D) - w).max()) \
+            < 2e-5 * float(jnp.abs(w).max())
+    counts = onp.bincount(onp.asarray(idx).reshape(-1), minlength=E)
+    assert (onp.asarray(blk.expert_rows.data().data) == counts[2:6]).all()
