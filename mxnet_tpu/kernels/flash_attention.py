@@ -19,8 +19,11 @@ gradients once. Design:
   acc) are float32 scratch; both products take MXU operands in the
   input's dtype with float32 accumulation. Causal tiles wholly above the
   diagonal are skipped and their k/v index maps clamp to the last live
-  tile, so they cost no copy. Under differentiation the kernel also
-  writes the row log-sum-exp (float32, lane-dense (B, H, 1, S_q)).
+  tile, so they cost no copy (each is still a grid step). Under a mask
+  spec the grid is (B, H, visits): the live tiles alone, a q tile's k
+  tiles one after another, the first of which clears the scratch and the
+  last of which writes o. Under differentiation the kernel also writes
+  the row log-sum-exp (float32, lane-dense (B, H, 1, S_q)).
 - backward ``flash_bwd``: ONE fused kernel, grid (B, H, S_k/bk, S_q/bq),
   q innermost, on the transposed tile s^T = k q^T (bk, bq) so the row
   statistics (lse, delta = rowsum(do * o)) broadcast along sublanes.
@@ -32,30 +35,44 @@ gradients once. Design:
   query rows whose dq block fits (``choose_backward``): the grid gains
   the segment as an outer dimension, (B, H, segments, k tiles, q tiles
   of a segment), dk and dv leave once per segment and are summed over
-  the segments where the group is summed, after the kernel; under a
-  mask spec a segment walks only the k tiles of its own band. The kernel
+  the segments where the group is summed, after the kernel. Under a
+  mask spec the grid is (B, H, visits) however many segments there are:
+  segment after segment, in each the k tiles of its band (as wide as the
+  widest segment's live k tiles), for each the segment's live q tiles;
+  a k tile's first visit in a segment clears dk_s and dv_s, its last
+  writes them, and a k tile of the band that no query of the segment
+  sees has one visit that does both and no product, so that the sum over
+  segments reads no block that was never written. The kernel
   counts ``flash_bwd_pallas`` and its segments ``flash_bwd_q_segments``
   in ``kernels.counters()``; a shape beside whose smallest segment no
   tile fits takes the ``lax.scan`` backward and counts
-  ``flash_bwd_scan``.
-- which scores live: ``causal`` (the diagonal, decided from the grid
-  indices as it always was), or a static ``mask`` spec
+  ``flash_bwd_scan``. Both passes count a head's grid steps and the
+  live tiles among them, ``flash_grid_steps`` and
+  ``flash_grid_steps_live``: apart by the dead tiles of the causal
+  rectangle, and under a spec by the visits that only write zeros.
+- which scores live: ``causal`` (the diagonal, decided from the indices
+  of a rectangular grid as it always was), or a static ``mask`` spec
   (``BlockDiffusionMask``, ``SlidingWindowMask``: dead tiles may lie on
-  either side of a query tile's live ones): at trace time the spec gives a table of tile
-  kinds (dead, whole, partly masked) per (q tile, k tile), which rides
-  into both kernels as a prefetched scalar array and drives the skipping
-  and the index maps exactly as the diagonal does. A partly masked tile
+  either side of a query tile's live ones): at trace time the spec
+  gives a table of tile kinds (dead, whole, partly masked) per (q tile,
+  k tile), and each pass walks the table's live tiles as a list of
+  *visits* in the order the rectangle swept them (``_visits``; as
+  ``grouped_matmul`` walks its row tiles): per visit its q tile, its k
+  tile, its kind and whether it opens or closes its sweep, prefetched
+  scalar arrays that the index maps and the kernel read at the grid's one
+  index. A block whose index is that of the visit before is not copied
+  again; a dead tile is no grid step at all. A partly masked tile
   is computed by strips: the table also gives it the number of its
   *pattern*, which says for each strip of queries (512, or the largest
   halving of it that divides both tiles) the hull of the strip-sized
   sub-tiles of its keys that hold a live score. A regular spec has few
   patterns (the block-diffusion band and its block-causal diagonal; a
-  window's diagonal and trailing edge), static data each kernel gets one unrolled branch for: a strip does its
-  products, its statistics and its rows of the gradients on its hull
-  alone, the spec's element rule on a column of its query ids and a row
-  of its key ids; a pattern's strips go through each stage together, and
-  a strip with no live key does nothing. No (S, S) array exists in HBM
-  either way.
+  window's diagonal and trailing edge), static data each kernel gets one
+  unrolled branch for: a strip does its products, its statistics and its
+  rows of the gradients on its hull alone, the spec's element rule on a
+  column of its query ids and a row of its key ids; a pattern's strips
+  go through each stage together, and a strip with no live key does
+  nothing. No (S, S) array exists in HBM either way.
 - grouped heads: q of H heads reads k, v of H / group heads in place
   through the index map. The backward writes dk, dv per query head and
   the group is summed after the kernel (a grid order that kept one k
@@ -97,6 +114,11 @@ _BWD_CAPS = (512, 512)
 #: the first live k tile of a q tile (the backward's dq assigns there),
 #: and a PARTIAL tile's pattern number counts in units of PATTERN
 DEAD, WHOLE, PARTIAL, FIRST, PATTERN = 0, 1, 2, 4, 8
+
+#: where a visit stands in its sweep (a q tile's k tiles forward, a k
+#: tile's q tiles of one segment backward): the sweep's first visit
+#: clears the scratch, its last writes the sweep's result
+OPENS, CLOSES = 1, 2
 
 #: rows of a strip of a partly masked tile, halved until it divides both
 #: tiles. On the chip at (2, 32 over 4, 8192, 128) bf16 under the
@@ -269,20 +291,25 @@ def mask_tile_table(mask, bq, bk, sub):
     return kinds.astype(onp.int32), tuple(kept)
 
 
-def _fetch_table(kinds, axis):
-    """For each tile the index along ``axis`` (1: k tiles of a q row; 0:
-    q tiles of a k column) of the block to name in the index map: its
-    own where it is live, else the live one named last (the first live
-    one before any), so that a dead tile copies nothing."""
+def _walk(kinds):
+    """The visits of a pass that sweeps the rows of ``kinds`` one after
+    another: int32 arrays ``(row, col, kind, edge)``, a visit for every
+    live tile in row-major order, with its kind as the table has it and
+    ``edge`` saying whether it OPENS or CLOSES its row's sweep. A row
+    with no live tile still has a result to write: it gets one visit of
+    kind DEAD that does both and no product, at the column of the visit
+    before it (the first column where there is none), so that it copies
+    nothing new."""
     live = (kinds & (WHOLE | PARTIAL)) != 0
-    if axis == 0:
-        live = live.T
-    n = live.shape[1]
-    idx = onp.where(live, onp.arange(n)[None], -1)
-    last = onp.maximum.accumulate(idx, axis=1)
-    first = live.argmax(1)[:, None]
-    out = onp.where(last < 0, first, last).astype(onp.int32)
-    return out.T if axis == 0 else out
+    visited = live.copy()
+    visited[~live.any(1), 0] = True
+    row, col = onp.nonzero(visited)
+    kind = onp.where(live[row, col], kinds[row, col], DEAD)
+    for at in onp.nonzero(kind[1:] == DEAD)[0] + 1:
+        col[at] = col[at - 1]
+    turns = row[1:] != row[:-1]
+    edge = OPENS * onp.r_[True, turns] + CLOSES * onp.r_[turns, True]
+    return tuple(a.astype(onp.int32) for a in (row, col, kind, edge))
 
 
 def _ref_attention(q, k, v, sm_scale, causal, s_k_real, spec=None):
@@ -478,6 +505,13 @@ def _tile_kinds(i, kb, bq, bk, causal, s_k_real, causal_off):
     return live, masked | ((kb + 1) * bk - 1 > i * bq + causal_off)
 
 
+def _causal_live(nq, nk, bq, bk, causal, causal_off):
+    """How many of the (nq, nk) tiles ``_tile_kinds`` calls live."""
+    i, kb = onp.ogrid[:nq, :nk]
+    return (kb * bk <= (i + 1) * bq - 1 + causal_off).sum() if causal \
+        else nq * nk
+
+
 # ---------------------------------------------------------------------------
 # forward
 
@@ -489,16 +523,23 @@ def _fa_kernel(*refs, bq, bk, nk, sm_scale, causal, s_k_real, causal_off,
     scratch carries the online softmax across the kb sweep (TPU grid steps
     run sequentially, scratch persists). ``rest`` is (m, l, acc), led by
     the lse output block when the backward will want it. Under a ``mask``
-    spec its two prefetched tables lead the refs and ``strips`` are those
-    of each pattern number its partly masked tiles bear."""
+    spec the grid is (B, H, visits): the prefetched arrays of ``_walk``
+    lead the refs, a visit's q tile, k tile, kind and place in the q
+    tile's sweep, and ``strips`` are those of each pattern number the
+    partly masked tiles bear."""
     if mask is not None:
-        kinds_ref, _, *refs = refs
+        qt_ref, kt_ref, kind_ref, edge_ref, *refs = refs
     q_ref, k_ref, v_ref, o_ref, *rest = refs
     *lse_out, m_s, l_s, acc_s = rest
-    i = pl.program_id(2)
-    kb = pl.program_id(3)
+    if mask is None:
+        i = pl.program_id(2)
+        kb = pl.program_id(3)
+    else:
+        at = pl.program_id(2)
+        i, kb, kind = qt_ref[at], kt_ref[at], kind_ref[at] & ~FIRST
+        edge = edge_ref[at]
 
-    @pl.when(kb == 0)
+    @pl.when(kb == 0 if mask is None else (edge & OPENS) != 0)
     def _init():
         m_s[:] = jnp.full_like(m_s, _NEG)
         l_s[:] = jnp.zeros_like(l_s)
@@ -535,10 +576,9 @@ def _fa_kernel(*refs, bq, bk, nk, sm_scale, causal, s_k_real, causal_off,
                 p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
     if mask is not None:
-        kind = kinds_ref[i * nk + kb] & ~FIRST
         pl.when(kind == WHOLE)(functools.partial(_tile, False))
 
-        @pl.when(kind > WHOLE)  # a dead step pays two tests, as it did
+        @pl.when(kind > WHOLE)
         def _partly():
             for n, parts, _ in strips:  # a strip with no live key adds nothing
                 pl.when(kind == PARTIAL + n * PATTERN)(
@@ -549,7 +589,7 @@ def _fa_kernel(*refs, bq, bk, nk, sm_scale, causal, s_k_real, causal_off,
         pl.when(live & masked)(functools.partial(_tile, True))
         pl.when(live & ~masked)(functools.partial(_tile, False))
 
-    @pl.when(kb == nk - 1)
+    @pl.when(kb == nk - 1 if mask is None else (edge & CLOSES) != 0)
     def _finalize():
         l = jnp.maximum(l_s[:], 1e-30)
         o_ref[:] = (acc_s[:] / l).astype(o_ref.dtype)
@@ -582,16 +622,45 @@ def _segment_bands(kinds, nseg):
     return onp.minimum(lo, nk - width).astype(onp.int32), width
 
 
-def _mask_tiles(mask, q, k, backward, bq, bk, nseg=1):
-    """Strips, band and the prefetched tables of a mask spec's pass at
-    tiles (bq, bk): ``(number, parts, empty)`` for each pattern number a
-    partly masked tile bears, cut as ``_strips`` cuts them (number 0: the
-    tile whole); the k tiles a segment's band holds; the kinds, flattened
-    row-major over (q tile, k tile), per tile the block to name where it
-    is dead (k blocks forward; backward q blocks of the tile's own
-    segment), and, where the backward has ``nseg`` > 1 segments, each
-    segment's first k tile. Counts the strip-sized sub-tiles the pass's
-    partly masked tiles hold, and those of them it computes."""
+def _visits(kinds, bands=None):
+    """The walk of a pass over the live tiles of the table ``kinds``, as
+    int32 arrays with an entry a visit. Forward ``(q tile, k tile, kind,
+    edge)``: a q tile's live k tiles one after another. Backward, given
+    the ``bands`` of its segments as ``_segment_bands`` has them, ``(q
+    tile, k tile, kind, edge, slot)``: segment after segment, in each
+    the k tiles of its band, for each the segment's live q tiles;
+    ``slot`` is where the sweep's dk and dv go, the band's k tiles of
+    segment after segment. A k tile of a band with no live q tile in the
+    segment has the one DEAD visit ``_walk`` gives it, which writes its
+    zeros."""
+    if bands is None:
+        return _walk(kinds)
+    first, width = bands
+    per = kinds.shape[0] // len(first)
+    walks = []
+    for s, k0 in enumerate(first.tolist()):
+        at, i, kind, edge = _walk(kinds[s * per:(s + 1) * per,
+                                        k0:k0 + width].T)
+        walks.append((i + s * per, at + k0, kind, edge, at + s * width))
+    return tuple(onp.concatenate(a) for a in zip(*walks))
+
+
+def _count_steps(steps, live):
+    """A head's grid steps in the pass being traced, and the live tiles
+    among them."""
+    _count("flash_grid_steps", int(steps))
+    _count("flash_grid_steps_live", int(live))
+
+
+def _mask_tiles(mask, q, k, bq, bk, nseg=0):
+    """Strips, bands and visits of a mask spec's pass at tiles (bq, bk),
+    the forward's or the backward's in ``nseg`` segments: ``(number,
+    parts, empty)`` for each pattern number a partly masked tile bears,
+    cut as ``_strips`` cuts them (number 0: the tile whole); each
+    segment's first k tile and the k tiles a band holds (None forward);
+    and the prefetched arrays of ``_visits``. Counts the pass's grid
+    steps, and the strip-sized sub-tiles its partly masked tiles hold
+    and those of them it computes."""
     S = q.shape[2]
     if mask.size != S or k.shape[2] != S:
         raise ValueError(f"mask over {mask.size} positions, q {q.shape} "
@@ -601,14 +670,9 @@ def _mask_tiles(mask, q, k, backward, bq, bk, nseg=1):
                          f"positions, got {S}")
     sub = _strip_size(bq, bk)
     kinds, patterns = mask_tile_table(mask, bq, bk, sub)
-    if backward:
-        per = kinds.shape[0] // nseg
-        fetch = onp.concatenate([
-            _fetch_table(kinds[s * per:(s + 1) * per], 0) + s * per
-            for s in range(nseg)])
-    else:
-        fetch = _fetch_table(kinds, 1)
-    first, width = _segment_bands(kinds, nseg)
+    bands = _segment_bands(kinds, nseg) if nseg else None
+    visits = _visits(kinds, bands)
+    _count_steps(visits[0].size, (visits[2] != DEAD).sum())
     held = (bq // sub) * (bk // sub)
     number = kinds[(kinds & PARTIAL) != 0] // PATTERN
     computed = onp.array([held] + [sum(hi - lo for lo, hi in pattern)
@@ -618,10 +682,7 @@ def _mask_tiles(mask, q, k, backward, bq, bk, nseg=1):
     strips = tuple(
         (n,) + (_strips(patterns[n - 1], sub) if n else (_WHOLE_TILE, ()))
         for n in onp.unique(number).tolist())
-    tables = [jnp.asarray(kinds.reshape(-1)), jnp.asarray(fetch.reshape(-1))]
-    if nseg > 1:
-        tables.append(jnp.asarray(first))
-    return strips, (first, width), tables
+    return strips, bands, [jnp.asarray(a) for a in visits]
 
 
 def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
@@ -635,29 +696,38 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
     B, H, S_q, D = q.shape
     S_k = k.shape[2]
     group = H // k.shape[1]
-    tables = strips = ()
+    visits = strips = ()
     if bq is None or bk is None:
         bq, bk = choose_tiles(S_q, S_k, D, q.dtype.itemsize)
-    if mask is not None:
-        strips, _, tables = _mask_tiles(mask, q, k, False, bq, bk)
-        _count("flash_mask_pallas")
     pq = (-S_q) % bq
     pk = (-S_k) % bk
     Sq_p, Sk_p = S_q + pq, S_k + pk
     nk = Sk_p // bk
     off = S_k - S_q
+    if mask is not None:
+        strips, _, visits = _mask_tiles(mask, q, k, bq, bk)
+        _count("flash_mask_pallas")
+    else:
+        _count_steps(Sq_p // bq * nk, _causal_live(Sq_p // bq, nk, bq, bk,
+                                                   causal, off))
     kern = functools.partial(_fa_kernel, bq=bq, bk=bk, nk=nk,
                              sm_scale=sm_scale, causal=causal,
                              s_k_real=S_k, causal_off=off, mask=mask,
                              strips=strips)
 
-    def kv_map(b, h, i, kb, *tabs):
-        # a dead tile (above the diagonal, or by the mask's table) is
-        # skipped: name the last live one again and the pipeline copies
-        # nothing
-        if tabs:
-            kb = tabs[1][i * nk + kb]
-        elif causal:
+    def tile(*at):
+        """(q tile, k tile) of a grid step: its own two indices, or the
+        visit's under a spec."""
+        if mask is None:
+            return at
+        v, qt, kt, *_ = at
+        return qt[v], kt[v]
+
+    def kv_map(b, h, *at):
+        i, kb = tile(*at)
+        if causal:
+            # a tile above the diagonal is skipped: name the last live
+            # one again and the pipeline copies nothing
             kb = jnp.minimum(kb, ((i + 1) * bq - 1 + off) // bk)
         if group > 1:
             h = h // group
@@ -666,15 +736,15 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
     # one head a grid row, its block's two leading dimensions squeezed:
     # the kernel sees (bq, D), (bk, D) and the (1, bq) row of lse
     q_spec = pl.BlockSpec((None, None, bq, D),
-                          lambda b, h, i, kb, *_: (b, h, i, 0))
+                          lambda b, h, *at: (b, h, tile(*at)[0], 0))
     kv_spec = pl.BlockSpec((None, None, bk, D), kv_map)
     out_specs = [q_spec]
     out_shape = [jax.ShapeDtypeStruct((B, H, Sq_p, D), q.dtype)]
     if with_lse:
-        out_specs.append(pl.BlockSpec((None, None, 1, bq),
-                                      lambda b, h, i, kb, *_: (b, h, 0, i)))
+        out_specs.append(pl.BlockSpec(
+            (None, None, 1, bq), lambda b, h, *at: (b, h, 0, tile(*at)[0])))
         out_shape.append(jax.ShapeDtypeStruct((B, H, 1, Sq_p), jnp.float32))
-    grid = (B, H, Sq_p // bq, nk)
+    grid = (B, H, visits[0].size) if visits else (B, H, Sq_p // bq, nk)
     in_specs = [q_spec, kv_spec, kv_spec]
     out_specs = out_specs if with_lse else out_specs[0]
     scratch = [
@@ -682,9 +752,9 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
         pltpu.VMEM((bq, 1), jnp.float32),
         pltpu.VMEM((bq, D), jnp.float32),
     ]
-    if tables:
+    if visits:
         how = {"grid_spec": pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            num_scalar_prefetch=len(visits), grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch)}
     else:
         how = {"grid": grid, "in_specs": in_specs, "out_specs": out_specs,
@@ -693,11 +763,11 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
         kern,
         out_shape=out_shape if with_lse else out_shape[0],
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "parallel", "arbitrary")),
+            ("parallel",) * (len(grid) - 1) + ("arbitrary",))),
         interpret=interpret,
         name="flash_fwd",  # the HLO instruction's name on a device trace
         **how,
-    )(*tables, _pad_rows(q, pq), _pad_rows(k, pk), _pad_rows(v, pk))
+    )(*visits, _pad_rows(q, pq), _pad_rows(k, pk), _pad_rows(v, pk))
     if with_lse:
         return out[0][:, :, :S_q], out[1]
     return out[:, :, :S_q]
@@ -716,27 +786,28 @@ def _fa_bwd_kernel(*refs, bq, bk, nq, sm_scale, causal, s_k_real,
     table's FIRST under a ``mask`` spec, whose tables lead the refs and
     whose ``strips`` are those of each pattern number its tiles bear).
     A head too long for its dq to be one block has ``nseg`` > 1 such
-    *segments*: the grid is (B, H, nseg, k tiles of a segment's band,
-    nq), dk and dv leave once per segment, and under a mask spec a third
-    table gives each segment's first k tile."""
+    *segments*: the grid is (B, H, nseg, k tiles, nq) and dk and dv leave
+    once per segment. Under a mask spec the grid is (B, H, visits) for
+    any ``nseg``: the prefetched arrays of ``_visits`` lead the refs, a
+    visit's q tile, k tile, kind and place in the sweep of its segment's
+    q tiles past the k tile (and the slot of that sweep's dk and dv,
+    which only the index maps read)."""
     if mask is not None:
-        kinds_ref, _, *refs = refs
-        if nseg > 1:
-            first_ref, *refs = refs
+        qt_ref, kt_ref, kind_ref, edge_ref, _, *refs = refs
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
      dv_ref, dk_s, dv_s) = refs
-    lead = 3 if nseg > 1 else 2         # grid dimensions before the k tile's
-    kb = pl.program_id(lead)
-    il = i = pl.program_id(lead + 1)    # in the segment, in the head
-    if nseg > 1:
-        seg = pl.program_id(2)
-        i = seg * nq + il
-        if mask is not None:
-            kb = first_ref[seg] + kb
-    if mask is not None:
-        kind = kinds_ref[i * (mask.size // bk) + kb]
+    if mask is None:
+        lead = 3 if nseg > 1 else 2     # grid dimensions before the k tile's
+        kb = pl.program_id(lead)
+        il = i = pl.program_id(lead + 1)    # in the segment, in the head
+        if nseg > 1:
+            i = pl.program_id(2) * nq + il
+    else:
+        at = pl.program_id(2)
+        i, kb, kind, edge = qt_ref[at], kt_ref[at], kind_ref[at], edge_ref[at]
+        il = lax.rem(i, nq)
 
-    @pl.when(il == 0)
+    @pl.when(il == 0 if mask is None else (edge & OPENS) != 0)
     def _init():
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
@@ -800,7 +871,7 @@ def _fa_bwd_kernel(*refs, bq, bk, nq, sm_scale, causal, s_k_real,
         live_kind = kind & ~FIRST
         pl.when(live_kind == WHOLE)(functools.partial(_tile, False))
 
-        @pl.when(live_kind > WHOLE)     # a dead step pays two tests
+        @pl.when(live_kind > WHOLE)
         def _partly():
             for n, parts, empty in strips:
                 pl.when(live_kind == PARTIAL + n * PATTERN)(
@@ -811,7 +882,7 @@ def _fa_bwd_kernel(*refs, bq, bk, nq, sm_scale, causal, s_k_real,
         pl.when(live & masked)(functools.partial(_tile, True))
         pl.when(live & ~masked)(functools.partial(_tile, False))
 
-    @pl.when(il == nq - 1)
+    @pl.when(il == nq - 1 if mask is None else (edge & CLOSES) != 0)
     def _finalize():
         dk_ref[:] = (dk_s[:] * sm_scale).astype(dk_ref.dtype)
         dv_ref[:] = dv_s[:].astype(dv_ref.dtype)
@@ -859,10 +930,12 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
     nq = rows // bq                     # q tiles a segment
     nk = Sk_p // bk
     first, band = onp.zeros(nseg, onp.int32), nk
-    tables = strips = ()
+    visits = strips = ()
     if mask is not None:
-        strips, (first, band), tables = _mask_tiles(mask, q, k, True, bq, bk,
-                                                    nseg)
+        strips, (first, band), visits = _mask_tiles(mask, q, k, bq, bk, nseg)
+    else:
+        _count_steps(nseg * nq * nk, _causal_live(nseg * nq, nk, bq, bk,
+                                                  causal, S_k - S_q))
     if lse.shape != (B, H, 1, Sq_p):
         raise ValueError(f"lse {lse.shape} is not the forward's for q "
                          f"{q.shape} padded to tiles of {bq}")
@@ -875,25 +948,24 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
                              strips=strips, nseg=nseg)
 
     def ids(args):
-        """(b, h, segment, k tile, q tile of the head, tables) of a grid
-        step; the segment is the number 0 where there is one."""
+        """(b, h, segment, k tile, q tile of the head, dk and dv's block)
+        of a grid step; the segment is the number 0 where there is one.
+        Under a spec they are the visit's."""
         b, h, *rest = args
+        if mask is not None:
+            v, qt, kt, _, _, slot = rest
+            return b, h, qt[v] // nq, kt[v], qt[v], (slot[v],)
         if nseg == 1:
-            kb, i, *tabs = rest
-            return b, h, 0, kb, i, tabs
-        seg, kb, i, *tabs = rest
-        if tabs:
-            kb = tabs[2][seg] + kb
-        return b, h, seg, kb, seg * nq + i, tabs
+            kb, i = rest
+            return b, h, 0, kb, i, (kb,)
+        seg, kb, i = rest
+        return b, h, seg, kb, seg * nq + i, (seg, kb)
 
     def _live_i(args):
-        # q tiles that are dead for k tile kb (above the diagonal, or by
-        # the mask's table) are skipped: name a live one of the segment
-        # and the pipeline copies nothing
-        b, h, seg, kb, i, tabs = ids(args)
-        if tabs:
-            i = tabs[1][i * nk + kb]
-        elif causal:
+        b, h, seg, kb, i, _ = ids(args)
+        if causal:
+            # q tiles above the diagonal of k tile kb are skipped: name a
+            # live one of the segment and the pipeline copies nothing
             i = jnp.maximum(i, jnp.maximum(kb * bk - off, 0) // bq)
             if nseg > 1:
                 i = jnp.minimum(i, (seg + 1) * nq - 1)
@@ -907,14 +979,24 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
         b, h, i = _live_i(args)
         return b, h, 0, i
 
+    def dkv_map(*args):
+        b, h, *_, block = ids(args)
+        return (b, h, *block, 0)
+
     q_spec = pl.BlockSpec((None, None, bq, D),
                           lambda *args: (*_live_i(args), 0))
     row_spec = pl.BlockSpec((None, None, 1, bq), row_map)
     kv_spec = pl.BlockSpec((None, None, bk, D), kv_map)
-    grid = (B, H) + ((nseg,) if nseg > 1 else ()) + (band, nq)
-    # dk, dv: a block for every grid step but the q sweep's
-    dkv_spec = pl.BlockSpec((None,) * (len(grid) - 2) + (bk, D),
-                            lambda *args: (*args[:len(grid) - 1], 0))
+    # dk, dv: a block for every sweep of q tiles. Without a spec a grid
+    # step's indices but the sweep's say which; a spec's visits give the
+    # blocks of all segments' bands one after another
+    if mask is not None:
+        grid = (B, H, visits[0].size)
+        dkv_shape = (B, H, nseg * band * bk, D)
+    else:
+        grid = (B, H) + ((nseg,) if nseg > 1 else ()) + (nk, nq)
+        dkv_shape = (B, H) + ((nseg,) if nseg > 1 else ()) + (nk * bk, D)
+    dkv_spec = pl.BlockSpec((None,) * (len(dkv_shape) - 2) + (bk, D), dkv_map)
     in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
     out_specs = [
         pl.BlockSpec((None, None, rows, D),
@@ -925,14 +1007,13 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
         pltpu.VMEM((bk, D), jnp.float32),
         pltpu.VMEM((bk, D), jnp.float32),
     ]
-    if tables:
+    if visits:
         how = {"grid_spec": pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(tables), grid=grid, in_specs=in_specs,
+            num_scalar_prefetch=len(visits), grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch)}
     else:
         how = {"grid": grid, "in_specs": in_specs, "out_specs": out_specs,
                "scratch_shapes": scratch}
-    dkv_shape = (B, H) + ((nseg,) if nseg > 1 else ()) + (band * bk, D)
     dq, dk, dv = pl.pallas_call(
         kern,
         out_shape=[
@@ -941,15 +1022,17 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
             jax.ShapeDtypeStruct(dkv_shape, v.dtype),
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            ("parallel", "parallel", "arbitrary") if visits else
             ("parallel",) * (len(grid) - 2) + ("arbitrary", "arbitrary"))),
         interpret=interpret,
         # attn_bwd_ms.tokens finds the backward by this name alone
         name="flash_bwd",
         **how,
-    )(*tables, _pad_rows(q, pq), _pad_rows(k, pk), _pad_rows(v, pk),
+    )(*visits, _pad_rows(q, pq), _pad_rows(k, pk), _pad_rows(v, pk),
       _pad_rows(do, pq), lse, delta)  # zero do: padded rows add nothing
     if nseg > 1:
-        dk, dv = (_sum_segments(a, group, first, bk, S_k) for a in (dk, dv))
+        dk, dv = (_sum_segments(a.reshape(B, H, nseg, band * bk, D), group,
+                                first, bk, S_k) for a in (dk, dv))
     else:
         dk, dv = dk[:, :, :S_k], dv[:, :, :S_k]
         if group > 1:

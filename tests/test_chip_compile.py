@@ -117,23 +117,24 @@ def test_flash_backward_compiles(one_chip, shape, dtype):
     assert after.get("flash_bwd_scan", 0) == before.get("flash_bwd_scan", 0)
 
 
-@pytest.mark.parametrize("b,hq,hkv,seq,d,subtiles", [
+@pytest.mark.parametrize("b,hq,hkv,seq,d,subtiles,visits", [
     # the cell sdar30b-train-bd-s4096: forward at (1024, 1024) in strips
     # of 512 the band's 4 x 2 and the diagonal's 8 x 3 sub-tiles of
     # 12 x 4, backward at (256, 512) in strips of 256 16 + 16 x (1 + 2)
-    # of 48 x 2
-    (2, 32, 4, 4096, 128, (32 + 64, 48 + 96)),
+    # of 48 x 2; 24 live tiles of 64 forward, 160 of 512 backward
+    (2, 32, 4, 4096, 128, (32 + 64, 48 + 96), 24 + 160),
     # no grouping, D=64: (1024, 1024) 2 + 2 x 3 of 3 x 4; at (512, 512) a
-    # tile is one strip, its 6 computed whole
-    (1, 8, 8, 1024, 64, (8 + 6, 12 + 6)),
+    # tile is one strip, its 6 computed whole; 3 live tiles of 4, 8 of 16
+    (1, 8, 8, 1024, 64, (8 + 6, 12 + 6), 3 + 8),
 ])
 def test_masked_grouped_flash_compiles(one_chip, b, hq, hkv, seq, d,
-                                       subtiles):
+                                       subtiles, visits):
     """The block-diffusion mask over 2 x seq positions with grouped
     heads, under ``jax.grad`` at the chooser's tiles: both kernels keep
-    their names (the table of tile kinds rides in as a prefetched
-    scalar), the strips of the partly masked tiles lower with them, and
-    the cell's counters count the lowering and its sub-tiles."""
+    their names (the visits of the live tiles ride in as prefetched
+    scalar arrays, the grid's last dimension), the strips of the partly
+    masked tiles lower with them, and the cell's counters count the
+    lowering, its sub-tiles and its grid steps, none of them dead."""
     mask = fa.BlockDiffusionMask(seq, 4)
     dtype = jnp.bfloat16
 
@@ -155,6 +156,8 @@ def test_masked_grouped_flash_compiles(one_chip, b, hq, hkv, seq, d,
     for name, n in zip(("live", "tile"), subtiles):
         name = "flash_mask_subtiles_" + name
         assert after[name] - before.get(name, 0) == n, name
+    for name in ("flash_grid_steps", "flash_grid_steps_live"):
+        assert after[name] - before.get(name, 0) == visits, name
 
 
 @pytest.mark.parametrize("window", [4096, None], ids=["window", "causal"])
@@ -162,9 +165,11 @@ def test_long_head_backward_compiles_in_segments(one_chip, window):
     """The cell smallthinker21b-train-s16384: (1, 28 over 4, 16384, 128)
     bf16, a window layer and the global one. A head's float32 dq is 8 MiB,
     past any block the budget admits, so the backward walks the head in
-    four segments of 4,096 rows at (512, 512) tiles (a window layer's
-    segment only the 16 k tiles of its band): one ``flash_bwd``, no
-    ``while``, and no (S, S) array anywhere in the program."""
+    four segments of 4,096 rows at (512, 512) tiles: one ``flash_bwd``, no
+    ``while``, and no (S, S) array anywhere in the program. A window
+    layer's grid is its visits: 70 live tiles forward, 252 backward and
+    the 8 k tiles of the first segment's band that only get their zeros
+    written; the global layer keeps its rectangle, dead steps and all."""
     seq, d, dtype = 16384, 128, jnp.bfloat16
     mask = fa.SlidingWindowMask(seq, window) if window else None
 
@@ -189,6 +194,10 @@ def test_long_head_backward_compiles_in_segments(one_chip, window):
         "flash_bwd_q_segments", 0) + 4
     if window:
         assert after["flash_mask_pallas"] > before.get("flash_mask_pallas", 0)
+    steps, live = (after[name] - before.get(name, 0) for name in
+                   ("flash_grid_steps", "flash_grid_steps_live"))
+    assert (steps, live) == (70 + 252 + 8, 70 + 252) if window else \
+        (16 * 16 + 4 * 32 * 8, 136 + 528)
     assert cost_model.pallas_fits_vmem("attention", (seq, d), 2)
 
 

@@ -14,6 +14,8 @@ import jax.numpy as jnp
 import numpy as onp
 import pytest
 
+from _flash_visits import (
+    check_backward_visits, check_forward_visits, check_visits)
 from mxnet_tpu import kernels
 from mxnet_tpu.kernels import flash_attention as fa
 from mxnet_tpu.kernels.flash_attention import (
@@ -93,15 +95,9 @@ def test_tile_table_against_brute_force(L, b, bq, bk):
     # FIRST sits on each q tile's first live k tile and nowhere else
     assert (((table & FIRST) != 0).sum(1) == 1).all()
     assert ((table & FIRST) != 0)[onp.arange(S // bq), live.argmax(1)].all()
-    # a dead tile names a live block of its row (forward) or column
-    # (backward), a live one names itself
-    for axis in (1, 0):
-        fetch = fa._fetch_table(table, axis)
-        own = onp.arange(table.shape[axis])
-        own = own[None] if axis == 1 else own[:, None]
-        assert (fetch[live] == onp.broadcast_to(own, live.shape)[live]).all()
-        named = onp.take_along_axis(live, fetch, axis)
-        assert named.all()
+    # both kernels visit exactly the live tiles, in the order the
+    # rectangle swept them, and write every block of their results
+    check_visits(table)
 
 
 def brute_patterns(L, b, bq, bk, sub=128):
@@ -195,6 +191,24 @@ def test_the_cell_shape_counts_what_the_issue_states():
     assert subtile_counts(4096, 4, 256, 512, 128) == (32 + 48 + 112, 384)
     assert subtile_counts(4096, 4, 1024, 1024, 256) == (16 + 80, 192)
     assert subtile_counts(4096, 4, 256, 512, 256) == (16 + 16 + 32, 96)
+
+
+def test_the_cells_walks_count_what_the_issue_states():
+    """A head of the cell sdar30b-train-bd-s4096: 24 visits forward for
+    the rectangle's 64 steps, 160 backward for 512, every k tile with a
+    live q tile (no visit that only writes zeros): 184 a layer."""
+    spec = BlockDiffusionMask(4096, 4)
+    fwd, _ = mask_tile_table(spec, 1024, 1024, 512)
+    bwd, _ = mask_tile_table(spec, 256, 512, 256)
+    assert fwd.size == 64 and check_forward_visits(fwd) == 24
+    assert bwd.size == 512
+    assert check_backward_visits(bwd, 1) == ([160], [0])
+    assert fa._visits(fwd)[0].size + fa._visits(
+        bwd, fa._segment_bands(bwd, 1))[0].size == 184
+    # two segments of 4,096 rows (PERF.md section 7, row 13): the clean
+    # queries' band holds the noised keys' tiles, which none of them sees
+    assert check_backward_visits(
+        mask_tile_table(spec, 512, 512, 512)[0], 2) == ([44, 36], [0, 8])
 
 
 def test_all_three_kinds_occur_at_the_tested_tiles():

@@ -14,6 +14,8 @@ import jax.numpy as jnp
 import numpy as onp
 import pytest
 
+from _flash_visits import (
+    check_backward_visits, check_forward_visits, check_visits)
 from mxnet_tpu import kernels
 from mxnet_tpu.kernels import flash_attention as fa
 from mxnet_tpu.kernels.flash_attention import (
@@ -72,12 +74,9 @@ def test_tile_table_has_dead_tiles_on_both_sides(S, w, bq, bk):
         assert (onp.diff(at) == 1).all()
     assert (((table & FIRST) != 0).sum(1) == 1).all()
     assert ((table & FIRST) != 0)[onp.arange(nq), live.argmax(1)].all()
-    for axis in (1, 0):         # a dead tile names a live one of its line
-        fetch = fa._fetch_table(table, axis)
-        own = onp.arange(table.shape[axis])
-        own = own[None] if axis == 1 else own[:, None]
-        assert (fetch[live] == onp.broadcast_to(own, live.shape)[live]).all()
-        assert onp.take_along_axis(live, fetch, axis).all()
+    # both kernels visit exactly the live tiles, in the order the
+    # rectangle swept them, and write every block of their results
+    check_visits(table)
     number = table // PATTERN
     if bq == bk and w % bk == 0 and bq > sub:
         # the diagonal's triangle and the band's trailing edge
@@ -100,6 +99,28 @@ def test_segment_bands_of_the_cells_window():
     assert width == 32 and first.tolist() == [0]
     first, width = fa._segment_bands(table, 8)
     assert width == 12 and first.tolist() == [0, 0, 0, 4, 8, 12, 16, 20]
+
+
+def test_the_cells_walks_count_what_the_issue_states():
+    """A head of a window layer of the cell smallthinker21b-train-s16384:
+    70 visits forward for the rectangle's 256 steps; backward 36, 72, 72
+    and 72 live tiles in the four segments for 4 x 128 steps, and 8
+    visits that only write zeros: the k tiles 8 to 15 of the first
+    segment's band, which no query of its 4,096 sees and
+    ``_sum_segments`` adds all the same. 330 a layer."""
+    spec = SlidingWindowMask(16384, 4096)
+    fwd, _ = mask_tile_table(spec, 1024, 1024, 512)
+    bwd, _ = mask_tile_table(spec, 512, 512, 256)
+    assert fwd.size == 256 and check_forward_visits(fwd) == 70
+    assert check_backward_visits(bwd, 4) == ([36, 72, 72, 72], [8, 0, 0, 0])
+    qt, kt, kind, edge, slot = fa._visits(bwd, fa._segment_bands(bwd, 4))
+    assert fa._visits(fwd)[0].size + qt.size == 330
+    zero = kind == DEAD
+    assert kt[zero].tolist() == list(range(8, 16)) == slot[zero].tolist()
+    # they copy no q tile: each names the one of the visit before it
+    assert (qt[zero] == qt[onp.nonzero(zero)[0][0] - 1]).all()
+    # one segment (a whole head's dq in one block): every k tile is live
+    assert check_backward_visits(bwd, 1) == ([252], [0])
 
 
 def _qkv(S, H, HKV, D, dtype=jnp.float32, seed=0):
@@ -147,17 +168,29 @@ def test_kernels_with_seven_query_heads_a_kv_head_at_d128(cap_tiles, window):
         assert after["flash_mask_pallas"] > before.get("flash_mask_pallas", 0)
         assert after["flash_mask_subtiles_tile"] > before.get(
             "flash_mask_subtiles_tile", 0)
+    # a head's grid steps, both passes: the window's 5 live tiles of 9
+    # and no other; the causal rectangle's 9, of which 6 are live
+    steps, live = (after[name] - before.get(name, 0) for name in
+                   ("flash_grid_steps", "flash_grid_steps_live"))
+    assert (steps, live) == ((10, 10) if window else (18, 12))
 
 
-@pytest.mark.parametrize("S,window,heads", [
-    (1024, 256, (2, 1)), (1024, 0, (2, 1)), (768, 384, (6, 2)),
-    (1024, 0, (4, 2))], ids=["window", "causal", "window_grouped",
-                             "causal_grouped"])
-def test_backward_in_segments_equals_the_oracles_gradients(S, window, heads):
+@pytest.mark.parametrize("S,window,heads,zeros", [
+    (1024, 256, (2, 1), 2), (1024, 0, (2, 1), 0), (768, 384, (6, 2), 4),
+    (1024, 0, (4, 2), 0), (1024, -4, (2, 1), 8)],
+    ids=["window", "causal", "window_grouped", "causal_grouped",
+         "block_diffusion"])
+def test_backward_in_segments_equals_the_oracles_gradients(S, window, heads,
+                                                           zeros):
     """A budget under which the whole head's dq fits beside no tile: the
     chooser cuts the head into segments, the kernel walks each (under
-    the window spec only its band of k tiles) and the sums over segments
-    and group are the oracle's gradients."""
+    a spec only the live tiles of its band of k tiles) and the sums over
+    segments and group are the oracle's gradients. ``zeros`` k tiles of
+    a segment's band have no live q tile in it and are written all the
+    same: under the window the last ones of the first segments' bands,
+    under block diffusion (``window`` its block length, negated) in four
+    segments also the noised keys' tiles between a noised segment's own
+    and the clean ones, and ahead of the clean queries' segments."""
     H, HKV = heads
     D = 64
     budget = fa.tile_vmem_bytes(128, 128, S // 2, D, 4, True) - 1
@@ -166,14 +199,21 @@ def test_backward_in_segments_equals_the_oracles_gradients(S, window, heads):
     assert rows < S and S % rows == 0 and rows % bq == 0
     assert choose_tiles(S, S, D, 4, True, budget) == (bq, bk)
     q, k, v, do = _qkv(S, H, HKV, D, seed=3)
-    mask = SlidingWindowMask(S, window) if window else None
+    mask = SlidingWindowMask(S, window) if window > 0 else \
+        fa.BlockDiffusionMask(S // 2, -window) if window else None
     sm = D ** -0.5
     o, lse = fa._pallas_forward(q, k, v, sm, mask is None, True,
                                 with_lse=True, bq=bq, bk=bk, mask=mask)
     before = kernels.counters().get("flash_bwd_q_segments", 0)
+    grid = {name: kernels.counters().get(name, 0)
+            for name in ("flash_grid_steps", "flash_grid_steps_live")}
     got = fa._pallas_backward(q, k, v, o, lse, do, sm, mask is None, True,
                               bq=bq, bk=bk, mask=mask, rows=rows)
     assert kernels.counters()["flash_bwd_q_segments"] == before + S // rows
+    if mask is not None:    # the visits that only write zeros are steps
+        steps, live = (kernels.counters()[name] - grid[name] for name in grid)
+        assert steps - live == sum(check_backward_visits(mask_tile_table(
+            mask, bq, bk, fa._strip_size(bq, bk))[0], S // rows)[1]) == zeros
     want = jax.vjp(lambda *a: fa._ref_attention(
         *a, sm, mask is None, S, mask), q, k, v)[1](do)
     for g, w in zip(got, want):
