@@ -397,15 +397,19 @@ def phase_masked(cfg):
     (16, 2048, 1536), (16384, 768) x (16, 768, 2048), to bfloat16's
     rounding, and at 16,384 positions of one key/value head's group of 7,
     window and causal, where a head's dq is past one block and the
-    backward walks it in segments. The kernels are forced
-    (``use_pallas=True``): what the chip's compiler refuses fails here,
-    nothing falls back."""
+    backward walks it in segments; the causal kernels at a head size of
+    256 in a group of 8, (1, 16 over 2, 8192, 256); and the gated delta
+    rule's two kernels (``gdn_fwd``, ``gdn_bwd``) against their
+    ``lax.scan`` twin, at (1, 32 over 16, 8192, 128). The kernels are
+    forced (``use_pallas=True``): what the chip's compiler refuses fails
+    here, nothing falls back."""
     import jax
     import jax.numpy as jnp
 
     from mxnet_tpu import kernels
     from mxnet_tpu.kernels.flash_attention import (
         BlockDiffusionMask, SlidingWindowMask, flash_attention)
+    from mxnet_tpu.kernels.gated_delta import gated_delta_rule
     from mxnet_tpu.kernels.grouped_matmul import grouped_matmul
 
     on_chip = not cfg.rehearse
@@ -488,10 +492,47 @@ def phase_masked(cfg):
                   f"({groups}, {k}, {n}) {name}", g, w, tol,
                   scale=float(jnp.abs(jnp.asarray(w, jnp.float32)).max()))
 
+    def rule(dtype, b, hk, hv, size, d, tols):
+        """The gated delta rule and its five gradients, kernels against
+        the twin, each to ``tols`` of the twin's largest element."""
+        unit = lambda a: a / jnp.linalg.norm(  # noqa: E731
+            a, axis=-1, keepdims=True)
+        q = (unit(randn(jnp.float32, b, hk, size, d)) * d ** -0.5) \
+            .astype(dtype)
+        k = unit(randn(jnp.float32, b, hk, size, d)).astype(dtype)
+        v, do = (randn(dtype, b, hv, size, d) for _ in range(2))
+        g = -jnp.abs(randn(jnp.float32, b, hv, size)) \
+            * jnp.linspace(0.01, 16.0, hv)[None, :, None]
+        beta = jax.nn.sigmoid(randn(jnp.float32, b, hv, size))
+
+        def run(pallas):
+            def f(q, k, v, g, beta, do):
+                o, vjp = jax.vjp(lambda *a: gated_delta_rule(
+                    *a, use_pallas=pallas), q, k, v, g, beta)
+                return (o,) + vjp(do)
+            return jax.jit(f)
+
+        got = run(True)(q, k, v, g, beta, do)
+        if on_chip:
+            text = run(True).lower(q, k, v, g, beta, do).as_text()
+            assert "gdn_fwd" in text and "gdn_bwd" in text
+        want = run(False)(q, k, v, g, beta, do)
+        for name, a, w, tol in zip(("forward", "dq", "dk", "dv", "dg",
+                                    "dbeta"), got, want, tols):
+            close(f"gated delta {jnp.dtype(dtype).name} "
+                  f"{(b, hk, hv, size, d)} {name}", a, w, tol,
+                  scale=float(jnp.abs(jnp.asarray(w, jnp.float32)).max()))
+
     with jax.default_matmul_precision("highest"):
         attention(jnp.float32, *((1, 4, 2, 256, 32) if cfg.rehearse
                                  else (1, 8, 2, 2048, 128)),
                   (1e-5, 1e-4, 1e-4, 1e-4))
+        # a head size of 256, 8 query heads a key/value head, causal
+        attention(jnp.float32, *((1, 8, 1, 256, 256) if cfg.rehearse
+                                 else (1, 8, 1, 1024, 256)),
+                  (1e-5, 1e-4, 1e-4, 1e-4), window=0)
+        rule(jnp.float32, *((1, 1, 2, 192, 128) if cfg.rehearse
+                            else (1, 2, 4, 1024, 128)), (1e-4,) * 6)
         attention(jnp.float32, *((1, 4, 2, 256, 32) if cfg.rehearse
                                  else (1, 14, 2, 2048, 128)),
                   (1e-5, 1e-4, 1e-4, 1e-4),
@@ -508,11 +549,16 @@ def phase_masked(cfg):
         attention(jnp.bfloat16, 1, 7, 1, 16384, 128,
                   (2e-2, 1e-1, 2e-1, 2e-1), window=window)
     assert kernels.counters()["flash_bwd_q_segments"] >= walked + 2 * 2
+    # the full layer of the cell qwen3next80b-train-s8192, and its rule
+    attention(jnp.bfloat16, 1, 16, 2, 8192, 256, (2e-2, 1e-1, 2e-1, 2e-1),
+              window=0)
+    rule(jnp.bfloat16, 1, 16, 32, 8192, 128, (2e-2,) * 6)
     products(jnp.bfloat16, 16384, 2048, 1536, 16, 1e-2)
     products(jnp.bfloat16, 16384, 768, 2048, 16, 1e-2)
     counted = kernels.counters()
     assert counted.get("flash_mask_pallas", 0) > 0
     assert counted.get("moe_gmm_pallas", 0) > 0
+    assert counted.get("gdn_pallas", 0) > 0
 
 
 def phase_dp4(cfg):

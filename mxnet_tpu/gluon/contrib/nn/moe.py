@@ -150,6 +150,12 @@ class TopKMoE(HybridBlock):
     compiled step carries it back like BatchNorm's running statistics,
     and ``SPMDTrainer`` publishes it as the ``moe/*`` telemetry counters.
 
+    ``shared_expert=width`` adds a dense gated expert of that width that
+    every token passes, weighted by its own sigmoid gate (``w_sg``: D x
+    1): ``sigmoid(x w_sg) * (act(x W_gate) * (x W_up)) W_down``, outside
+    the row buffer and the grouped products, added to the held experts'
+    part (a layer that is one share of a group holds it whole).
+
     With ``axis_name`` on the active mesh (``mesh=`` or
     ``parallel.mesh_scope``) and every expert held, the experts are
     sharded over that axis (``parallel.moe.expert_parallel_ffn``).
@@ -157,7 +163,8 @@ class TopKMoE(HybridBlock):
 
     def __init__(self, num_experts, hidden_size, top_k, in_units=0,
                  experts_held=None, norm_topk_prob=True, axis_name="ep",
-                 mesh=None, activation="silu", prefix=None, params=None):
+                 mesh=None, activation="silu", shared_expert=None,
+                 prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         from ....parallel.moe import ACTIVATIONS, note_expert_rows
 
@@ -174,6 +181,7 @@ class TopKMoE(HybridBlock):
         self._held = (int(first), int(count))
         self._norm = bool(norm_topk_prob)
         self._axis, self._mesh = axis_name, mesh
+        self._S = int(shared_expert or 0)
         D = int(in_units)
         with self.name_scope():
             self.gate_weight = self.params.get(
@@ -188,6 +196,16 @@ class TopKMoE(HybridBlock):
             self.expert_rows = self.params.get(
                 "expert_rows", grad_req="null", shape=(count,),
                 init="zeros", differentiable=False)
+            if self._S:
+                self.shared_gate_weight = self.params.get(
+                    "shared_gate_weight", shape=(D, 1),
+                    allow_deferred_init=True)
+                self.shared_w13 = self.params.get(
+                    "shared_w13", shape=(D, 2 * self._S),
+                    allow_deferred_init=True)
+                self.shared_w2 = self.params.get(
+                    "shared_w2", shape=(self._S, D),
+                    allow_deferred_init=True)
         self.expert_rows.step_stat = note_expert_rows
 
     def infer_param_shapes(self, x, *args):
@@ -195,9 +213,15 @@ class TopKMoE(HybridBlock):
         self.gate_weight.shape = (D, self._E)
         self.expert_w13.shape = (count, D, 2 * self._F)
         self.expert_w2.shape = (count, self._F, D)
+        if self._S:
+            self.shared_gate_weight.shape = (D, 1)
+            self.shared_w13.shape = (D, 2 * self._S)
+            self.shared_w2.shape = (self._S, D)
 
     def hybrid_forward(self, F, x, router_input=None, *, gate_weight,
-                       expert_w13, expert_w2, expert_rows):
+                       expert_w13, expert_w2, expert_rows,
+                       shared_gate_weight=None, shared_w13=None,
+                       shared_w2=None):
         from .... import autograd
         from ....ndarray.registry import apply_pure
         from ....parallel import moe
@@ -207,9 +231,24 @@ class TopKMoE(HybridBlock):
         sharded = mesh is not None and self._axis in mesh.axis_names \
             and mesh.shape[self._axis] > 1 and self._held[1] == self._E
         k, held, n, norm = self._k, self._held, self._E, self._norm
-        act = self._act
+        act, shared = self._act, self._S
 
-        def pure(xv, gw, w13, w2, rv=None):
+        def shared_part(flat, sg, s13, s2):
+            """sigmoid(x w_sg) * (act(x W_gate) * (x W_up)) W_down."""
+            import jax
+            import jax.numpy as jnp
+
+            f32 = jnp.float32
+            h = jnp.dot(flat, s13)
+            a = (moe.ACTIVATIONS[act](h[:, :shared].astype(f32))
+                 * h[:, shared:].astype(f32)).astype(flat.dtype)
+            gate = jax.nn.sigmoid(jnp.dot(flat, sg).astype(f32))
+            return (gate * jnp.dot(a, s2).astype(f32)).astype(flat.dtype)
+
+        routed = router_input is not None
+
+        def pure(xv, gw, w13, w2, *rest):
+            rv, extra = (rest[0], rest[1:]) if routed else (None, rest)
             flat = xv.reshape(-1, xv.shape[-1])
             by = flat if rv is None else rv.reshape(flat.shape)
             if sharded:
@@ -221,11 +260,15 @@ class TopKMoE(HybridBlock):
                 idx, gates = moe.top_k_router(by, gw, k, norm)
                 y, rows = moe.expert_ffn(flat, idx, gates, w13, w2, held, n,
                                          activation=act)
+            if shared:
+                y = y + shared_part(flat, *extra)
             return y.reshape(xv.shape), rows.astype("float32")
 
         inputs = [x, gate_weight, expert_w13, expert_w2]
-        if router_input is not None:
+        if routed:
             inputs.append(router_input)
+        if shared:
+            inputs += [shared_gate_weight, shared_w13, shared_w2]
         out, rows = apply_pure(pure, inputs)
         if autograd.is_training():
             expert_rows._data = rows.data

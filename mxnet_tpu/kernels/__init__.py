@@ -43,8 +43,12 @@ def _count(name, n=1):
 def counters():
     """Snapshot of the fusion counters: ``clusters_<pattern>`` rewrite
     counts, ``nodes_absorbed``, ``impl_<lax|pallas>`` selections,
-    ``fallback_<reason>`` rejections, and the serving
-    ``serving_pad_fused`` / ``serving_slice_fused`` call counts."""
+    ``fallback_<reason>`` rejections, the serving
+    ``serving_pad_fused`` / ``serving_slice_fused`` call counts, and
+    what each kernel file counts of its own lowerings at trace time
+    (``flash_*``, ``moe_gmm_*``; ``gdn_pallas`` / ``gdn_plain``: which
+    lowering a trace of the gated delta rule took, ``gdn_chunks``: the
+    chunks a head its passes walk)."""
     return _COUNTERS.snapshot()
 
 

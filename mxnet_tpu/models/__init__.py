@@ -8,8 +8,8 @@ from .transformer import TransformerLM, TransformerBlock, \
     MultiHeadSelfAttention
 from .decoder import DecoderBlockLM
 from .moe_decoder import MoEDecoderLM, MoEDecoderBlock, \
-    GroupedQueryAttention
+    GroupedQueryAttention, GatedDeltaNet
 
 __all__ = ["vision", "get_model", "TransformerLM", "TransformerBlock",
            "MultiHeadSelfAttention", "DecoderBlockLM", "MoEDecoderLM",
-           "MoEDecoderBlock", "GroupedQueryAttention"]
+           "MoEDecoderBlock", "GroupedQueryAttention", "GatedDeltaNet"]

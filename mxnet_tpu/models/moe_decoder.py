@@ -18,9 +18,20 @@ the architecture, given layer by layer:
   of either half at position i mod L, under ``BlockDiffusionMask(L, b)``;
   the head runs on the L noised positions only, so the logits are
   (B, L, vocab).
+  ``{"gated_delta": {...}}`` is no attention at all: the layer's mixer
+  is a ``GatedDeltaNet`` (linear attention by the gated delta rule,
+  ``kernels/gated_delta.py``) of ``num_k_heads`` key heads of
+  ``head_k_dim`` serving ``num_v_heads`` value heads of ``head_v_dim``
+  behind a causal depthwise convolution of ``conv_kernel`` positions.
 - ``rope``: whether q and k carry rotary positions (rotate-half), for
   every layer or layer by layer (a layer without them has no positions
-  at all: NoPE).
+  at all: NoPE); ``rotary_dim``: on the leading ``rotary_dim`` of a
+  head's dimensions only (all of them by default).
+- ``output_gate``: the q projection is twice as wide, a head's columns
+  its query then its gate, and ``sigmoid(gate)`` multiplies the
+  attention's result before the output projection.
+- ``shared_expert``: the width of a dense gated expert every token
+  passes beside its routed ones (``TopKMoE(shared_expert=)``).
 - ``qk_norm``: a per-head RMSNorm on q and k before the positions.
 - ``router_input``: ``"mlp"`` routes each token by the normed input of
   its MLP, ``"layer"`` by the normed input of the layer, before
@@ -33,23 +44,27 @@ from ..gluon.block import HybridBlock
 from ..gluon import nn
 from ..gluon.contrib.nn import TopKMoE
 
-__all__ = ["MoEDecoderLM", "MoEDecoderBlock", "GroupedQueryAttention"]
+__all__ = ["MoEDecoderLM", "MoEDecoderBlock", "GroupedQueryAttention",
+           "GatedDeltaNet"]
 
 
 _SIZED_KINDS = {"window": "window", "block_length": "block"}
 
 
 def _attention_kind(attention):
-    """``(kind, size)`` of one layer's attention: ``("causal", None)``,
-    ``("window", w)`` or ``("block", b)``."""
+    """``(kind, size)`` of one layer's mixer: ``("causal", None)``,
+    ``("window", w)``, ``("block", b)`` or ``("gated_delta", {sizes})``."""
     if attention == "causal":
         return "causal", None
     if isinstance(attention, dict) and len(attention) == 1:
         (key, size), = attention.items()
+        if key == "gated_delta" and isinstance(size, dict):
+            return key, dict(size)
         if key in _SIZED_KINDS and int(size) > 0:
             return _SIZED_KINDS[key], int(size)
-    raise ValueError('attention is "causal", {"window": w} or '
-                     f'{{"block_length": b}}, got {attention!r}')
+    raise ValueError('attention is "causal", {"window": w}, '
+                     '{"block_length": b} or {"gated_delta": {...}}, got '
+                     f"{attention!r}")
 
 
 def _per_layer(value, num_layers, what):
@@ -83,21 +98,32 @@ class GroupedQueryAttention(HybridBlock):
     """Self-attention over (B, S, E): ``num_heads`` query heads read
     ``num_kv_heads`` key/value heads of ``head_dim`` (query head h reads
     head h // group), q and k each pass an RMSNorm over their head
-    (``qk_norm``) and then RoPE (``rope``), no bias. One fused q|k|v
-    projection."""
+    (``qk_norm``) and then RoPE (``rope``, on the leading ``rotary_dim``
+    of the head), no bias. One fused q|k|v projection; with
+    ``output_gate`` its q part is twice as wide, each head's query then
+    its gate, and ``sigmoid(gate)`` multiplies the heads' results before
+    the output projection."""
 
     def __init__(self, embed_dim, num_heads, num_kv_heads, head_dim,
                  rope_theta=1e6, epsilon=1e-6, attention="causal",
-                 rope=True, qk_norm=True, **kwargs):
+                 rope=True, qk_norm=True, rotary_dim=None,
+                 output_gate=False, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads over {num_kv_heads}")
         self._h, self._hkv, self._d = num_heads, num_kv_heads, head_dim
         self._theta, self._eps = float(rope_theta), float(epsilon)
         self._kind, self._size = _attention_kind(attention)
+        if self._kind == "gated_delta":
+            raise ValueError("a gated-delta layer is a GatedDeltaNet")
         self._rope, self._qk_norm = bool(rope), bool(qk_norm)
+        self._rot = head_dim if rotary_dim is None else int(rotary_dim)
+        if not 0 < self._rot <= head_dim or self._rot % 2:
+            raise ValueError(f"rotary_dim {rotary_dim} of {head_dim}")
+        self._gate = bool(output_gate)
+        q_heads = num_heads * (2 if self._gate else 1)
         with self.name_scope():
-            self.qkv = nn.Dense((num_heads + 2 * num_kv_heads) * head_dim,
+            self.qkv = nn.Dense((q_heads + 2 * num_kv_heads) * head_dim,
                                 use_bias=False, flatten=False)
             if self._qk_norm:
                 self.q_norm = self.params.get(
@@ -112,8 +138,18 @@ class GroupedQueryAttention(HybridBlock):
         h, hkv, d, eps, theta = self._h, self._hkv, self._d, self._eps, \
             self._theta
         kind, size, with_rope = self._kind, self._size, self._rope
+        rot, gated = self._rot, self._gate
+
+        def positions(x, pos):
+            if rot == d:
+                return rope(x, pos, theta)
+            import jax.numpy as jnp
+
+            return jnp.concatenate(
+                [rope(x[..., :rot], pos, theta), x[..., rot:]], -1)
 
         def pure(qkv, gq=None, gk=None):
+            import jax
             import jax.numpy as jnp
 
             from ..gluon.nn.basic_layers import rms_norm
@@ -121,7 +157,13 @@ class GroupedQueryAttention(HybridBlock):
                 BlockDiffusionMask, SlidingWindowMask, flash_attention)
 
             b, s, _ = qkv.shape
-            q = qkv[..., :h * d].reshape(b, s, h, d)
+            gate = None
+            if gated:       # a head's 2d columns: its query, its gate
+                qg = qkv[..., :2 * h * d].reshape(b, s, h, 2 * d)
+                q, gate = qg[..., :d], qg[..., d:].reshape(b, s, h * d)
+                qkv = qkv[..., h * d:]
+            else:
+                q = qkv[..., :h * d].reshape(b, s, h, d)
             k = qkv[..., h * d:(h + hkv) * d].reshape(b, s, hkv, d)
             v = qkv[..., (h + hkv) * d:].reshape(b, s, hkv, d)
 
@@ -139,13 +181,114 @@ class GroupedQueryAttention(HybridBlock):
                 q, k = rms_norm(q, gq, eps), rms_norm(k, gk, eps)
             q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
             if with_rope:
-                q, k = rope(q, pos, theta), rope(k, pos, theta)
+                q, k = positions(q, pos), positions(k, pos)
             o = flash_attention(q, k, v.transpose(0, 2, 1, 3),
                                 causal=mask is None, mask=mask)
-            return o.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+            o = o.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+            if gate is not None:
+                o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(o.dtype)
+            return o
 
         gammas = [q_norm, k_norm] if self._qk_norm else []
         return self.out(apply_pure(pure, [self.qkv(x)] + gammas))
+
+
+class GatedDeltaNet(HybridBlock):
+    """Linear attention by the gated delta rule over (B, S, E) (Gated
+    DeltaNet, Yang et al., arXiv:2412.06464), no bias: one fused
+    projection to q | k | v | z and one to b | a (a value head each);
+    q | k | v pass a causal depthwise convolution over ``conv_kernel``
+    positions (shifted multiply-adds) and SiLU; q and k are l2-normalised
+    a head, q scaled by ``head_k_dim ** -0.5``; ``beta = sigmoid(b)``,
+    ``g = -exp(A_log) * softplus(a + dt_bias)`` in float32; the rule
+    (``kernels.gated_delta.gated_delta_rule``, key head j serving the
+    value heads from ``j * num_v_heads / num_k_heads`` on); an RMSNorm
+    over each head's result times ``silu(z)``; the output projection."""
+
+    def __init__(self, embed_dim, num_k_heads, num_v_heads, head_k_dim,
+                 head_v_dim, conv_kernel=4, epsilon=1e-6, chunk=64,
+                 **kwargs):
+        super().__init__(**kwargs)
+        if num_v_heads % num_k_heads:
+            raise ValueError(f"{num_v_heads} value heads over "
+                             f"{num_k_heads} key heads")
+        self._hk, self._hv = int(num_k_heads), int(num_v_heads)
+        self._dk, self._dv = int(head_k_dim), int(head_v_dim)
+        self._taps, self._eps = int(conv_kernel), float(epsilon)
+        self._chunk = int(chunk)
+        kd, vd = self._hk * self._dk, self._hv * self._dv
+        with self.name_scope():
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(self._taps, 2 * kd + vd))
+            self.a_log = self.params.get("a_log", shape=(self._hv,),
+                                         init="zeros")
+            self.dt_bias = self.params.get("dt_bias", shape=(self._hv,),
+                                           init="ones")
+            self.norm_gamma = self.params.get(
+                "norm_gamma", shape=(self._dv,), init="ones")
+            self.qkvz = nn.Dense(2 * kd + 2 * vd, use_bias=False,
+                                 flatten=False)
+            self.ba = nn.Dense(2 * self._hv, use_bias=False, flatten=False)
+            self.out = nn.Dense(embed_dim, use_bias=False, flatten=False)
+
+    def hybrid_forward(self, F, x, conv_weight, a_log, dt_bias, norm_gamma):
+        from ..ndarray.registry import apply_pure
+
+        hk, hv, dk, dv = self._hk, self._hv, self._dk, self._dv
+        taps, eps, chunk = self._taps, self._eps, self._chunk
+
+        def pure(qkvz, ba, conv_w, a_log, dt_bias, gamma):
+            import jax
+            import jax.numpy as jnp
+            from jax import lax
+
+            from ..gluon.nn.basic_layers import rms_norm
+            from ..kernels.gated_delta import gated_delta_rule
+
+            b, s, _ = qkvz.shape
+            kd, f32 = hk * dk, jnp.float32
+
+            def unit(a):    # (B, S, H, d) float32, l2-normalised a head
+                return a * lax.rsqrt(
+                    jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6)
+
+            # Both sides of the rule are element-wise work in float32 on
+            # 8,192 channels a position: recomputed in the backward pass
+            # from the projection's result, which is kept anyway.
+            @jax.checkpoint
+            def before(qkvz, conv_w):
+                # y_t = sum_j w_j x_(t - (taps - 1) + j): the last tap is
+                # the position's own
+                mixed = qkvz[..., :2 * kd + hv * dv]
+                padded = jnp.pad(mixed, ((0, 0), (taps - 1, 0), (0, 0)))
+                conv = jax.nn.silu(sum(
+                    padded[:, j:j + s].astype(f32) * conv_w[j].astype(f32)
+                    for j in range(taps)))
+                q = unit(conv[..., :kd].reshape(b, s, hk, dk)) * dk ** -0.5
+                k = unit(conv[..., kd:2 * kd].reshape(b, s, hk, dk))
+                v = conv[..., 2 * kd:].reshape(b, s, hv, dv)
+                return tuple(a.astype(qkvz.dtype).transpose(0, 2, 1, 3)
+                             for a in (q, k, v))
+
+            @jax.checkpoint
+            def after(o, qkvz, gamma):
+                z = qkvz[..., 2 * kd + hv * dv:].reshape(b, s, hv, dv)
+                o = rms_norm(o.transpose(0, 2, 1, 3), gamma, eps)
+                o = o.astype(f32) * jax.nn.silu(z.astype(f32))
+                return o.astype(qkvz.dtype).reshape(b, s, hv * dv)
+
+            q, k, v = before(qkvz, conv_w)
+            beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
+            g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+                ba[..., hv:].astype(f32) + dt_bias.astype(f32))
+            o = gated_delta_rule(q, k, v, g.transpose(0, 2, 1),
+                                 beta.transpose(0, 2, 1), chunk=chunk)
+            return after(o, qkvz, gamma)
+
+        return self.out(apply_pure(pure, [
+            self.qkvz(x), self.ba(x), conv_weight, a_log, dt_bias,
+            norm_gamma]))
 
 
 class MoEDecoderBlock(HybridBlock):
@@ -153,22 +296,29 @@ class MoEDecoderBlock(HybridBlock):
                  num_experts, expert_dim, top_k, experts_held=None,
                  norm_topk_prob=True, rope_theta=1e6, epsilon=1e-6,
                  attention="causal", rope=True, qk_norm=True,
-                 router_input="mlp", activation="silu", **kwargs):
+                 router_input="mlp", activation="silu", rotary_dim=None,
+                 output_gate=False, shared_expert=None, **kwargs):
         super().__init__(**kwargs)
         if router_input not in ("mlp", "layer"):
             raise ValueError(f'router_input is "mlp" or "layer", got '
                              f"{router_input!r}")
         self._route_by_layer_input = router_input == "layer"
+        kind, size = _attention_kind(attention)
         with self.name_scope():
             self.ln1 = nn.RMSNorm(epsilon)
-            self.attn = GroupedQueryAttention(
-                embed_dim, num_heads, num_kv_heads, head_dim, rope_theta,
-                epsilon, attention, rope, qk_norm)
+            if kind == "gated_delta":
+                self.attn = GatedDeltaNet(embed_dim, epsilon=epsilon, **size)
+            else:
+                self.attn = GroupedQueryAttention(
+                    embed_dim, num_heads, num_kv_heads, head_dim, rope_theta,
+                    epsilon, attention, rope, qk_norm, rotary_dim,
+                    output_gate)
             self.ln2 = nn.RMSNorm(epsilon)
             self.moe = TopKMoE(num_experts, expert_dim, top_k,
                                experts_held=experts_held,
                                norm_topk_prob=norm_topk_prob,
-                               activation=activation)
+                               activation=activation,
+                               shared_expert=shared_expert)
 
     def hybrid_forward(self, F, x):
         n = self.ln1(x)
@@ -185,13 +335,14 @@ class MoEDecoderLM(HybridBlock):
                  num_kv_heads, head_dim, num_experts, expert_dim, top_k,
                  experts_held=None, norm_topk_prob=True, rope_theta=1e6,
                  epsilon=1e-6, attention="causal", rope=True, qk_norm=True,
-                 router_input="mlp", activation="silu", **kwargs):
+                 router_input="mlp", activation="silu", rotary_dim=None,
+                 output_gate=False, shared_expert=None, **kwargs):
         super().__init__(**kwargs)
         kinds = _per_layer(attention, num_layers, "attention")
         ropes = _per_layer(rope, num_layers, "rope")
-        parsed = {_attention_kind(a) for a in kinds}
+        parsed = [_attention_kind(a) for a in kinds]
         self._block = any(kind == "block" for kind, _ in parsed)
-        if self._block and len(parsed) > 1:
+        if self._block and any(p != parsed[0] for p in parsed):
             raise ValueError("block-diffusion attention is every layer's, "
                              f"at one block length, or none's: {kinds!r}")
         with self.name_scope():
@@ -202,7 +353,8 @@ class MoEDecoderLM(HybridBlock):
                     embed_dim, num_heads, num_kv_heads, head_dim,
                     num_experts, expert_dim, top_k, experts_held,
                     norm_topk_prob, rope_theta, epsilon, kind, with_rope,
-                    qk_norm, router_input, activation))
+                    qk_norm, router_input, activation, rotary_dim,
+                    output_gate, shared_expert))
             self.ln_f = nn.RMSNorm(epsilon)
             self.head = nn.Dense(vocab_size, flatten=False, use_bias=False)
 

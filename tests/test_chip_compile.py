@@ -201,6 +201,69 @@ def test_long_head_backward_compiles_in_segments(one_chip, window):
     assert cost_model.pallas_fits_vmem("attention", (seq, d), 2)
 
 
+def test_head_size_256_in_a_group_of_8_compiles(one_chip):
+    """The full layer of the cell qwen3next80b-train-s8192: (1, 16 over
+    2, 8192, 256) bf16 causal. Forward at (512, 1024), backward at
+    (512, 512) in four segments of 2,048 query rows, as the chooser
+    prices them: one ``flash_fwd``, one ``flash_bwd``, no ``while``."""
+    seq, d, dtype = 8192, 256, jnp.bfloat16
+    assert fa.choose_tiles(seq, seq, d, 2) == (512, 1024)
+    assert fa.choose_backward(seq, seq, d, 2) == (512, 512, 2048)
+
+    def loss(q, k, v):
+        with jax.named_scope("attn"):
+            o = _flash(q, k, v, d ** -0.5, True, "pallas")
+        return o.astype(jnp.float32).sum()
+
+    before = kernels.counters()
+    c = _compile(jax.grad(loss, (0, 1, 2)), one_chip,
+                 ((1, 16, seq, d), dtype), ((1, 2, seq, d), dtype),
+                 ((1, 2, seq, d), dtype))
+    _assert_kernel(c, "flash_fwd")
+    _assert_kernel(c, "flash_bwd")
+    text = c.as_text()
+    assert not re.search(r"\bwhile\(", text)
+    assert f"{seq},{seq}]" not in text
+    after = kernels.counters()
+    assert after["flash_bwd_pallas"] == before.get("flash_bwd_pallas", 0) + 1
+    assert after.get("flash_bwd_scan", 0) == before.get("flash_bwd_scan", 0)
+    assert after["flash_bwd_q_segments"] == before.get(
+        "flash_bwd_q_segments", 0) + 4
+    assert cost_model.pallas_fits_vmem("attention", (seq, d), 2)
+
+
+@pytest.mark.parametrize("b,hk,hv,seq,dtype", [
+    (1, 16, 32, 8192, jnp.bfloat16),    # a linear layer of the same cell
+    (1, 2, 4, 1000, jnp.float32),       # float32 at ``highest``, ragged
+])
+def test_gated_delta_rule_compiles(one_chip, b, hk, hv, seq, dtype):
+    """The gated delta rule under ``jax.grad``: the walk over chunks and
+    its reverse under the instruction names ``gdn_ms.tokens`` and the two
+    rooflines find them by, the preparation around them XLA's."""
+    from mxnet_tpu.kernels import gated_delta as gd
+
+    d = 128
+    assert gd.eligible(d, d, 64, jnp.dtype(dtype).itemsize)
+
+    def loss(q, k, v, g, beta):
+        with jax.named_scope("gdn"):
+            o = gd._rule(q, k, v, g, beta, 64, False)
+        return o.astype(jnp.float32).sum()
+
+    seq_p = -(-seq // gd.step_rows(seq, 64)) * gd.step_rows(seq, 64)
+    before = kernels.counters()
+    c = _compile(jax.value_and_grad(loss, (0, 1, 2, 3, 4)), one_chip,
+                 ((b, hk, seq_p, d), dtype), ((b, hk, seq_p, d), dtype),
+                 ((b, hv, seq_p, d), dtype), ((b, hv, seq_p), jnp.float32),
+                 ((b, hv, seq_p), jnp.float32))
+    _assert_kernel(c, "gdn_fwd")
+    _assert_kernel(c, "gdn_bwd")
+    assert not re.search(r"\bwhile\(", c.as_text())
+    after = kernels.counters()
+    assert after["gdn_chunks"] - before.get("gdn_chunks", 0) \
+        == 2 * seq_p // 64
+
+
 @pytest.mark.parametrize("m,k,n,g,dtype", [
     (16384, 2048, 1536, 16, jnp.bfloat16),  # the cell's gate + up product
     (16384, 768, 2048, 16, jnp.bfloat16),   # and its down product
@@ -289,6 +352,47 @@ def test_kernels_keep_their_names_inside_the_trainers_step(one_chip,
         assert after.get(name, 0) > before.get(name, 0), name
     for name in ("flash_bwd_scan", "moe_gmm_plain"):
         assert after.get(name, 0) == before.get(name, 0), name
+
+
+def test_gated_delta_layers_keep_their_names_inside_the_trainers_step(
+        one_chip, monkeypatch):
+    """``SPMDTrainer``'s step of a small ``MoEDecoderLM`` under [gated
+    delta, gated full attention with partial RoPE] and a shared expert,
+    compiled for the chip: the rule's four kernels and the flash kernels
+    under the instruction names the benchmark's readers go by, counted
+    as kernels and no twin."""
+    import numpy as onp
+
+    from mxnet_tpu import models, parallel
+    from mxnet_tpu.gluon.loss import L2Loss
+
+    linear = {"gated_delta": dict(num_k_heads=1, num_v_heads=2,
+                                  head_k_dim=128, head_v_dim=128)}
+    net = models.MoEDecoderLM(
+        vocab_size=256, embed_dim=128, num_layers=2, num_heads=2,
+        num_kv_heads=1, head_dim=128, num_experts=8, expert_dim=128,
+        top_k=2, experts_held=(0, 4), attention=[linear, "causal"],
+        rotary_dim=32, output_gate=True, shared_expert=128)
+    net.initialize()
+    trainer = parallel.SPMDTrainer(
+        net, L2Loss(), optimizer="adamw",
+        optimizer_params={"learning_rate": 1e-3},
+        mesh=parallel.make_mesh({"dp": 1}, devices=jax.devices()[:1]),
+        compute_dtype="bfloat16")
+    x = onp.zeros((2, 256), "int32")
+    y = onp.zeros((2, 256, 256), "float32")
+    trainer._ensure_built(x, y)     # its eager forward takes the CPU's twins
+    before = kernels.counters()
+    c = _compile_trainer_step(trainer, x, y, one_chip, monkeypatch)
+    for name in ("gdn_prep_fwd", "gdn_fwd", "gdn_prep_refwd", "gdn_bwd",
+                 "gdn_prep_bwd", "flash_fwd", "flash_bwd", "moe_gmm_fwd"):
+        _assert_kernel(c, name)
+    after = kernels.counters()
+    for name in ("gdn_pallas", "flash_bwd_pallas", "moe_gmm_pallas"):
+        assert after.get(name, 0) > before.get(name, 0), name
+    for name in ("gdn_plain", "flash_bwd_scan", "moe_gmm_plain"):
+        assert after.get(name, 0) == before.get(name, 0), name
+    assert after["gdn_chunks"] - before.get("gdn_chunks", 0) == 2 * 4
 
 
 @pytest.fixture(scope="module")
