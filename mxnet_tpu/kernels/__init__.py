@@ -48,7 +48,8 @@ def counters():
     what each kernel file counts of its own lowerings at trace time
     (``flash_*``, ``moe_gmm_*``; ``gdn_pallas`` / ``gdn_plain``: which
     lowering a trace of the gated delta rule took, ``gdn_chunks``: the
-    chunks a head its passes walk)."""
+    chunks a head its passes walk; ``pick_masked``: the ``pick`` ops a
+    trace lowered as a masked sum, ``ndarray/ops_index.py``)."""
     return _COUNTERS.snapshot()
 
 

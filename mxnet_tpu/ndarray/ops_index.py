@@ -33,12 +33,31 @@ def take_along_axis(data, indices, axis=0):
 
 @register()
 def pick(data, index, axis=-1, keepdims=False, mode="clip"):
-    """Reference: broadcast_reduce_op_index.cc pick."""
+    """Reference: broadcast_reduce_op_index.cc pick. ``mode`` as there:
+    ``clip`` holds an index in ``[0, n - 1]``, ``wrap`` takes it modulo
+    ``n``.
+
+    The element is taken by a mask, not by a gather: the one place of
+    ``axis`` whose number is the index is selected (never multiplied: an
+    unpicked ``inf`` or NaN reaches nothing) and the rest summed as
+    zeros, which is the gathered value (a picked ``-0.0`` reads ``0.0``:
+    it was added to zeros). Under autodiff that is a select of the
+    cotangent, dense, where a gather's would scatter-add into zeros the
+    size of ``data``: at one sequence a batch XLA:TPU keeps that
+    scatter, into a flat logits-sized array that a ``while`` then
+    re-tiles (PERF.md section 6, PR 37). Counted once a trace:
+    ``kernels.counters()["pick_masked"]``."""
+    from ..kernels import _count
+
+    _count("pick_masked")
+    axis %= data.ndim
+    n = data.shape[axis]
     idx = jnp.expand_dims(index.astype(jnp.int32), axis=axis)
-    out = jnp.take_along_axis(data, idx, axis=axis)
-    if not keepdims:
-        out = jnp.squeeze(out, axis=axis)
-    return out
+    idx = idx % n if mode == "wrap" else jnp.clip(idx, 0, n - 1)
+    place = lax.broadcasted_iota(
+        jnp.int32, (1,) * axis + (n,) + (1,) * (data.ndim - axis - 1), axis)
+    return jnp.sum(jnp.where(idx == place, data, 0), axis=axis,
+                   keepdims=keepdims, dtype=data.dtype)
 
 
 @register()

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .ops_index import pick
 from .registry import register
 
 
@@ -451,9 +452,7 @@ def embedding(data, weight, input_dim=0, output_dim=0, dtype="float32",
 @register()
 def softmax_cross_entropy(data, label):
     """Reference: src/operator/loss_binary_op.cc."""
-    logp = jax.nn.log_softmax(data, axis=-1)
-    nll = -jnp.take_along_axis(logp, label.astype(jnp.int32)[..., None], axis=-1)
-    return jnp.sum(nll)
+    return -jnp.sum(pick(jax.nn.log_softmax(data, axis=-1), label, axis=-1))
 
 
 @register()

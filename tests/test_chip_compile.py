@@ -395,6 +395,39 @@ def test_gated_delta_layers_keep_their_names_inside_the_trainers_step(
     assert after["gdn_chunks"] - before.get("gdn_chunks", 0) == 2 * 4
 
 
+@pytest.mark.parametrize("b,s,v", [
+    (1, 8192, 18992),     # qwen3next80b-train-s8192: one sequence a step
+    (2, 2048, 50272),     # opt1.3b-train-s2048: two
+])
+def test_next_token_loss_compiles_dense(one_chip, b, s, v):
+    """The cells' loss block at their widths: the head's product
+    (E = 2048, bfloat16), float32 logits, ``log_softmax`` of all
+    positions but the last, ``pick`` of the next token, the mean, and
+    ``jax.grad`` to the hidden state and the head's weight. With the
+    element gathered, XLA:TPU at ONE sequence a batch kept the gather's
+    scatter-add: a zero-filled flat ``f32[(s - 1) * v]``, a serial
+    scatter into it and a ``while`` of 148 trips that re-tiled it for
+    the softmax's gradient, three logits-sized temporaries in all (1.867
+    GB at the first shape; PERF.md section 6, PR 37). Taken by a mask
+    the program is dense at either batch: one float32 logits array
+    (0.623 and 0.824 GB)."""
+    from mxnet_tpu.ndarray.ops_index import pick
+
+    def loss(h, w, label):
+        pred = jnp.einsum("bse,ve->bsv", h, w).astype(jnp.float32)
+        logp = jax.nn.log_softmax(pred[:, :-1], axis=-1)
+        return jnp.mean(-pick(logp, label[:, 1:], axis=-1, keepdims=True))
+
+    c = _compile(jax.grad(loss, argnums=(0, 1)), one_chip,
+                 ((b, s, 2048), jnp.bfloat16), ((v, 2048), jnp.bfloat16),
+                 ((b, s), jnp.int32))
+    text = c.as_text()
+    assert " while(" not in text
+    assert " scatter(" not in text
+    logits = b * (s - 1) * v * 4
+    assert abs(c.memory_analysis().temp_size_in_bytes / logits - 1) < 0.05
+
+
 @pytest.fixture(scope="module")
 def opt_layer():
     """One ``TransformerLM`` layer at the widths of the cell
