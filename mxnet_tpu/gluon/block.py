@@ -18,6 +18,7 @@ like the reference records one node for the whole cached graph
 """
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 from collections import OrderedDict
@@ -127,11 +128,18 @@ class Block:
                            for key, block in self._children.items())
         return s.format(name=type(self).__name__, modstr=modstr)
 
+    #: the name the first parent registered this block under: the scope
+    #: its forward runs in (``jax.named_scope``), so that a compiled
+    #: program's op names read ``.../blocks/2/attn/...``; None for a block
+    #: no parent registered
+    _scope_name = None
+
     def __setattr__(self, name, value):
         if isinstance(value, Block):
             existing = getattr(self, "_children", None)
             if existing is not None:
                 self._children[name] = value
+                value._adopt(name)
         elif isinstance(value, Parameter):
             if hasattr(self, "_reg_params"):
                 self._reg_params[name] = value
@@ -167,7 +175,20 @@ class Block:
         return ret
 
     def register_child(self, block, name=None):
-        self._children[name or str(len(self._children))] = block
+        name = name or str(len(self._children))
+        self._children[name] = block
+        block._adopt(name)
+
+    def _adopt(self, name):
+        if self._scope_name is None:
+            self._scope_name = name
+
+    def _scope(self):
+        """The forward's scope: metadata at trace time, nothing in a
+        compiled step (docs/TELEMETRY.md, "Scopes")."""
+        if self._scope_name is None:
+            return contextlib.nullcontext()
+        return jax.named_scope(self._scope_name)
 
     def register_forward_hook(self, hook):
         self._forward_hooks.append(hook)
@@ -372,7 +393,8 @@ class Block:
     def __call__(self, *args, **kwargs):
         for hook in list(self._forward_pre_hooks):
             hook(self, args)
-        out = self.forward(*args, **kwargs)
+        with self._scope():
+            out = self.forward(*args, **kwargs)
         for hook in list(self._forward_hooks):
             hook(self, args, out)
         return out
@@ -577,7 +599,8 @@ class HybridBlock(Block):
                     self._build_cache()
                 for hook in list(self._forward_pre_hooks):
                     hook(self, args)
-                out = self._cached_op(*args)
+                with self._scope():
+                    out = self._cached_op(*args)
                 for hook in list(self._forward_hooks):
                     hook(self, args, out)
                 return out

@@ -239,11 +239,13 @@ class TopKMoE(HybridBlock):
             import jax.numpy as jnp
 
             f32 = jnp.float32
-            h = jnp.dot(flat, s13)
-            a = (moe.ACTIVATIONS[act](h[:, :shared].astype(f32))
-                 * h[:, shared:].astype(f32)).astype(flat.dtype)
-            gate = jax.nn.sigmoid(jnp.dot(flat, sg).astype(f32))
-            return (gate * jnp.dot(a, s2).astype(f32)).astype(flat.dtype)
+            with jax.named_scope("shared"):
+                h = jnp.dot(flat, s13)
+                a = (moe.ACTIVATIONS[act](h[:, :shared].astype(f32))
+                     * h[:, shared:].astype(f32)).astype(flat.dtype)
+                gate = jax.nn.sigmoid(jnp.dot(flat, sg).astype(f32))
+                return (gate * jnp.dot(a, s2).astype(f32)).astype(
+                    flat.dtype)
 
         routed = router_input is not None
 

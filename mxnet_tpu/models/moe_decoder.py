@@ -178,16 +178,19 @@ class GroupedQueryAttention(HybridBlock):
             elif kind == "window" and size < s:     # else causal alone
                 mask = SlidingWindowMask(s, size)
             if gq is not None:
-                q, k = rms_norm(q, gq, eps), rms_norm(k, gk, eps)
+                with jax.named_scope("qk_norm"):
+                    q, k = rms_norm(q, gq, eps), rms_norm(k, gk, eps)
             q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
             if with_rope:
-                q, k = positions(q, pos), positions(k, pos)
+                with jax.named_scope("rope"):
+                    q, k = positions(q, pos), positions(k, pos)
             o = flash_attention(q, k, v.transpose(0, 2, 1, 3),
                                 causal=mask is None, mask=mask)
             o = o.transpose(0, 2, 1, 3).reshape(b, s, h * d)
             if gate is not None:
-                o = (o.astype(jnp.float32) * jax.nn.sigmoid(
-                    gate.astype(jnp.float32))).astype(o.dtype)
+                with jax.named_scope("gate"):
+                    o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+                        gate.astype(jnp.float32))).astype(o.dtype)
             return o
 
         gammas = [q_norm, k_norm] if self._qk_norm else []
@@ -260,23 +263,28 @@ class GatedDeltaNet(HybridBlock):
             def before(qkvz, conv_w):
                 # y_t = sum_j w_j x_(t - (taps - 1) + j): the last tap is
                 # the position's own
-                mixed = qkvz[..., :2 * kd + hv * dv]
-                padded = jnp.pad(mixed, ((0, 0), (taps - 1, 0), (0, 0)))
-                conv = jax.nn.silu(sum(
-                    padded[:, j:j + s].astype(f32) * conv_w[j].astype(f32)
-                    for j in range(taps)))
-                q = unit(conv[..., :kd].reshape(b, s, hk, dk)) * dk ** -0.5
-                k = unit(conv[..., kd:2 * kd].reshape(b, s, hk, dk))
+                with jax.named_scope("conv"):
+                    mixed = qkvz[..., :2 * kd + hv * dv]
+                    padded = jnp.pad(mixed,
+                                     ((0, 0), (taps - 1, 0), (0, 0)))
+                    conv = jax.nn.silu(sum(
+                        padded[:, j:j + s].astype(f32)
+                        * conv_w[j].astype(f32) for j in range(taps)))
+                with jax.named_scope("l2norm"):
+                    q = unit(conv[..., :kd].reshape(b, s, hk, dk)) \
+                        * dk ** -0.5
+                    k = unit(conv[..., kd:2 * kd].reshape(b, s, hk, dk))
                 v = conv[..., 2 * kd:].reshape(b, s, hv, dv)
                 return tuple(a.astype(qkvz.dtype).transpose(0, 2, 1, 3)
                              for a in (q, k, v))
 
             @jax.checkpoint
             def after(o, qkvz, gamma):
-                z = qkvz[..., 2 * kd + hv * dv:].reshape(b, s, hv, dv)
-                o = rms_norm(o.transpose(0, 2, 1, 3), gamma, eps)
-                o = o.astype(f32) * jax.nn.silu(z.astype(f32))
-                return o.astype(qkvz.dtype).reshape(b, s, hv * dv)
+                with jax.named_scope("gate_norm"):
+                    z = qkvz[..., 2 * kd + hv * dv:].reshape(b, s, hv, dv)
+                    o = rms_norm(o.transpose(0, 2, 1, 3), gamma, eps)
+                    o = o.astype(f32) * jax.nn.silu(z.astype(f32))
+                    return o.astype(qkvz.dtype).reshape(b, s, hv * dv)
 
             q, k, v = before(qkvz, conv_w)
             beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))

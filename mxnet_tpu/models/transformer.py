@@ -124,8 +124,11 @@ class TransformerLM(HybridBlock):
         if self._tie:
             # tied decoder = embedding matrix reused as the output proj
             # (reference word LM ties weights, word_lm/model.py:41-50)
+            import jax
+
             w = self.embed.weight.data()
             E = w.shape[1]
-            return nd.dot(x.reshape(-1, E),
-                          nd.transpose(w)).reshape(B, S, -1)
+            with jax.named_scope("head"):   # no child block to open it
+                return nd.dot(x.reshape(-1, E),
+                              nd.transpose(w)).reshape(B, S, -1)
         return self.head(x)  # (B, S, vocab)

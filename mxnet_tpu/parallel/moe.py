@@ -178,16 +178,17 @@ def top_k_router(x, gate_w, k, norm_topk_prob=True):
     """Top-k routing over ALL experts: ``(idx (T, k) int32, gates (T, k)
     float32)``. Logits accumulate and the softmax runs in float32; with
     ``norm_topk_prob`` the k probabilities are divided by their sum."""
-    logits = jnp.dot(x, gate_w, preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    _, idx = lax.top_k(lax.stop_gradient(probs), k)
-    # the k probabilities picked through a one-hot mask: its derivative
-    # is dense too, where top_k's own would scatter
-    picked = idx[..., None] == jnp.arange(probs.shape[-1])[None, None]
-    gates = jnp.sum(jnp.where(picked, probs[:, None, :], 0.0), axis=-1)
-    if norm_topk_prob:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
-    return idx.astype(jnp.int32), gates
+    with jax.named_scope("router"):
+        logits = jnp.dot(x, gate_w, preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        _, idx = lax.top_k(lax.stop_gradient(probs), k)
+        # the k probabilities picked through a one-hot mask: its
+        # derivative is dense too, where top_k's own would scatter
+        picked = idx[..., None] == jnp.arange(probs.shape[-1])[None, None]
+        gates = jnp.sum(jnp.where(picked, probs[:, None, :], 0.0), axis=-1)
+        if norm_topk_prob:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        return idx.astype(jnp.int32), gates
 
 
 def _layout(idx, first, count):
@@ -329,13 +330,16 @@ def _ffn_pass(p, x, gates, w13, w2, order, rank, starts, sizes, cap,
     sizes_p = jnp.clip(jnp.minimum(ends, lo + cap) - jnp.maximum(starts, lo),
                        0, cap)
     f = w2.shape[1]
-    runs = _token_runs(tok, valid, t)
-    xg = _dispatch(x, tok, runs, k)
+    with jax.named_scope("runs"):
+        runs = _token_runs(tok, valid, t)
+    with jax.named_scope("dispatch"):
+        xg = _dispatch(x, tok, runs, k)
     h = grouped_matmul(xg, w13, sizes_p, use_pallas)
     a = (ACTIVATIONS[activation](h[:, :f].astype(jnp.float32))
          * h[:, f:].astype(jnp.float32)).astype(x.dtype)
     yo = grouped_matmul(a, w2, sizes_p, use_pallas)
-    return _combine(yo, gates, tok, row_gate, rows, live, runs)
+    with jax.named_scope("combine"):
+        return _combine(yo, gates, tok, row_gate, rows, live, runs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
@@ -419,7 +423,8 @@ def expert_ffn(x, idx, gates, w13, w2, experts_held=None, num_experts=None,
     expect = t * k * count / num_experts
     cap = max(1, math.ceil(capacity_factor * expect / ROWS)) * ROWS
     cap = min(cap, -(-t * k // ROWS) * ROWS)
-    order, rank, starts, sizes = _layout(idx, first, count)
+    with jax.named_scope("layout"):
+        order, rank, starts, sizes = _layout(idx, first, count)
     with jax.named_scope("moe"):
         y = _ffn(x, gates.astype(jnp.float32), w13, w2, order, rank, starts,
                  sizes, cap, use_pallas, activation)

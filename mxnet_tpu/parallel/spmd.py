@@ -25,6 +25,7 @@ from ..base import MXNetError
 from .. import ndarray as nd
 from ..utils import compile_cache as _cc
 from ..telemetry import metrics as _metrics
+from ..telemetry import scopes as _scopes
 from ..telemetry import tracer as _telem
 from ..ndarray import NDArray
 from .. import autograd
@@ -376,6 +377,7 @@ class SPMDTrainer:
         stat_idx = [i for i, p in enumerate(self._params)
                     if getattr(p, "step_stat", None) is not None]
         self._stat_sinks = [self._params[i].step_stat for i in stat_idx]
+        self._scopes_due = True     # published after the first step
         self._stats_pending = collections.deque(maxlen=8)
         batch_shard = NamedSharding(mesh, P(self._axis))
         rep = NamedSharding(mesh, P())
@@ -394,32 +396,36 @@ class SPMDTrainer:
             def loss_fn(pv):
                 saved = [p._data for p in pnds]
                 try:
-                    for i, (p, v) in enumerate(zip(pnds, pv)):
-                        # half-precision compute on fp32 masters; the
-                        # cast's vjp upcasts cotangents, so grads come
-                        # back fp32. Non-trainable params (BN running
-                        # stats) stay fp32 — re-quantizing the running
-                        # statistic each step would defeat the fp32-stat
-                        # accumulation in batch_norm (AMP rule: norm
-                        # stats keep full precision)
-                        if cdtype is not None and trainable[i] and \
-                                jnp.issubdtype(v.dtype, jnp.floating):
-                            v = v.astype(cdtype)
-                        p._data = v
-                    xin = xd
-                    if cdtype is not None and \
-                            jnp.issubdtype(xin.dtype, jnp.floating):
-                        xin = xin.astype(cdtype)
                     # "fwd" reaches the ops' metadata as jvp(fwd) and,
-                    # for the backward, transpose(jvp(fwd))
-                    with autograd.pause(train_mode=True), \
-                            mxrandom.key_provider(fwd_key), \
-                            jax.named_scope("fwd"):
-                        out = net.forward(NDArray(xin))
-                        if cdtype is not None:
-                            out = NDArray(out.data.astype(jnp.float32))
-                        lval = loss.forward(out, NDArray(yd))
-                        scalar = jnp.mean(lval.data.astype(jnp.float32))
+                    # for the backward, transpose(jvp(fwd)); the casts of
+                    # the masters are the forward's too
+                    with jax.named_scope("fwd"):
+                        for i, (p, v) in enumerate(zip(pnds, pv)):
+                            # half-precision compute on fp32 masters; the
+                            # cast's vjp upcasts cotangents, so grads come
+                            # back fp32. Non-trainable params (BN running
+                            # stats) stay fp32 — re-quantizing the running
+                            # statistic each step would defeat the
+                            # fp32-stat accumulation in batch_norm (AMP
+                            # rule: norm stats keep full precision)
+                            if cdtype is not None and trainable[i] and \
+                                    jnp.issubdtype(v.dtype, jnp.floating):
+                                v = v.astype(cdtype)
+                            p._data = v
+                        xin = xd
+                        if cdtype is not None and \
+                                jnp.issubdtype(xin.dtype, jnp.floating):
+                            xin = xin.astype(cdtype)
+                        with autograd.pause(train_mode=True), \
+                                mxrandom.key_provider(fwd_key):
+                            out = net.forward(NDArray(xin))
+                            if cdtype is not None:
+                                out = NDArray(out.data.astype(jnp.float32))
+                            # called through .forward: no block's scope
+                            with jax.named_scope("loss"):
+                                lval = loss.forward(out, NDArray(yd))
+                                scalar = jnp.mean(
+                                    lval.data.astype(jnp.float32))
                     mut = {str(i): p._data for i, (p, v) in
                            enumerate(zip(pnds, pv)) if p._data is not v}
                     return scalar, mut
@@ -497,6 +503,8 @@ class SPMDTrainer:
         _COUNTERS.add("steps")
         _COUNTERS.add("placed_bytes", nbytes)
         self._publish_stats()
+        if self._scopes_due:
+            self._publish_scopes(xd, yd)
         return NDArray(lval)
 
     def _place(self, x, y):
@@ -510,6 +518,25 @@ class SPMDTrainer:
         if stats:
             self._stats_pending.append(stats)
         return lval
+
+    def _step_text(self, xd, yd):
+        """The compiled step's optimised HLO text. Once the step has run
+        with these shapes, lowering and compiling again find JAX's cached
+        executable: nothing is traced or compiled a second time."""
+        return _cc.aot_compile(self._compiled, self._param_vals,
+                               self._states, self._aux, xd, yd).as_text()
+
+    def _publish_scopes(self, xd, yd):
+        """Once a build, after the first call of the compiled step has
+        returned: the table from the step's instructions to the scopes
+        they were traced under (``telemetry.scopes``), for whoever splits
+        a device trace of the step by block and by phase."""
+        self._scopes_due = False
+        with _telem.span("spmd.build.scopes", cat="train") as sp:
+            text = self._step_text(xd, yd)
+            table = _scopes.parse(text)
+            _scopes.publish("spmd_step", table)
+            sp.set(instructions=len(table), bytes=len(text))
 
     def _publish_stats(self):
         """Hand the steps' published state whose arrays the device has
@@ -533,10 +560,7 @@ class SPMDTrainer:
         """Optimised HLO text of the step executable for this batch:
         where to look for the collectives XLA inserted over the mesh."""
         self._ensure_built(x, y)
-        xd, yd = self._place(x, y)
-        return self._compiled.lower(
-            self._param_vals, self._states, self._aux, xd,
-            yd).compile().as_text()
+        return self._step_text(*self._place(x, y))
 
     def sync_params_to_gluon(self):
         """Write the device-resident values back into the gluon Parameters

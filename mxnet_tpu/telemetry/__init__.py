@@ -9,7 +9,7 @@ through batcher/session/state-store threads, and every counter family
 in the process — training and serving — reads and scrapes from one
 registry.
 
-Three pieces, importable à la carte:
+Four pieces, importable à la carte:
 
 - :mod:`.tracer` — ``span()`` / ``instant()`` / ``trace_context()``,
   ``MXNET_TELEMETRY={0,1,2}``-gated (default 1), bounded drop-oldest
@@ -19,6 +19,9 @@ Three pieces, importable à la carte:
   Prometheus exposition for training and serving.
 - :mod:`.exporter` — ``dump_trace(path)``: Chrome-trace/Perfetto JSON
   of spans + thread names + registry counter samples.
+- :mod:`.scopes` — the table from a compiled program's instructions to
+  the scopes they were traced under, which splits a device trace by
+  block and by phase.
 
 ``profiler`` keeps its MXNet-parity surface (``set_config`` /
 ``dump`` / ``dumps`` / ``*_counters()``) as thin views over this
@@ -38,6 +41,7 @@ from .metrics import (REGISTRY, CounterFamily, MetricsRegistry,
                       counter_family, family_snapshot, prometheus_text,
                       register_exposition, register_family, snapshot)
 from .exporter import build_trace, counter_samples, dump_trace
+from . import scopes
 
 __all__ = [
     # tracer
@@ -51,4 +55,6 @@ __all__ = [
     "snapshot", "prometheus_text",
     # exporter
     "build_trace", "counter_samples", "dump_trace",
+    # instruction -> scope tables of compiled programs
+    "scopes",
 ]

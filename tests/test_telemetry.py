@@ -412,6 +412,15 @@ def test_step_hlo_carries_forward_backward_and_update_scopes():
     assert any("jit(spmd_step)/jvp(fwd)/" in n for n in names)
     assert any("jit(spmd_step)/transpose(jvp(fwd))/" in n for n in names)
     assert any("jit(spmd_step)/update/" in n for n in names)
+    # and below the phase the scope of every block, by the name its
+    # parent gave it, and the loss's
+    for scope in ("jvp(fwd)/0/", "jvp(fwd)/1/", "transpose(jvp(fwd))/0/",
+                  "transpose(jvp(fwd))/1/", "jvp(fwd)/loss/",
+                  "transpose(jvp(fwd))/loss/"):
+        assert any("jit(spmd_step)/" + scope in n for n in names), scope
+    # the text is the table's source: the step's scopes are published
+    table = telemetry.scopes.table("spmd_step")
+    assert {e["op_name"] for e in table.values()} <= names | {""}
 
 
 def test_prefetch_stage_span_carries_the_bytes_it_placed(monkeypatch):
