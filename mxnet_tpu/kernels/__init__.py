@@ -48,7 +48,10 @@ def counters():
     what each kernel file counts of its own lowerings at trace time
     (``flash_*``, ``moe_gmm_*``; ``gdn_pallas`` / ``gdn_plain``: which
     lowering a trace of the gated delta rule took, ``gdn_chunks``: the
-    chunks a head its passes walk; ``qk_prologue_pallas`` /
+    chunks a head its passes walk; ``ssm_scan_pallas`` /
+    ``ssm_scan_plain``: which lowering a trace of the selective scan
+    took, ``ssm_scan_chunks``: the chunks a channel tile its passes walk,
+    ``kernels/selective_scan.py``; ``qk_prologue_pallas`` /
     ``qk_prologue_plain``: which lowering each attention layer's q/k
     norms, RoPE and layout took, ``kernels/qk_prologue.py``;
     ``pick_masked``: the ``pick`` ops a trace lowered as a masked sum,

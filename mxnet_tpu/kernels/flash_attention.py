@@ -694,11 +694,11 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S_q, D = q.shape
-    S_k = k.shape[2]
+    S_k, Dv = k.shape[2], v.shape[3]
     group = H // k.shape[1]
     visits = strips = ()
     if bq is None or bk is None:
-        bq, bk = choose_tiles(S_q, S_k, D, q.dtype.itemsize)
+        bq, bk = choose_tiles(S_q, S_k, max(D, Dv), q.dtype.itemsize)
     pq = (-S_q) % bq
     pk = (-S_k) % bk
     Sq_p, Sk_p = S_q + pq, S_k + pk
@@ -734,23 +734,28 @@ def _pallas_forward(q, k, v, sm_scale, causal, interpret, with_lse=False,
         return b, h, kb, 0
 
     # one head a grid row, its block's two leading dimensions squeezed:
-    # the kernel sees (bq, D), (bk, D) and the (1, bq) row of lse
-    q_spec = pl.BlockSpec((None, None, bq, D),
-                          lambda b, h, *at: (b, h, tile(*at)[0], 0))
+    # the kernel sees (bq, D), (bk, D), v's (bk, Dv), o's (bq, Dv) and the
+    # (1, bq) row of lse
+    def rows_spec(width):
+        return pl.BlockSpec((None, None, bq, width),
+                            lambda b, h, *at: (b, h, tile(*at)[0], 0))
+
+    q_spec = rows_spec(D)
     kv_spec = pl.BlockSpec((None, None, bk, D), kv_map)
-    out_specs = [q_spec]
-    out_shape = [jax.ShapeDtypeStruct((B, H, Sq_p, D), q.dtype)]
+    v_spec = pl.BlockSpec((None, None, bk, Dv), kv_map)
+    out_specs = [rows_spec(Dv)]
+    out_shape = [jax.ShapeDtypeStruct((B, H, Sq_p, Dv), q.dtype)]
     if with_lse:
         out_specs.append(pl.BlockSpec(
             (None, None, 1, bq), lambda b, h, *at: (b, h, 0, tile(*at)[0])))
         out_shape.append(jax.ShapeDtypeStruct((B, H, 1, Sq_p), jnp.float32))
     grid = (B, H, visits[0].size) if visits else (B, H, Sq_p // bq, nk)
-    in_specs = [q_spec, kv_spec, kv_spec]
+    in_specs = [q_spec, kv_spec, v_spec]
     out_specs = out_specs if with_lse else out_specs[0]
     scratch = [
         pltpu.VMEM((bq, 1), jnp.float32),
         pltpu.VMEM((bq, 1), jnp.float32),
-        pltpu.VMEM((bq, D), jnp.float32),
+        pltpu.VMEM((bq, Dv), jnp.float32),
     ]
     if visits:
         how = {"grid_spec": pltpu.PrefetchScalarGridSpec(
@@ -913,10 +918,11 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S_q, D = q.shape
-    S_k = k.shape[2]
+    S_k, Dv = k.shape[2], v.shape[3]
     group = H // k.shape[1]
     if bq is None or bk is None:
-        bq, bk, chosen = choose_backward(S_q, S_k, D, q.dtype.itemsize)
+        bq, bk, chosen = choose_backward(S_q, S_k, max(D, Dv),
+                                         q.dtype.itemsize)
         rows = rows or chosen
     pq = (-S_q) % bq
     pk = (-S_k) % bk
@@ -983,10 +989,13 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
         b, h, *_, block = ids(args)
         return (b, h, *block, 0)
 
-    q_spec = pl.BlockSpec((None, None, bq, D),
-                          lambda *args: (*_live_i(args), 0))
+    def q_spec(width):
+        return pl.BlockSpec((None, None, bq, width),
+                            lambda *args: (*_live_i(args), 0))
+
     row_spec = pl.BlockSpec((None, None, 1, bq), row_map)
     kv_spec = pl.BlockSpec((None, None, bk, D), kv_map)
+    v_spec = pl.BlockSpec((None, None, bk, Dv), kv_map)
     # dk, dv: a block for every sweep of q tiles. Without a spec a grid
     # step's indices but the sweep's say which; a spec's visits give the
     # blocks of all segments' bands one after another
@@ -996,16 +1005,19 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
     else:
         grid = (B, H) + ((nseg,) if nseg > 1 else ()) + (nk, nq)
         dkv_shape = (B, H) + ((nseg,) if nseg > 1 else ()) + (nk * bk, D)
+    dv_shape = dkv_shape[:-1] + (Dv,)
     dkv_spec = pl.BlockSpec((None,) * (len(dkv_shape) - 2) + (bk, D), dkv_map)
-    in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+    dv_spec = pl.BlockSpec((None,) * (len(dv_shape) - 2) + (bk, Dv),
+                           dkv_map)
+    in_specs = [q_spec(D), kv_spec, v_spec, q_spec(Dv), row_spec, row_spec]
     out_specs = [
         pl.BlockSpec((None, None, rows, D),
                      lambda *args: (args[0], args[1], ids(args)[2], 0)),
-        dkv_spec, dkv_spec,
+        dkv_spec, dv_spec,
     ]
     scratch = [
         pltpu.VMEM((bk, D), jnp.float32),
-        pltpu.VMEM((bk, D), jnp.float32),
+        pltpu.VMEM((bk, Dv), jnp.float32),
     ]
     if visits:
         how = {"grid_spec": pltpu.PrefetchScalarGridSpec(
@@ -1019,7 +1031,7 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq_p, D), jnp.float32),
             jax.ShapeDtypeStruct(dkv_shape, k.dtype),
-            jax.ShapeDtypeStruct(dkv_shape, v.dtype),
+            jax.ShapeDtypeStruct(dv_shape, v.dtype),
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             ("parallel", "parallel", "arbitrary") if visits else
@@ -1031,12 +1043,13 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, interpret,
     )(*visits, _pad_rows(q, pq), _pad_rows(k, pk), _pad_rows(v, pk),
       _pad_rows(do, pq), lse, delta)  # zero do: padded rows add nothing
     if nseg > 1:
-        dk, dv = (_sum_segments(a.reshape(B, H, nseg, band * bk, D), group,
-                                first, bk, S_k) for a in (dk, dv))
+        dk, dv = (_sum_segments(a.reshape(B, H, nseg, band * bk,
+                                          a.shape[-1]), group, first, bk,
+                                S_k) for a in (dk, dv))
     else:
         dk, dv = dk[:, :, :S_k], dv[:, :, :S_k]
         if group > 1:
-            dk, dv = (a.reshape(B, H // group, group, S_k, D)
+            dk, dv = (a.reshape(B, H // group, group, S_k, a.shape[-1])
                       .astype(jnp.float32).sum(2).astype(a.dtype)
                       for a in (dk, dv))
     return dq[:, :, :S_q].astype(q.dtype), dk, dv
@@ -1116,7 +1129,8 @@ def _flash(q, k, v, sm_scale, causal, impl, mask=None):
 
 def _flash_fwd(q, k, v, sm_scale, causal, impl, mask=None):
     fits = impl != "xla" and choose_tiles(
-        q.shape[2], k.shape[2], q.shape[3], q.dtype.itemsize, backward=True)
+        q.shape[2], k.shape[2], max(q.shape[3], v.shape[3]),
+        q.dtype.itemsize, backward=True)
     if not fits:  # the scan backward recomputes from q, k, v alone
         return (_flash(q, k, v, sm_scale, causal, impl, mask),
                 (q, k, v, None, None))
@@ -1141,7 +1155,8 @@ def _scan_backward(q, k, v, do, sm_scale, causal):
     nchunk = (S_q + pad) // chunk
     B, H, _, D = q.shape
     qc = qp.reshape(B, H, nchunk, chunk, D).transpose(2, 0, 1, 3, 4)
-    doc = dop.reshape(B, H, nchunk, chunk, D).transpose(2, 0, 1, 3, 4)
+    doc = dop.reshape(B, H, nchunk, chunk, v.shape[3]).transpose(
+        2, 0, 1, 3, 4)
     kid = jnp.arange(S_k)[None, :]
     off = S_k - S_q  # bottom-right causal alignment
 
@@ -1191,7 +1206,9 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q, k, v, sm_scale=None, causal=False, use_pallas=None,
                     mask=None):
     """Scaled dot-product attention over (B, H, S, D) tensors; k and v
-    may hold H / group heads, query head h then reads head h // group.
+    may hold H / group heads, query head h then reads head h // group,
+    and v's heads may be wider than q's and k's (the result is v's
+    width).
 
     use_pallas: None = pallas on TPU / XLA elsewhere; True forces the
     kernel (interpreted off-TPU — slow, for testing); False forces XLA.

@@ -9,7 +9,11 @@ from .transformer import TransformerLM, TransformerBlock, \
 from .decoder import DecoderBlockLM
 from .moe_decoder import MoEDecoderLM, MoEDecoderBlock, \
     GroupedQueryAttention, GatedDeltaNet
+from .samba_y import SambaYLM, SambaYBlock, MambaMixer, \
+    DifferentialAttention, GatedMemoryUnit
 
 __all__ = ["vision", "get_model", "TransformerLM", "TransformerBlock",
            "MultiHeadSelfAttention", "DecoderBlockLM", "MoEDecoderLM",
-           "MoEDecoderBlock", "GroupedQueryAttention", "GatedDeltaNet"]
+           "MoEDecoderBlock", "GroupedQueryAttention", "GatedDeltaNet",
+           "SambaYLM", "SambaYBlock", "MambaMixer", "DifferentialAttention",
+           "GatedMemoryUnit"]

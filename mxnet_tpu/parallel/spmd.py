@@ -355,15 +355,25 @@ class SPMDTrainer:
             pass
         init_ctx = (jax.default_device(cpu) if cpu is not None
                     else contextlib.nullcontext())
-        with _telem.span("spmd.build.init_forward", cat="train"), \
+        # the forward finishes deferred shapes and writes the state of
+        # layers that keep one (BatchNorm's running statistics, TopKMoE's
+        # rows per expert: grad_req 'null'). A net whose every parameter
+        # holds its array and that keeps no such state gains nothing from
+        # it, and on the host it would only cost time (a selective scan
+        # or a dense attention of 8,192 positions there)
+        pending = any(p._ndarray is None or p.grad_req == "null"
+                      for p in net.collect_params().values())
+        with _telem.span("spmd.build.init_forward", cat="train",
+                         ran=pending), \
                 init_ctx, autograd.pause(train_mode=True):
             xs = x
-            if getattr(x, "shape", None) and x.shape:
+            if pending and getattr(x, "shape", None) and x.shape:
                 # fresh 1-sample batch, created INSIDE that scope: the
                 # init forward never touches the caller's full batch
                 xs = nd.array(onp.zeros((1,) + tuple(x.shape[1:]),
                                         dtype=str(x.dtype)))
-            net.forward(xs)
+            if pending:
+                net.forward(xs)
         self._params = [p for _, p in sorted(net.collect_params().items())]
         names = [p.name for p in self._params]
         trainable = [p.grad_req != "null" for p in self._params]

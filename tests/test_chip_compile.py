@@ -14,6 +14,7 @@ never in conftest, never autouse), and every compile happens in the
 test's own process with the persistent compilation cache off (a
 described-chip executable can be written to it but not read back).
 """
+import json
 import os
 import re
 
@@ -448,6 +449,61 @@ def test_the_sdar_cells_step_takes_the_prologue_kernels(one_chip,
     assert after.get("qk_prologue_plain", 0) == before.get(
         "qk_prologue_plain", 0)
     assert c.memory_analysis().temp_size_in_bytes <= 6_556_542_976
+
+
+def test_the_phi4flash_cells_step_fits_and_takes_the_scan_kernels(
+        one_chip, monkeypatch):
+    """``SPMDTrainer``'s step of the cell phi4flash3.8b-train-s8192 at its
+    own depth (6 layers: Mamba, window 512, Mamba, full, GMU, cross),
+    widths, vocabulary slice (25,008 rows) and length (1 x 8,192),
+    built by the benchmark's own ``build_net`` and loss block, compiled for
+    the chip: both Mamba layers' scans are the kernel pair (counted, no
+    twin; 32 chunks a pass) under the names ``ssm_scan_ms.tokens`` reads,
+    the flash kernels beside them, and the step's temporaries stay under
+    4.1 GB (3,868,037,120 B as built; 7,418,326,528 before the MLP and the
+    attention maps ran again in the backward pass), so that with the
+    11.43 GB resident the cell holds at most 92% of the chip's 16.9.
+    Plain SGD: Adam's two moments would double the host memory of this
+    test and change no temporary. The trainer is built on a short batch:
+    its parameters do not depend on the length."""
+    import importlib.util
+
+    import numpy as onp
+
+    from mxnet_tpu import parallel
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    spec = importlib.util.spec_from_file_location(
+        "chip_compile_phi4flash",
+        os.path.join(bench, "models", "phi4-mini-flash-3.8b.py"))
+    model = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(model)
+    with open(os.path.join(bench, "configs",
+                           "phi4-mini-flash-3.8b.json")) as f:
+        cfg = json.load(f)
+    net = model.build_net(cfg)
+    net.initialize()
+    trainer = parallel.SPMDTrainer(
+        net, model.loss_block(cfg), optimizer="sgd",
+        optimizer_params={"learning_rate": 1e-7},
+        mesh=parallel.make_mesh({"dp": 1}, devices=jax.devices()[:1]),
+        compute_dtype="bfloat16")
+    short = onp.zeros((1, 256), "int32")
+    trainer._ensure_built(short, short)
+    before = kernels.counters()
+    tokens = onp.zeros((1, 8192), "int32")
+    c = _compile_trainer_step(trainer, tokens, tokens, one_chip,
+                              monkeypatch)
+    for name in ("ssm_scan_fwd", "ssm_scan_bwd", "flash_fwd", "flash_bwd"):
+        _assert_kernel(c, name)
+    after = kernels.counters()
+    assert after["ssm_scan_pallas"] - before.get("ssm_scan_pallas", 0) == 2
+    assert after.get("ssm_scan_plain", 0) == before.get("ssm_scan_plain", 0)
+    assert after["ssm_scan_chunks"] - before.get("ssm_scan_chunks", 0) \
+        == 2 * 2 * 8192 // 256
+    assert after.get("flash_bwd_scan", 0) == before.get("flash_bwd_scan", 0)
+    assert c.memory_analysis().temp_size_in_bytes < 4.1e9
 
 
 @pytest.mark.parametrize("b,s,v", [

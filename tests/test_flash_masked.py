@@ -365,6 +365,51 @@ def test_causal_lowers_without_tables():
         assert eqn.params["grid_mapping"].num_index_operands == 0
 
 
+@pytest.mark.parametrize("how", ["causal", "window"])
+@pytest.mark.parametrize("dv", [64, 128])
+def test_value_width_reaches_only_the_value_sized_blocks(how, dv):
+    """A value head of ``Dv`` widens v, o, do, dv and their float32
+    scratch alone; q, k, dq, dk keep D = 64. At ``Dv == D``, every call
+    of the four older cells, each block is the width it had before wide
+    value heads existed."""
+    d = 64
+    q = jax.ShapeDtypeStruct((1, 4, 1024, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 2, 1024, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 2, 1024, dv), jnp.bfloat16)
+    kw = ({"causal": True} if how == "causal"
+          else {"mask": fa.SlidingWindowMask(1024, 256)})
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(
+            q, k, v, use_pallas=True, **kw).astype(jnp.float32).sum(),
+        (0, 1, 2)))(q, k, v)
+    calls = {}
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls[eqn.params["name"]] = eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+
+    def widths(eqn):
+        gm = eqn.params["grid_mapping"]
+        blocks = [bm.block_aval.shape[-1] for bm in gm.block_mappings]
+        scratch = [a.shape[-1] for a in gm.scratch_avals]
+        return blocks, scratch
+
+    # q, k, v in; o and the lse row out; (bq, 1) x 2 and o's accumulator
+    blocks, scratch = widths(calls["flash_fwd"])
+    assert blocks[:4] == [d, d, dv, dv] and scratch == [1, 1, dv]
+    # q, k, v, do, lse, delta in; dq, dk, dv out; dk's and dv's
+    # accumulators
+    blocks, scratch = widths(calls["flash_bwd"])
+    assert [blocks[i] for i in (0, 1, 2, 3, 6, 7, 8)] == \
+        [d, d, dv, dv, d, d, dv]
+    assert scratch == [d, dv]
+
+
 def test_what_the_entry_refuses():
     q = jnp.zeros((1, 4, 256, 32))
     kv = jnp.zeros((1, 2, 256, 32))

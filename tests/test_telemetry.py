@@ -320,6 +320,41 @@ def test_spmd_step_nests_place_and_launch_and_build_appears_once(
     assert "mxnet_spmd_steps" in telemetry.prometheus_text()
 
 
+@pytest.mark.parametrize("with_state", [False, True])
+def test_init_forward_runs_only_where_it_finishes_something(monkeypatch,
+                                                           with_state):
+    """Every array set before the trainer is built: the deferred-init
+    forward still runs where the net keeps state the forward writes
+    (BatchNorm's running variance moves once on the zeros batch, 1 ->
+    0.9) and is skipped where it has nothing to finish (span argument
+    ``ran``)."""
+    import jax
+
+    from mxnet_tpu import gluon, parallel
+
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    net = nn.HybridSequential()
+    net.add(nn.Dense(16, activation="relu"))
+    if with_state:
+        net.add(nn.BatchNorm())
+    net.add(nn.Dense(4))
+    net.initialize()
+    with autograd.pause(train_mode=False):
+        net(nd.zeros((1, 10)))
+    trainer = parallel.SPMDTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(),
+        mesh=parallel.make_mesh({"dp": 1}, devices=jax.devices()[:1]))
+    telemetry.reset_trace()
+    trainer._ensure_built(nd.zeros((8, 10)), nd.zeros((8,)))
+    (init_fwd,) = _named("spmd.build.init_forward")
+    assert init_fwd["args"]["ran"] is with_state
+    variances = [p.data().asnumpy() for name, p in
+                 net.collect_params().items() if name.endswith("running_var")]
+    assert len(variances) == int(with_state)
+    for var in variances:
+        assert onp.allclose(var, 0.9)
+
+
 def test_set_data_on_a_deferred_parameter_marks_the_init_discarded(
         monkeypatch):
     monkeypatch.setenv("MXNET_TELEMETRY", "1")
