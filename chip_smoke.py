@@ -13,6 +13,9 @@ weights from a fixed seed:
            int8 fully-connected lowering against the dequant one.
   prologue the q/k norms, RoPE and layout of grouped-query attention as
            their kernel pair against the plain twin.
+  delta_prologue
+           the causal convolution, SiLU, l2 norms and layout before the
+           gated delta rule as their kernel pair against the plain twin.
 
 ``--chips 4`` runs ONLY the data-parallel phase and what it is compared
 with (same seed, same global batch, one-device mesh vs dp=4 mesh).
@@ -643,6 +646,62 @@ def phase_prologue(cfg):
     assert kernels.counters().get("qk_prologue_pallas", 0) > 0
 
 
+def phase_delta_prologue(cfg):
+    """The prologue of a Gated DeltaNet layer (``kernels/delta_prologue.py``:
+    the causal convolution, SiLU, the l2 norms and the layout the delta
+    rule reads) as its kernel pair against the plain twin: q, k, v and
+    the VJP to ``qkvz`` and the convolution's weight, each to ``tol`` of
+    the twin's largest element; float32 at a small shape over three
+    tiles of positions to rounding, then bfloat16 over (1, 8192) of 16
+    key and 32 value heads of 128, the Qwen3-Next cell's linear layer,
+    to bfloat16's (the twin's backward rounds each tap's cotangent, the
+    kernels once). The kernels are forced (``use_pallas=True``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import kernels
+    from mxnet_tpu.kernels.delta_prologue import delta_prologue
+
+    on_chip = not cfg.rehearse
+    rs = onp.random.RandomState(SEED + 6)
+
+    def randn(dtype, *shape):
+        return jnp.asarray(rs.randn(*shape).astype("f"), dtype)
+
+    def check(dtype, b, s, hk, hv, d, tol):
+        qkvz = randn(dtype, b, s, 2 * (hk + hv) * d)
+        conv_w = 0.5 * randn(jnp.float32, 4, (2 * hk + hv) * d).astype(dtype)
+        cot = (randn(dtype, b, hk, s, d), randn(dtype, b, hk, s, d),
+               randn(dtype, b, hv, s, d))
+
+        def run(pallas):
+            def f(qkvz, conv_w, cot):
+                out, vjp = jax.vjp(lambda *a: delta_prologue(
+                    *a, hk, hv, d, d, use_pallas=pallas), qkvz, conv_w)
+                return out + vjp(cot)
+            return jax.jit(f)
+
+        got = run(True)(qkvz, conv_w, cot)
+        if on_chip:
+            assert "tpu_custom_call" in run(True).lower(
+                qkvz, conv_w, cot).as_text()
+        want = run(False)(qkvz, conv_w, cot)
+        for name, a, w in zip(("q", "k", "v", "dqkvz", "dconv_w"), got,
+                              want):
+            a, w = (jnp.asarray(x, jnp.float32) for x in (a, w))
+            err = float(jnp.abs(a - w).max() / jnp.abs(w).max())
+            log(f"delta prologue {jnp.dtype(dtype).name} "
+                f"{(b, s, hk, hv, d)} {name} max |pallas - plain| = "
+                f"{err:.3e} of the largest (tol {tol})")
+            assert bool(jnp.isfinite(a).all()) and err < tol, (name, err)
+
+    check(jnp.float32, 2, 384, 2, 4, 128, 1e-5)
+    if cfg.rehearse:
+        return
+    check(jnp.bfloat16, 1, 8192, 16, 32, 128, 2e-2)
+    assert kernels.counters().get("delta_prologue_pallas", 0) > 0
+
+
 def phase_dp4(cfg):
     """Data-parallel training over four chips against the same steps on
     one: same seed, same global batch.
@@ -728,7 +787,8 @@ def main(argv=None):
 
     one_chip = {"train": phase_train, "serve": phase_serve,
                 "kernels": phase_kernels, "masked": phase_masked,
-                "prologue": phase_prologue}
+                "prologue": phase_prologue,
+                "delta_prologue": phase_delta_prologue}
     todo = {"dp4": phase_dp4} if args.chips == 4 else one_chip
     if args.phases:
         todo = {n: todo[n] for n in args.phases.split(",")}

@@ -54,8 +54,10 @@ def counters():
     ``kernels/selective_scan.py``; ``qk_prologue_pallas`` /
     ``qk_prologue_plain``: which lowering each attention layer's q/k
     norms, RoPE and layout took, ``kernels/qk_prologue.py``;
-    ``pick_masked``: the ``pick`` ops a trace lowered as a masked sum,
-    ``ndarray/ops_index.py``)."""
+    ``delta_prologue_pallas`` / ``delta_prologue_plain``: which lowering
+    each Gated DeltaNet layer's convolution, l2 norms and layout took,
+    ``kernels/delta_prologue.py``; ``pick_masked``: the ``pick`` ops a
+    trace lowered as a masked sum, ``ndarray/ops_index.py``)."""
     return _COUNTERS.snapshot()
 
 

@@ -165,12 +165,13 @@ class GatedDeltaNet(HybridBlock):
     DeltaNet, Yang et al., arXiv:2412.06464), no bias: one fused
     projection to q | k | v | z and one to b | a (a value head each);
     q | k | v pass a causal depthwise convolution over ``conv_kernel``
-    positions (shifted multiply-adds) and SiLU; q and k are l2-normalised
-    a head, q scaled by ``head_k_dim ** -0.5``; ``beta = sigmoid(b)``,
-    ``g = -exp(A_log) * softplus(a + dt_bias)`` in float32; the rule
-    (``kernels.gated_delta.gated_delta_rule``, key head j serving the
-    value heads from ``j * num_v_heads / num_k_heads`` on); an RMSNorm
-    over each head's result times ``silu(z)``; the output projection."""
+    positions and SiLU; q and k are l2-normalised a head, q scaled by
+    ``head_k_dim ** -0.5`` (``kernels.delta_prologue``); ``beta =
+    sigmoid(b)``, ``g = -exp(A_log) * softplus(a + dt_bias)`` in
+    float32; the rule (``kernels.gated_delta.gated_delta_rule``, key
+    head j serving the value heads from ``j * num_v_heads /
+    num_k_heads`` on); an RMSNorm over each head's result times
+    ``silu(z)``; the output projection."""
 
     def __init__(self, embed_dim, num_k_heads, num_v_heads, head_k_dim,
                  head_v_dim, conv_kernel=4, epsilon=1e-6, chunk=64,
@@ -202,45 +203,22 @@ class GatedDeltaNet(HybridBlock):
         from ..ndarray.registry import apply_pure
 
         hk, hv, dk, dv = self._hk, self._hv, self._dk, self._dv
-        taps, eps, chunk = self._taps, self._eps, self._chunk
+        eps, chunk = self._eps, self._chunk
 
         def pure(qkvz, ba, conv_w, a_log, dt_bias, gamma):
             import jax
             import jax.numpy as jnp
-            from jax import lax
 
             from ..gluon.nn.basic_layers import rms_norm
+            from ..kernels import delta_prologue as dp
             from ..kernels.gated_delta import gated_delta_rule
 
             b, s, _ = qkvz.shape
             kd, f32 = hk * dk, jnp.float32
 
-            def unit(a):    # (B, S, H, d) float32, l2-normalised a head
-                return a * lax.rsqrt(
-                    jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6)
-
-            # Both sides of the rule are element-wise work in float32 on
-            # 8,192 channels a position: recomputed in the backward pass
-            # from the projection's result, which is kept anyway.
-            @jax.checkpoint
-            def before(qkvz, conv_w):
-                # y_t = sum_j w_j x_(t - (taps - 1) + j): the last tap is
-                # the position's own
-                with jax.named_scope("conv"):
-                    mixed = qkvz[..., :2 * kd + hv * dv]
-                    padded = jnp.pad(mixed,
-                                     ((0, 0), (taps - 1, 0), (0, 0)))
-                    conv = jax.nn.silu(sum(
-                        padded[:, j:j + s].astype(f32)
-                        * conv_w[j].astype(f32) for j in range(taps)))
-                with jax.named_scope("l2norm"):
-                    q = unit(conv[..., :kd].reshape(b, s, hk, dk)) \
-                        * dk ** -0.5
-                    k = unit(conv[..., kd:2 * kd].reshape(b, s, hk, dk))
-                v = conv[..., 2 * kd:].reshape(b, s, hv, dv)
-                return tuple(a.astype(qkvz.dtype).transpose(0, 2, 1, 3)
-                             for a in (q, k, v))
-
+            # the gated norm: element-wise work in float32, recomputed in
+            # the backward pass from the projection's result, which is
+            # kept anyway
             @jax.checkpoint
             def after(o, qkvz, gamma):
                 with jax.named_scope("gate_norm"):
@@ -249,7 +227,9 @@ class GatedDeltaNet(HybridBlock):
                     o = o.astype(f32) * jax.nn.silu(z.astype(f32))
                     return o.astype(qkvz.dtype).reshape(b, s, hv * dv)
 
-            q, k, v = before(qkvz, conv_w)
+            # the convolution, SiLU, the l2 norms and (B, H, S, d): one
+            # kernel pair on the chip (kernels/delta_prologue.py)
+            q, k, v = dp.delta_prologue(qkvz, conv_w, hk, hv, dk, dv)
             beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
             g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
                 ba[..., hv:].astype(f32) + dt_bias.astype(f32))

@@ -386,14 +386,55 @@ def test_gated_delta_layers_keep_their_names_inside_the_trainers_step(
     before = kernels.counters()
     c = _compile_trainer_step(trainer, x, y, one_chip, monkeypatch)
     for name in ("gdn_prep_fwd", "gdn_fwd", "gdn_prep_refwd", "gdn_bwd",
-                 "gdn_prep_bwd", "flash_fwd", "flash_bwd", "moe_gmm_fwd"):
+                 "gdn_prep_bwd", "flash_fwd", "flash_bwd", "moe_gmm_fwd",
+                 "delta_prologue_fwd", "delta_prologue_bwd"):
         _assert_kernel(c, name)
     after = kernels.counters()
     for name in ("gdn_pallas", "flash_bwd_pallas", "moe_gmm_pallas"):
         assert after.get(name, 0) > before.get(name, 0), name
-    for name in ("gdn_plain", "flash_bwd_scan", "moe_gmm_plain"):
+    for name in ("gdn_plain", "flash_bwd_scan", "moe_gmm_plain",
+                 "delta_prologue_plain"):
         assert after.get(name, 0) == before.get(name, 0), name
     assert after["gdn_chunks"] - before.get("gdn_chunks", 0) == 2 * 4
+    assert after["delta_prologue_pallas"] - before.get(
+        "delta_prologue_pallas", 0) == 1
+    # the convolution and the norms are the kernels' alone: no op of the
+    # step was traced under the plain twin's scopes, float32 or other
+    text = c.as_text()
+    assert not re.search(r'op_name="[^"]*/(conv|l2norm)/', text)
+
+
+def test_delta_prologue_kernels_compile(one_chip, monkeypatch):
+    """The Gated DeltaNet prologue of the cell qwen3next80b-train-s8192
+    at its shape, ``qkvz`` (1, 8192, 12288) bf16 of 16 key and 32 value
+    heads of 128, value and gradient in both inputs: ``delta_prologue_fwd``
+    and ``delta_prologue_bwd`` under the names ``delta_prologue_ms.tokens``
+    reads, at the tiles the budget gives (1,024 positions of 2 heads),
+    counted once as kernels, and no (8192, 8192) float32 array in the
+    program, where the XLA passes held several."""
+    from mxnet_tpu.kernels import delta_prologue as dp
+
+    b, s, hk, hv, d = 1, 8192, 16, 32, 128
+    assert dp.tiles(s, dp.Layout(hk, hv, d, d, 4), 2) == (1024, 2)
+
+    def loss(qkvz, conv_w):
+        q, k, v = dp.delta_prologue(qkvz, conv_w, hk, hv, d, d)
+        return sum(a.astype(jnp.float32).sum() for a in (q, k, v))
+
+    before = kernels.counters()
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        c = _compile(jax.value_and_grad(loss, (0, 1)), one_chip,
+                     ((b, s, 2 * (hk + hv) * d), jnp.bfloat16),
+                     ((4, (2 * hk + hv) * d), jnp.bfloat16))
+    _assert_kernel(c, "delta_prologue_fwd")
+    _assert_kernel(c, "delta_prologue_bwd")
+    assert "f32[1,8192,8192]" not in c.as_text()
+    after = kernels.counters()
+    assert after["delta_prologue_pallas"] == before.get(
+        "delta_prologue_pallas", 0) + 1
+    assert after.get("delta_prologue_plain", 0) == before.get(
+        "delta_prologue_plain", 0)
 
 
 def test_the_sdar_cells_step_takes_the_prologue_kernels(one_chip,
@@ -448,6 +489,7 @@ def test_the_sdar_cells_step_takes_the_prologue_kernels(one_chip,
         "qk_prologue_pallas", 0) == 4
     assert after.get("qk_prologue_plain", 0) == before.get(
         "qk_prologue_plain", 0)
+    assert "delta_prologue" not in c.as_text()      # no Gated DeltaNet
     assert c.memory_analysis().temp_size_in_bytes <= 6_556_542_976
 
 
@@ -503,6 +545,7 @@ def test_the_phi4flash_cells_step_fits_and_takes_the_scan_kernels(
     assert after["ssm_scan_chunks"] - before.get("ssm_scan_chunks", 0) \
         == 2 * 2 * 8192 // 256
     assert after.get("flash_bwd_scan", 0) == before.get("flash_bwd_scan", 0)
+    assert "delta_prologue" not in c.as_text()      # no Gated DeltaNet
     assert c.memory_analysis().temp_size_in_bytes < 4.1e9
 
 

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as onp
 import pytest
 
-from mxnet_tpu import autograd, models, nd
+from mxnet_tpu import autograd, kernels, models, nd
 from mxnet_tpu.gluon.contrib.nn import TopKMoE
 from mxnet_tpu.kernels import flash_attention as fa
 from mxnet_tpu.kernels.qk_prologue import rope
@@ -160,6 +160,59 @@ def test_gated_delta_net_matches_the_references_layer(ref):
                             if leaf.endswith(".w") else
                             onp.asarray(params["l0.attn." + leaf])))
     assert _rel(block(nd.array(onp.asarray(n))).data, want) < TOL
+
+
+def test_gated_delta_net_with_prologue_kernels_matches_the_reference(
+        ref, monkeypatch):
+    """The block with the convolution, SiLU, l2 norms and layout as the
+    kernel pair (``kernels/delta_prologue.py``, forced and interpreted;
+    heads of 128 lanes, a key head serving two value heads, 384 positions
+    in three tiles) against ``linear_layer``: the result, and the
+    gradient of every weight and of the input."""
+    from refcommon import Prec
+
+    from mxnet_tpu.kernels import delta_prologue as dp
+
+    cfg = dict(CFG, num_hidden_layers=1, linear_key_head_dim=128,
+               linear_value_head_dim=128)
+    params, _ = ref.init(cfg, jax.random.PRNGKey(13))
+    params = {k: v * 5 if v.ndim > 1 else v for k, v in params.items()}
+    n = jax.random.normal(jax.random.PRNGKey(14), (1, 384, 64))
+    cot = jax.random.normal(jax.random.PRNGKey(15), (1, 384, 64))
+    order = ["conv.w", "a_log", "dt_bias", "norm.gamma", "qkvz.w", "ba.w",
+             "out.w"]
+    leaves = {leaf: params["l0.attn." + leaf] for leaf in order}
+
+    def reference(n, leaves):
+        p = {"l0.attn." + k: v for k, v in leaves.items()}
+        return (ref.linear_layer(n, p, "l0", cfg, Prec("float32"))
+                * cot).sum()
+
+    want, (want_dn, want_dp) = jax.value_and_grad(reference, (0, 1))(
+        n, leaves)
+    block = models.GatedDeltaNet(64, 2, 4, 128, 128, conv_kernel=4)
+    block.initialize()
+    block(nd.array(onp.asarray(n)))
+    named = list(block.collect_params().values())
+    for p, leaf in zip(named, order):
+        value = onp.asarray(leaves[leaf])
+        p.set_data(nd.array(value.T if leaf.endswith(".w") else value))
+    before = kernels.counters().get("delta_prologue_pallas", 0)
+    monkeypatch.setattr(dp, "delta_prologue", functools.partial(
+        dp.delta_prologue, use_pallas=True))
+    x = nd.array(onp.asarray(n))
+    x.attach_grad()
+    with autograd.record():
+        total = (block(x) * nd.array(onp.asarray(cot))).sum()
+    total.backward()
+    assert kernels.counters()["delta_prologue_pallas"] > before
+    assert abs(float(total.asscalar()) - float(want)) < TOL * abs(float(want))
+    assert _rel(x.grad.data, want_dn) < TOL
+    for p, leaf in zip(named, order):
+        got = p.grad().data
+        tol = TOL_DECAY if leaf in ("a_log", "dt_bias") else TOL
+        assert _rel(got.T if leaf.endswith(".w") else got,
+                    want_dp[leaf]) < tol, leaf
 
 
 # ---------------------------------------------------------------------------
