@@ -16,6 +16,9 @@ weights from a fixed seed:
   delta_prologue
            the causal convolution, SiLU, l2 norms and layout before the
            gated delta rule as their kernel pair against the plain twin.
+  short_conv
+           LFM2's gated short convolution as its kernel pair against the
+           plain twin, and both timed alone at the LFM2 cell's shape.
 
 ``--chips 4`` runs ONLY the data-parallel phase and what it is compared
 with (same seed, same global batch, one-device mesh vs dp=4 mesh).
@@ -702,6 +705,72 @@ def phase_delta_prologue(cfg):
     assert kernels.counters().get("delta_prologue_pallas", 0) > 0
 
 
+def phase_short_conv(cfg):
+    """LFM2's gated short convolution (``kernels/short_conv.py``: the
+    gates and the causal depthwise convolution between the B|C|x and
+    the output projections) as its kernel pair against the plain twin: y
+    and the VJP to ``bcx`` and the convolution's weight, each to ``tol``
+    of the twin's largest element; float32 at a small shape over three
+    tiles of positions to rounding, then bfloat16 at (1, 8192) of 2,048
+    channels, the LFM2 cell's conv layer, to bfloat16's. There both are
+    timed alone (host clock, 20 calls back to back, forward and forward
+    with the VJP). The kernels are forced (``use_pallas=True``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import kernels
+    from mxnet_tpu.kernels.short_conv import short_conv
+
+    on_chip = not cfg.rehearse
+    rs = onp.random.RandomState(SEED + 7)
+
+    def randn(dtype, *shape):
+        return jnp.asarray(rs.randn(*shape).astype("f"), dtype)
+
+    def run(pallas, with_vjp=True):
+        def f(bcx, conv_w, dy):
+            y, vjp = jax.vjp(lambda *a: short_conv(*a, use_pallas=pallas),
+                             bcx, conv_w)
+            return (y,) + vjp(dy) if with_vjp else (y,)
+        return jax.jit(f)
+
+    def check(dtype, b, s, e, tol):
+        bcx = randn(dtype, b, s, 3 * e)
+        conv_w = 0.5 * randn(jnp.float32, 3, e).astype(dtype)
+        dy = randn(dtype, b, s, e)
+        got = run(True)(bcx, conv_w, dy)
+        if on_chip:
+            assert "tpu_custom_call" in run(True).lower(
+                bcx, conv_w, dy).as_text()
+        want = run(False)(bcx, conv_w, dy)
+        for name, a, w in zip(("y", "dbcx", "dconv_w"), got, want):
+            a, w = (jnp.asarray(x, jnp.float32) for x in (a, w))
+            err = float(jnp.abs(a - w).max() / jnp.abs(w).max())
+            log(f"short conv {jnp.dtype(dtype).name} {(b, s, e)} {name} "
+                f"max |pallas - plain| = {err:.3e} of the largest "
+                f"(tol {tol})")
+            assert bool(jnp.isfinite(a).all()) and err < tol, (name, err)
+        return bcx, conv_w, dy
+
+    check(jnp.float32, 2, 384, 256, 1e-5)
+    if cfg.rehearse:
+        return
+    args = check(jnp.bfloat16, 1, 8192, 2048, 2e-2)
+    assert kernels.counters().get("short_conv_pallas", 0) > 0
+    for pallas in (True, False):
+        for with_vjp in (False, True):
+            fn = run(pallas, with_vjp)
+            jax.block_until_ready(fn(*args))
+            t0 = time.perf_counter()
+            for _ in range(20):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            ms = (time.perf_counter() - t0) / 20 * 1e3
+            log(f"short conv alone, {'kernels' if pallas else 'twin'}, "
+                f"{'forward + VJP' if with_vjp else 'forward'}: "
+                f"{ms:.3f} ms a call")
+
+
 def phase_dp4(cfg):
     """Data-parallel training over four chips against the same steps on
     one: same seed, same global batch.
@@ -788,7 +857,8 @@ def main(argv=None):
     one_chip = {"train": phase_train, "serve": phase_serve,
                 "kernels": phase_kernels, "masked": phase_masked,
                 "prologue": phase_prologue,
-                "delta_prologue": phase_delta_prologue}
+                "delta_prologue": phase_delta_prologue,
+                "short_conv": phase_short_conv}
     todo = {"dp4": phase_dp4} if args.chips == 4 else one_chip
     if args.phases:
         todo = {n: todo[n] for n in args.phases.split(",")}

@@ -156,6 +156,21 @@ class TopKMoE(HybridBlock):
     the row buffer and the grouped products, added to the held experts'
     part (a layer that is one share of a group holds it whole).
 
+    ``score`` is the router's: ``"softmax"`` over the experts, or each
+    expert's ``"sigmoid"``; the chosen scores are renormalised with
+    ``norm_topk_prob``.
+    ``expert_bias=rate`` adds DeepSeek-V3's auxiliary-loss-free balancing
+    (arXiv:2412.19437 section 2.1.2): a selection bias ``rate *
+    expert_bias`` (``expert_bias`` (num_experts,) in whole steps of the
+    rate, no gradient) that enters only which experts are chosen, and
+    that a training forward moves by one step, ``sign(mean load -
+    load_e)``, from the load over all the routed experts, which it keeps
+    in ``expert_load`` (each expert's share over the mean, no gradient).
+    A compiled step carries both back like ``expert_rows``;
+    ``SPMDTrainer`` publishes them as ``moe/bias_steps_max`` and
+    ``moe/load_max_over_mean`` and does not let its one-sample
+    shape-inference forward move the bias (``Parameter.carried``).
+
     With ``axis_name`` on the active mesh (``mesh=`` or
     ``parallel.mesh_scope``) and every expert held, the experts are
     sharded over that axis (``parallel.moe.expert_parallel_ffn``).
@@ -164,14 +179,20 @@ class TopKMoE(HybridBlock):
     def __init__(self, num_experts, hidden_size, top_k, in_units=0,
                  experts_held=None, norm_topk_prob=True, axis_name="ep",
                  mesh=None, activation="silu", shared_expert=None,
-                 prefix=None, params=None):
+                 score="softmax", expert_bias=None, prefix=None,
+                 params=None):
         super().__init__(prefix=prefix, params=params)
-        from ....parallel.moe import ACTIVATIONS, note_expert_rows
+        from ....parallel.moe import (ACTIVATIONS, SCORES, note_expert_bias,
+                                      note_expert_load, note_expert_rows)
 
         if activation not in ACTIVATIONS:
             raise ValueError(f"activation {activation!r}: one of "
                              f"{sorted(ACTIVATIONS)}")
+        if score not in SCORES:
+            raise ValueError(f"score {score!r}: one of {sorted(SCORES)}")
         self._act = activation
+        self._score = score
+        self._rate = None if expert_bias is None else float(expert_bias)
 
         self._E, self._F, self._k = int(num_experts), int(hidden_size), \
             int(top_k)
@@ -196,6 +217,13 @@ class TopKMoE(HybridBlock):
             self.expert_rows = self.params.get(
                 "expert_rows", grad_req="null", shape=(count,),
                 init="zeros", differentiable=False)
+            if self._rate is not None:
+                self.expert_bias = self.params.get(
+                    "expert_bias", grad_req="null", shape=(self._E,),
+                    init="zeros", differentiable=False)
+                self.expert_load = self.params.get(
+                    "expert_load", grad_req="null", shape=(self._E,),
+                    init="zeros", differentiable=False)
             if self._S:
                 self.shared_gate_weight = self.params.get(
                     "shared_gate_weight", shape=(D, 1),
@@ -207,6 +235,10 @@ class TopKMoE(HybridBlock):
                     "shared_w2", shape=(self._S, D),
                     allow_deferred_init=True)
         self.expert_rows.step_stat = note_expert_rows
+        if self._rate is not None:
+            self.expert_bias.step_stat = note_expert_bias
+            self.expert_bias.carried = True
+            self.expert_load.step_stat = note_expert_load
 
     def infer_param_shapes(self, x, *args):
         D, count = x.shape[-1], self._held[1]
@@ -221,7 +253,7 @@ class TopKMoE(HybridBlock):
     def hybrid_forward(self, F, x, router_input=None, *, gate_weight,
                        expert_w13, expert_w2, expert_rows,
                        shared_gate_weight=None, shared_w13=None,
-                       shared_w2=None):
+                       shared_w2=None, expert_bias=None, expert_load=None):
         from .... import autograd
         from ....ndarray.registry import apply_pure
         from ....parallel import moe
@@ -232,6 +264,7 @@ class TopKMoE(HybridBlock):
             and mesh.shape[self._axis] > 1 and self._held[1] == self._E
         k, held, n, norm = self._k, self._held, self._E, self._norm
         act, shared = self._act, self._S
+        score, rate = self._score, self._rate
 
         def shared_part(flat, sg, s13, s2):
             """sigmoid(x w_sg) * (act(x W_gate) * (x W_up)) W_down."""
@@ -250,30 +283,43 @@ class TopKMoE(HybridBlock):
         routed = router_input is not None
 
         def pure(xv, gw, w13, w2, *rest):
-            rv, extra = (rest[0], rest[1:]) if routed else (None, rest)
+            rv, rest = (rest[0], rest[1:]) if routed else (None, rest)
+            extra, steps = (rest[:-1], rest[-1]) if rate is not None \
+                else (rest, None)
+            bias = None if steps is None else rate * steps
             flat = xv.reshape(-1, xv.shape[-1])
             by = flat if rv is None else rv.reshape(flat.shape)
             if sharded:
-                y, rows = moe.expert_parallel_ffn(
+                y, rows, *load = moe.expert_parallel_ffn(
                     flat, gw, w13, w2, k, mesh, self._axis, norm,
                     activation=act,
-                    router_input=None if rv is None else by)
+                    router_input=None if rv is None else by, score=score,
+                    bias=bias)
             else:
-                idx, gates = moe.top_k_router(by, gw, k, norm)
+                idx, gates = moe.top_k_router(by, gw, k, norm, score, bias)
                 y, rows = moe.expert_ffn(flat, idx, gates, w13, w2, held, n,
                                          activation=act)
+                load = [] if bias is None else [moe.expert_load(idx, n)]
             if shared:
                 y = y + shared_part(flat, *extra)
-            return y.reshape(xv.shape), rows.astype("float32")
+            out = (y.reshape(xv.shape), rows.astype("float32"))
+            if bias is None:
+                return out
+            return out + (load[0], moe.update_expert_bias(steps, load[0]))
 
         inputs = [x, gate_weight, expert_w13, expert_w2]
         if routed:
             inputs.append(router_input)
         if shared:
             inputs += [shared_gate_weight, shared_w13, shared_w2]
-        out, rows = apply_pure(pure, inputs)
+        if rate is not None:
+            inputs.append(expert_bias)
+        out, rows, *balance = apply_pure(pure, inputs)
         if autograd.is_training():
             expert_rows._data = rows.data
+            if balance:
+                expert_load._data = balance[0].data
+                expert_bias._data = balance[1].data
         return out
 
     def __repr__(self):

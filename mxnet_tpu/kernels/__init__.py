@@ -56,7 +56,9 @@ def counters():
     norms, RoPE and layout took, ``kernels/qk_prologue.py``;
     ``delta_prologue_pallas`` / ``delta_prologue_plain``: which lowering
     each Gated DeltaNet layer's convolution, l2 norms and layout took,
-    ``kernels/delta_prologue.py``; ``pick_masked``: the ``pick`` ops a
+    ``kernels/delta_prologue.py``; ``short_conv_pallas`` /
+    ``short_conv_plain``: which lowering each gated short convolution
+    took, ``kernels/short_conv.py``; ``pick_masked``: the ``pick`` ops a
     trace lowered as a masked sum, ``ndarray/ops_index.py``)."""
     return _COUNTERS.snapshot()
 

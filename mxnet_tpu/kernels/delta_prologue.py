@@ -56,6 +56,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from . import _count
+from . import causal_conv as cc
 from .cost_model import _TILE_COLS, _VMEM_BUDGET_BYTES
 
 #: position tiles a grid step may take, largest first
@@ -108,9 +109,7 @@ def _plain(qkvz, conv_w, lay):
 # ---------------------------------------------------------------------------
 # the kernels
 
-def _halo(itemsize):
-    """Rows of a halo block: one sublane tile of the input's dtype."""
-    return 8 * max(1, 4 // itemsize)
+_halo = cc.halo
 
 
 def _vmem_bytes(rows, hb, lay, itemsize):
@@ -118,11 +117,8 @@ def _vmem_bytes(rows, hb, lay, itemsize):
     dv, ``qkvz`` and d(q|k|v), each double-buffered with their halos; the
     weight's and its partial gradient's float32 blocks; a dozen float32
     working copies of a head with its halos."""
-    cols, halo = hb * lay.k_dim, _halo(itemsize)
-    blocks = 2 * itemsize * cols * (5 * rows + 5 * halo)
-    weights = 2 * 2 * 8 * cols * 4
-    head = (rows + 2 * halo) * lay.k_dim * 4
-    return blocks + weights + 12 * head
+    return cc.vmem_bytes(rows, hb * lay.k_dim, itemsize, 5, 5, lay.k_dim,
+                         12)
 
 
 def tiles(s, lay, itemsize):
@@ -157,20 +153,6 @@ def _sigmoid(x):
     return 1.0 / (1.0 + jnp.exp(-x))
 
 
-def _conv(ext, w, taps):
-    """``y_p = sum_j w_j ext_(p - taps + 1 + j)`` over the rows of ``ext``
-    (n, d) float32, the taps summed in the twin's order; the first
-    ``taps - 1`` rows wrap around and are never kept."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    y = None
-    for j in range(taps):
-        back = taps - 1 - j
-        term = (pltpu.roll(ext, back, 0) if back else ext) * w[j:j + 1, :]
-        y = term if y is None else y + term
-    return y
-
-
 def _call(kernel, name, grid, in_specs, out_specs, out_shape, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
@@ -193,7 +175,7 @@ def _fwd_kernel(x_ref, xb_ref, w_ref, q_ref, k_ref, v_ref, *, lay, hb, nq):
             cols = slice(j * d, (j + 1) * d)
             before = jnp.where(t > 0, xb_ref[:, cols].astype(f32), 0.0)
             ext = jnp.concatenate([before, x_ref[:, cols].astype(f32)], 0)
-            y = _conv(ext, w_ref[:, cols], lay.taps)[halo:]
+            y = cc.conv(ext, w_ref[:, cols], lay.taps)[halo:]
             y = y * _sigmoid(y)
             if norm:
                 y = y * lax.rsqrt(jnp.sum(y * y, -1, keepdims=True) + _EPS)
@@ -236,12 +218,10 @@ def _forward(qkvz, w, lay, interpret):
 
 def _bwd_kernel(dq_ref, dk_ref, dv_ref, aq_ref, ak_ref, av_ref, x_ref,
                 xb_ref, xa_ref, w_ref, dx_ref, dw_ref, *, lay, hb, nq):
-    from jax.experimental.pallas import tpu as pltpu
-
     t, c, last = pl.program_id(1), pl.program_id(2), pl.num_programs(1) - 1
     d, taps, f32 = lay.k_dim, lay.taps, jnp.float32
     rows, halo = x_ref.shape[0], xb_ref.shape[0]
-    n, keep = rows + 2 * halo, slice(halo, halo + rows)
+    keep = slice(halo, halo + rows)
 
     def heads(cot_ref, after_ref, norm, scale=None):
         for j in range(hb):
@@ -251,7 +231,7 @@ def _bwd_kernel(dq_ref, dk_ref, dv_ref, aq_ref, ak_ref, av_ref, x_ref,
                 jnp.where(t > 0, xb_ref[:, cols].astype(f32), 0.0),
                 x_ref[:, cols].astype(f32),
                 jnp.where(t < last, xa_ref[:, cols].astype(f32), 0.0)], 0)
-            y = _conv(ext, w, taps)
+            y = cc.conv(ext, w, taps)
             sig = _sigmoid(y)
             # the cotangent at the tile's rows and the halo's after them
             g = jnp.concatenate([
@@ -265,19 +245,10 @@ def _bwd_kernel(dq_ref, dk_ref, dv_ref, aq_ref, ak_ref, av_ref, x_ref,
                     g = g * scale
                 g = r * (g - u * jnp.sum(u * g, -1, keepdims=True))
             dy = g * (sig * (1.0 + y * (1.0 - sig)))
-            dx = None
-            for k in range(taps):
-                ahead = taps - 1 - k
-                term = (pltpu.roll(dy, n - ahead, 0) if ahead else dy) \
-                    * w[k:k + 1, :]
-                dx = term if dx is None else dx + term
+            dx = cc.conv_back(dy, w, taps)
             dx_ref[:, cols] = dx[keep].astype(dx_ref.dtype)
-            dy = dy[keep]
-            for k in range(taps):
-                back = taps - 1 - k
-                xs = pltpu.roll(ext, back, 0) if back else ext
-                dw_ref[k:k + 1, cols] = jnp.sum(xs[keep] * dy, 0,
-                                                keepdims=True)
+            for k, row in enumerate(cc.taps_grad(ext, dy[keep], taps, keep)):
+                dw_ref[k:k + 1, cols] = row
 
     pl.when(c < nq)(lambda: heads(dq_ref, aq_ref, True, d ** -0.5))
     pl.when((c >= nq) & (c < 2 * nq))(lambda: heads(dk_ref, ak_ref, True))

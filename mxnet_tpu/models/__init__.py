@@ -8,12 +8,13 @@ from .transformer import TransformerLM, TransformerBlock, \
     MultiHeadSelfAttention
 from .decoder import DecoderBlockLM
 from .moe_decoder import MoEDecoderLM, MoEDecoderBlock, \
-    GroupedQueryAttention, GatedDeltaNet
+    GroupedQueryAttention, GatedDeltaNet, GatedShortConv, SwiGLU
 from .samba_y import SambaYLM, SambaYBlock, MambaMixer, \
     DifferentialAttention, GatedMemoryUnit
 
 __all__ = ["vision", "get_model", "TransformerLM", "TransformerBlock",
            "MultiHeadSelfAttention", "DecoderBlockLM", "MoEDecoderLM",
            "MoEDecoderBlock", "GroupedQueryAttention", "GatedDeltaNet",
+           "GatedShortConv", "SwiGLU",
            "SambaYLM", "SambaYBlock", "MambaMixer", "DifferentialAttention",
            "GatedMemoryUnit"]

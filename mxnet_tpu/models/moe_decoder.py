@@ -3,10 +3,10 @@ block-diffusion training input.
 
 A pre-norm decoder block with RMSNorm, grouped key/value heads, no bias,
 no learned positions, a drop-free top-k mixture of gated experts for
-every layer's MLP (``gluon.contrib.nn.TopKMoE``: told which experts it
-holds), a final RMSNorm and an untied head. Attention is the flash
-kernels (``kernels/flash_attention.py``). The layer pattern is part of
-the architecture, given layer by layer:
+each layer's MLP (``gluon.contrib.nn.TopKMoE``: told which experts it
+holds) or a dense one, a final RMSNorm and a head, untied by default.
+Attention is the flash kernels (``kernels/flash_attention.py``). The
+layer pattern is part of the architecture, given layer by layer:
 
 - ``attention``: one kind for every layer, or a list of one kind a
   layer. ``"causal"`` is autoregressive attention over (B, S) token ids;
@@ -23,6 +23,18 @@ the architecture, given layer by layer:
   ``kernels/gated_delta.py``) of ``num_k_heads`` key heads of
   ``head_k_dim`` serving ``num_v_heads`` value heads of ``head_v_dim``
   behind a causal depthwise convolution of ``conv_kernel`` positions.
+  ``{"short_conv": {"taps": n}}`` is none either: the mixer is a
+  ``GatedShortConv`` (LFM2's gated short convolution,
+  ``kernels/short_conv.py``) over ``n`` positions.
+- ``mlp``: each layer's MLP, for every layer or layer by layer:
+  ``"moe"``, the mixture of experts, or ``{"dense": width}``, a dense
+  SwiGLU of that width (``SwiGLU``), as a model's leading dense layers
+  have it.
+- ``score``, ``expert_bias``: the router's score (``"softmax"`` or
+  ``"sigmoid"``) and the rate of its auxiliary-loss-free selection bias
+  (none by default; ``TopKMoE``).
+- ``tie_embeddings``: the head reads the embedding's matrix and has no
+  weight of its own.
 - ``rope``: whether q and k carry rotary positions (rotate-half), for
   every layer or layer by layer (a layer without them has no positions
   at all: NoPE); ``rotary_dim``: on the leading ``rotary_dim`` of a
@@ -45,26 +57,41 @@ from ..gluon import nn
 from ..gluon.contrib.nn import TopKMoE
 
 __all__ = ["MoEDecoderLM", "MoEDecoderBlock", "GroupedQueryAttention",
-           "GatedDeltaNet"]
+           "GatedDeltaNet", "GatedShortConv", "SwiGLU"]
 
 
 _SIZED_KINDS = {"window": "window", "block_length": "block"}
 
 
+#: mixers that are no attention, by the key that names them
+_MIXERS = ("gated_delta", "short_conv")
+
+
 def _attention_kind(attention):
     """``(kind, size)`` of one layer's mixer: ``("causal", None)``,
-    ``("window", w)``, ``("block", b)`` or ``("gated_delta", {sizes})``."""
+    ``("window", w)``, ``("block", b)``, ``("gated_delta", {sizes})`` or
+    ``("short_conv", {sizes})``."""
     if attention == "causal":
         return "causal", None
     if isinstance(attention, dict) and len(attention) == 1:
         (key, size), = attention.items()
-        if key == "gated_delta" and isinstance(size, dict):
+        if key in _MIXERS and isinstance(size, dict):
             return key, dict(size)
         if key in _SIZED_KINDS and int(size) > 0:
             return _SIZED_KINDS[key], int(size)
     raise ValueError('attention is "causal", {"window": w}, '
-                     '{"block_length": b} or {"gated_delta": {...}}, got '
-                     f"{attention!r}")
+                     '{"block_length": b}, {"gated_delta": {...}} or '
+                     f'{{"short_conv": {{...}}}}, got {attention!r}')
+
+
+def _mlp_kind(mlp):
+    """One layer's MLP: ``None`` for the experts, else the dense width."""
+    if mlp == "moe":
+        return None
+    if isinstance(mlp, dict) and list(mlp) == ["dense"] \
+            and int(mlp["dense"]) > 0:
+        return int(mlp["dense"])
+    raise ValueError(f'mlp is "moe" or {{"dense": width}}, got {mlp!r}')
 
 
 def _per_layer(value, num_layers, what):
@@ -98,8 +125,8 @@ class GroupedQueryAttention(HybridBlock):
         self._h, self._hkv, self._d = num_heads, num_kv_heads, head_dim
         self._theta, self._eps = float(rope_theta), float(epsilon)
         self._kind, self._size = _attention_kind(attention)
-        if self._kind == "gated_delta":
-            raise ValueError("a gated-delta layer is a GatedDeltaNet")
+        if self._kind in _MIXERS:
+            raise ValueError(f"a {self._kind} layer is no attention")
         self._rope, self._qk_norm = bool(rope), bool(qk_norm)
         self._rot = head_dim if rotary_dim is None else int(rotary_dim)
         if not 0 < self._rot <= head_dim or self._rot % 2:
@@ -242,75 +269,161 @@ class GatedDeltaNet(HybridBlock):
             norm_gamma]))
 
 
+class GatedShortConv(HybridBlock):
+    """LFM2's gated short convolution over (B, S, E), no bias anywhere
+    (Liquid AI's LFM2, ``transformers``' ``Lfm2ShortConv``): one fused
+    projection to B | C | x, ``u = B * x``, a causal depthwise
+    convolution over ``taps`` positions (the last tap the position's
+    own), ``y = C * conv(u)``, the output projection. The part between
+    the projections is one kernel pair on the chip
+    (``kernels.short_conv``)."""
+
+    def __init__(self, embed_dim, taps=3, **kwargs):
+        super().__init__(**kwargs)
+        self._taps = int(taps)
+        with self.name_scope():
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(self._taps, embed_dim))
+            self.in_proj = nn.Dense(3 * embed_dim, use_bias=False,
+                                    flatten=False)
+            self.out_proj = nn.Dense(embed_dim, use_bias=False,
+                                     flatten=False)
+
+    def hybrid_forward(self, F, x, conv_weight):
+        from ..kernels.short_conv import short_conv
+        from ..ndarray.registry import apply_pure
+
+        return self.out_proj(apply_pure(short_conv,
+                                        [self.in_proj(x), conv_weight]))
+
+
+class SwiGLU(HybridBlock):
+    """A dense gated MLP of ``width``, no bias: ``w2(silu(w1 x) * w3 x)``,
+    w1 and w3 one fused projection ``w13`` (gate, then up), the gate in
+    float32."""
+
+    def __init__(self, embed_dim, width, **kwargs):
+        super().__init__(**kwargs)
+        self._width = int(width)
+        with self.name_scope():
+            self.w13 = nn.Dense(2 * self._width, use_bias=False,
+                                flatten=False)
+            self.w2 = nn.Dense(embed_dim, use_bias=False, flatten=False)
+
+    def hybrid_forward(self, F, x):
+        from ..ndarray.registry import apply_pure
+
+        f = self._width
+
+        def gate(h):
+            import jax
+            import jax.numpy as jnp
+
+            return (jax.nn.silu(h[..., :f].astype(jnp.float32))
+                    * h[..., f:].astype(jnp.float32)).astype(h.dtype)
+
+        return self.w2(apply_pure(gate, [self.w13(x)]))
+
+
 class MoEDecoderBlock(HybridBlock):
     def __init__(self, embed_dim, num_heads, num_kv_heads, head_dim,
                  num_experts, expert_dim, top_k, experts_held=None,
                  norm_topk_prob=True, rope_theta=1e6, epsilon=1e-6,
                  attention="causal", rope=True, qk_norm=True,
                  router_input="mlp", activation="silu", rotary_dim=None,
-                 output_gate=False, shared_expert=None, **kwargs):
+                 output_gate=False, shared_expert=None, mlp="moe",
+                 score="softmax", expert_bias=None, **kwargs):
         super().__init__(**kwargs)
         if router_input not in ("mlp", "layer"):
             raise ValueError(f'router_input is "mlp" or "layer", got '
                              f"{router_input!r}")
         self._route_by_layer_input = router_input == "layer"
         kind, size = _attention_kind(attention)
+        dense = _mlp_kind(mlp)
         with self.name_scope():
             self.ln1 = nn.RMSNorm(epsilon)
             if kind == "gated_delta":
                 self.attn = GatedDeltaNet(embed_dim, epsilon=epsilon, **size)
+            elif kind == "short_conv":
+                self.attn = GatedShortConv(embed_dim, **size)
             else:
                 self.attn = GroupedQueryAttention(
                     embed_dim, num_heads, num_kv_heads, head_dim, rope_theta,
                     epsilon, attention, rope, qk_norm, rotary_dim,
                     output_gate)
             self.ln2 = nn.RMSNorm(epsilon)
-            self.moe = TopKMoE(num_experts, expert_dim, top_k,
-                               experts_held=experts_held,
-                               norm_topk_prob=norm_topk_prob,
-                               activation=activation,
-                               shared_expert=shared_expert)
+            if dense is not None:
+                self.mlp = SwiGLU(embed_dim, dense)
+            else:
+                self.moe = TopKMoE(num_experts, expert_dim, top_k,
+                                   experts_held=experts_held,
+                                   norm_topk_prob=norm_topk_prob,
+                                   activation=activation,
+                                   shared_expert=shared_expert, score=score,
+                                   expert_bias=expert_bias)
+        self._dense = dense is not None
 
     def hybrid_forward(self, F, x):
         n = self.ln1(x)
         h = x + self.attn(n)
+        if self._dense:
+            return h + self.mlp(self.ln2(h))
         if self._route_by_layer_input:
             return h + self.moe(self.ln2(h), n)
         return h + self.moe(self.ln2(h))
 
 
 class MoEDecoderLM(HybridBlock):
-    """embed -> N x MoEDecoderBlock -> RMSNorm -> untied head."""
+    """embed -> N x MoEDecoderBlock -> RMSNorm -> head (untied, or the
+    embedding's matrix with ``tie_embeddings``)."""
 
     def __init__(self, vocab_size, embed_dim, num_layers, num_heads,
                  num_kv_heads, head_dim, num_experts, expert_dim, top_k,
                  experts_held=None, norm_topk_prob=True, rope_theta=1e6,
                  epsilon=1e-6, attention="causal", rope=True, qk_norm=True,
                  router_input="mlp", activation="silu", rotary_dim=None,
-                 output_gate=False, shared_expert=None, **kwargs):
+                 output_gate=False, shared_expert=None, mlp="moe",
+                 score="softmax", expert_bias=None, tie_embeddings=False,
+                 **kwargs):
         super().__init__(**kwargs)
         kinds = _per_layer(attention, num_layers, "attention")
         ropes = _per_layer(rope, num_layers, "rope")
+        mlps = _per_layer(mlp, num_layers, "mlp")
         parsed = [_attention_kind(a) for a in kinds]
         self._block = any(kind == "block" for kind, _ in parsed)
         if self._block and any(p != parsed[0] for p in parsed):
             raise ValueError("block-diffusion attention is every layer's, "
                              f"at one block length, or none's: {kinds!r}")
+        self._tied = bool(tie_embeddings)
         with self.name_scope():
             self.embed = nn.Embedding(vocab_size, embed_dim)
             self.blocks = nn.HybridSequential(prefix="blocks_")
-            for kind, with_rope in zip(kinds, ropes):
+            for kind, with_rope, layer_mlp in zip(kinds, ropes, mlps):
                 self.blocks.add(MoEDecoderBlock(
                     embed_dim, num_heads, num_kv_heads, head_dim,
                     num_experts, expert_dim, top_k, experts_held,
                     norm_topk_prob, rope_theta, epsilon, kind, with_rope,
                     qk_norm, router_input, activation, rotary_dim,
-                    output_gate, shared_expert))
+                    output_gate, shared_expert, layer_mlp, score,
+                    expert_bias))
             self.ln_f = nn.RMSNorm(epsilon)
-            self.head = nn.Dense(vocab_size, flatten=False, use_bias=False)
+            if not self._tied:
+                self.head = nn.Dense(vocab_size, flatten=False,
+                                     use_bias=False)
 
     def hybrid_forward(self, F, tokens):
         x = self.blocks(self.embed(tokens))
         if self._block:     # the head sees the noised half
             x = x[:, :tokens.shape[1] // 2]
-        return self.head(self.ln_f(x))
+        x = self.ln_f(x)
+        if not self._tied:
+            return self.head(x)
+        import jax
+
+        from .. import nd
+
+        b, s = x.shape[:2]
+        w = self.embed.weight.data()
+        with jax.named_scope("head"):   # no child block to open it
+            return nd.dot(x.reshape(-1, w.shape[1]),
+                          nd.transpose(w)).reshape(b, s, -1)

@@ -42,7 +42,8 @@ from jax.sharding import PartitionSpec as P
 
 __all__ = ["moe_ffn", "switch_router", "moe_specs", "top_k_router",
            "expert_ffn", "expert_parallel_ffn", "note_expert_rows",
-           "ACTIVATIONS"]
+           "note_expert_bias", "note_expert_load", "expert_load",
+           "update_expert_bias", "ACTIVATIONS", "SCORES"]
 
 
 def moe_specs(mesh, axis_name="ep", batch_axes=None):
@@ -174,14 +175,25 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, mesh=None, axis_name="ep",
 # ---------------------------------------------------------------------------
 # drop-free top-k routing over the experts held here
 
-def top_k_router(x, gate_w, k, norm_topk_prob=True):
+#: the router's score of an expert by the name the layer is given
+SCORES = {"softmax": lambda a: jax.nn.softmax(a, axis=-1),
+          "sigmoid": jax.nn.sigmoid}
+
+
+def top_k_router(x, gate_w, k, norm_topk_prob=True, score="softmax",
+                 bias=None):
     """Top-k routing over ALL experts: ``(idx (T, k) int32, gates (T, k)
-    float32)``. Logits accumulate and the softmax runs in float32; with
-    ``norm_topk_prob`` the k probabilities are divided by their sum."""
+    float32)``. Logits accumulate and the ``score`` (``"softmax"`` over
+    the experts, or each expert's ``"sigmoid"``) runs in float32; the k
+    experts are chosen by score plus ``bias`` (E,) where one is given (a
+    selection bias that enters nothing else: DeepSeek-V3's auxiliary-
+    loss-free balancing, arXiv:2412.19437 section 2.1.2); with
+    ``norm_topk_prob`` the k chosen scores are divided by their sum."""
     with jax.named_scope("router"):
         logits = jnp.dot(x, gate_w, preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        _, idx = lax.top_k(lax.stop_gradient(probs), k)
+        probs = SCORES[score](logits.astype(jnp.float32))
+        chooser = probs if bias is None else probs + bias.astype(jnp.float32)
+        _, idx = lax.top_k(lax.stop_gradient(chooser), k)
         # the k probabilities picked through a one-hot mask: its
         # derivative is dense too, where top_k's own would scatter
         picked = idx[..., None] == jnp.arange(probs.shape[-1])[None, None]
@@ -189,6 +201,26 @@ def top_k_router(x, gate_w, k, norm_topk_prob=True):
         if norm_topk_prob:
             gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
         return idx.astype(jnp.int32), gates
+
+
+def expert_load(idx, num_experts):
+    """The load of each of the ``num_experts`` routed experts from ``idx``
+    (T, k): its assignments over the mean, float32 (E,); 1 is an even
+    share."""
+    count = jnp.sum(idx.reshape(-1)[:, None]
+                    == jnp.arange(num_experts, dtype=idx.dtype)[None],
+                    axis=0, dtype=jnp.int32)
+    return count.astype(jnp.float32) / (idx.size / num_experts)
+
+
+def update_expert_bias(steps, load):
+    """The selection bias after a step, in steps of its rate: ``n_e +
+    sign(1 - load_e)`` from ``expert_load``'s shares (DeepSeek-V3's
+    ``b_e + rate * sign(mean load - load_e)``, arXiv:2412.19437 section
+    2.1.2, with ``b = rate * n``: whole numbers, which float32 holds
+    exactly). An expert that got more than its share is chosen less, one
+    that got less is chosen more."""
+    return steps + jnp.sign(1.0 - load)
 
 
 def _layout(idx, first, count):
@@ -433,32 +465,40 @@ def expert_ffn(x, idx, gates, w13, w2, experts_held=None, num_experts=None,
 
 def expert_parallel_ffn(x, gate_w, w13, w2, k, mesh, axis_name="ep",
                         norm_topk_prob=True, use_pallas=None,
-                        activation="silu", router_input=None):
+                        activation="silu", router_input=None,
+                        score="softmax", bias=None):
     """The same layer with the experts sharded over ``axis_name``: every
     device gathers the axis's tokens, routes them over all the experts
-    (from ``router_input``'s rows where given, sharded as ``x``),
-    computes the part its own slice of ``w13`` / ``w2`` gives
-    (``experts_held`` from its place on the axis) and the parts are
-    summed back to the tokens' owners. x (T, D) sharded over the axis on
-    its rows; returns ``(y, rows (E,))``."""
+    (from ``router_input``'s rows where given, sharded as ``x``; ``score``
+    and ``bias`` as ``top_k_router`` takes them), computes the
+    part its own slice of ``w13`` / ``w2`` gives (``experts_held`` from
+    its place on the axis) and the parts are summed back to the tokens'
+    owners. x (T, D) sharded over the axis on its rows; returns ``(y,
+    rows (E,))``, and with ``bias`` also ``load (E,)``, every routed
+    expert's share of the gathered tokens' assignments (``expert_load``)."""
     n_experts = gate_w.shape[-1]
+    extra = () if bias is None else (bias,)
 
-    def local(xl, rl, gw, w13l, w2l):
+    def local(xl, rl, gw, w13l, w2l, *bl):
         xa = lax.all_gather(xl, axis_name, axis=0, tiled=True)
         ra = xa if router_input is None else lax.all_gather(
             rl, axis_name, axis=0, tiled=True)
-        idx, gates = top_k_router(ra, gw, k, norm_topk_prob)
+        idx, gates = top_k_router(ra, gw, k, norm_topk_prob, score, *bl)
         held = (lax.axis_index(axis_name) * w13l.shape[0], w13l.shape[0])
         y, rows = expert_ffn(xa, idx, gates, w13l, w2l, held, n_experts,
                              use_pallas=use_pallas, activation=activation)
-        return lax.psum_scatter(y, axis_name, scatter_dimension=0,
-                                tiled=True), rows
+        out = (lax.psum_scatter(y, axis_name, scatter_dimension=0,
+                                tiled=True), rows)
+        return out + tuple(expert_load(idx, n_experts) for _ in bl)
 
     espec = P(axis_name)
     return _shard_map(local, mesh=mesh,
-                      in_specs=(espec, espec, P(), espec, espec),
-                      out_specs=(espec, espec), check_vma=False)(
-        x, x if router_input is None else router_input, gate_w, w13, w2)
+                      in_specs=(espec, espec, P(), espec, espec)
+                      + (P(),) * len(extra),
+                      out_specs=(espec, espec) + (P(),) * len(extra),
+                      check_vma=False)(
+        x, x if router_input is None else router_input, gate_w, w13, w2,
+        *extra)
 
 
 # what the compiled step counted, published when its arrays are ready
@@ -471,14 +511,36 @@ def note_expert_rows(rows_by_layer):
     as ``telemetry`` counters: ``moe/steps``, ``moe/assignments_held``
     (summed over layers and steps), ``moe/max_expert_rows`` (the largest
     group of the last step read)."""
+    counters = _moe_counters()
+    counters.add("steps")
+    counters.add("assignments_held",
+                 int(sum(float(r.sum()) for r in rows_by_layer)))
+    counters.set("max_expert_rows",
+                 int(max(float(r.max()) for r in rows_by_layer)))
+
+
+def _moe_counters():
     global _MOE_COUNTERS
     if _MOE_COUNTERS is None:
         from ..telemetry import metrics
 
         _MOE_COUNTERS = metrics.counter_family(
             "moe", {"steps": 0, "assignments_held": 0, "max_expert_rows": 0})
-    _MOE_COUNTERS.add("steps")
-    _MOE_COUNTERS.add("assignments_held",
-                      int(sum(float(r.sum()) for r in rows_by_layer)))
-    _MOE_COUNTERS.set("max_expert_rows",
-                      int(max(float(r.max()) for r in rows_by_layer)))
+    return _MOE_COUNTERS
+
+
+def note_expert_bias(steps_by_layer):
+    """Publish one step's routing bias (one host array a layer, after
+    the step's update, in steps of its rate) as the ``telemetry`` counter
+    ``moe/bias_steps_max``: the largest |b| / rate of any layer."""
+    _moe_counters().set("bias_steps_max", float(max(
+        abs(n).max() for n in steps_by_layer)))
+
+
+def note_expert_load(load_by_layer):
+    """Publish one step's load over all the routed experts (one host
+    array a layer, ``expert_load``'s shares) as the ``telemetry`` counter
+    ``moe/load_max_over_mean``: the largest share of any layer, how far
+    the bias has still to even it."""
+    _moe_counters().set("load_max_over_mean", float(max(
+        a.max() for a in load_by_layer)))

@@ -363,6 +363,12 @@ class SPMDTrainer:
         # or a dense attention of 8,192 positions there)
         pending = any(p._ndarray is None or p.grad_req == "null"
                       for p in net.collect_params().values())
+        # state that each step moves on from its own last value
+        # (``Parameter.carried``: TopKMoE's routing bias) is put back
+        # after that forward: a one-sample batch of zeros is no step
+        carried = [(p, p._ndarray._data) for p in
+                   net.collect_params().values()
+                   if getattr(p, "carried", False) and p._ndarray is not None]
         with _telem.span("spmd.build.init_forward", cat="train",
                          ran=pending), \
                 init_ctx, autograd.pause(train_mode=True):
@@ -374,6 +380,8 @@ class SPMDTrainer:
                                         dtype=str(x.dtype)))
             if pending:
                 net.forward(xs)
+        for p, value in carried:
+            p._ndarray._data = value
         self._params = [p for _, p in sorted(net.collect_params().items())]
         names = [p.name for p in self._params]
         trainable = [p.grad_req != "null" for p in self._params]

@@ -549,6 +549,92 @@ def test_the_phi4flash_cells_step_fits_and_takes_the_scan_kernels(
     assert c.memory_analysis().temp_size_in_bytes < 4.1e9
 
 
+def test_short_conv_kernels_compile(one_chip, monkeypatch):
+    """LFM2's gated short convolution at the shape of the cell
+    lfm2moe24b-train-s8192, ``bcx`` (1, 8192, 6144) bf16 of 2,048
+    channels, value and gradient in both inputs: ``short_conv_fwd`` and
+    ``short_conv_bwd`` under the names ``short_conv_ms.tokens`` reads,
+    at the tiles the budget gives (1,024 positions of 512 channels
+    forward, of 256 backward), counted once as kernels, and no padded
+    (1, 8194, 2048) float32 ``B * x``, which the twin's passes hold."""
+    from mxnet_tpu.kernels import short_conv as sc
+
+    assert sc.tiles(8192, 2048, 2, False) == (1024, 512)
+    assert sc.tiles(8192, 2048, 2, True) == (1024, 256)
+
+    def loss(bcx, conv_w):
+        return sc.short_conv(bcx, conv_w).astype(jnp.float32).sum()
+
+    before = kernels.counters()
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        c = _compile(jax.value_and_grad(loss, (0, 1)), one_chip,
+                     ((1, 8192, 6144), jnp.bfloat16),
+                     ((3, 2048), jnp.bfloat16))
+    _assert_kernel(c, "short_conv_fwd")
+    _assert_kernel(c, "short_conv_bwd")
+    assert "f32[1,8194,2048]" not in c.as_text()
+    after = kernels.counters()
+    assert after["short_conv_pallas"] == before.get(
+        "short_conv_pallas", 0) + 1
+    assert after.get("short_conv_plain", 0) == before.get(
+        "short_conv_plain", 0)
+
+
+def test_the_lfm2_cells_step_fits_and_takes_the_short_conv_kernels(
+        one_chip, monkeypatch):
+    """``SPMDTrainer``'s step of the cell lfm2moe24b-train-s8192 at its
+    own depth (6 layers: two dense conv layers, attention, three expert
+    conv layers), widths, vocabulary slice (8,192 rows, tied) and length
+    (1 x 8,192), built by the benchmark's own ``build_net`` and loss
+    block, compiled for the chip: the five conv layers' mixers are the
+    kernel pair (counted, no twin) under the names
+    ``short_conv_ms.tokens`` reads, the flash kernels and the grouped
+    products beside them, and the step's temporaries stay under 4.8 GB
+    (4,546,375,680 B as built), so that with the 9.16 GB resident the
+    cell holds about 81% of the chip's 16.9. Plain SGD: Adam's two
+    moments would double the host memory of this test and change no
+    temporary. The trainer is built on a short batch: its parameters do
+    not depend on the length."""
+    import importlib.util
+
+    import numpy as onp
+
+    from mxnet_tpu import parallel
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    spec = importlib.util.spec_from_file_location(
+        "chip_compile_lfm2", os.path.join(bench, "models",
+                                          "lfm2-24b-a2b.py"))
+    model = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(model)
+    with open(os.path.join(bench, "configs", "lfm2-24b-a2b.json")) as f:
+        cfg = json.load(f)
+    net = model.build_net(cfg)
+    net.initialize()
+    trainer = parallel.SPMDTrainer(
+        net, model.loss_block(cfg), optimizer="sgd",
+        optimizer_params={"learning_rate": 1e-7},
+        mesh=parallel.make_mesh({"dp": 1}, devices=jax.devices()[:1]),
+        compute_dtype="bfloat16")
+    short = onp.zeros((1, 256), "int32")
+    trainer._ensure_built(short, short)
+    before = kernels.counters()
+    tokens = onp.zeros((1, 8192), "int32")
+    c = _compile_trainer_step(trainer, tokens, tokens, one_chip,
+                              monkeypatch)
+    for name in ("short_conv_fwd", "short_conv_bwd", "flash_fwd",
+                 "flash_bwd", "moe_gmm_fwd"):
+        _assert_kernel(c, name)
+    after = kernels.counters()
+    assert after["short_conv_pallas"] - before.get(
+        "short_conv_pallas", 0) == 5
+    assert after.get("short_conv_plain", 0) == before.get(
+        "short_conv_plain", 0)
+    assert c.memory_analysis().temp_size_in_bytes < 4.8e9
+
+
 @pytest.mark.parametrize("b,s,v", [
     (1, 8192, 18992),     # qwen3next80b-train-s8192: one sequence a step
     (2, 2048, 50272),     # opt1.3b-train-s2048: two
