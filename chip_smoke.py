@@ -19,6 +19,9 @@ weights from a fixed seed:
   short_conv
            LFM2's gated short convolution as its kernel pair against the
            plain twin, and both timed alone at the LFM2 cell's shape.
+  ssm_scan the selective scan of a Mamba layer as its kernel pair
+           against the lax.scan twin, and both timed alone at the
+           Phi-4-mini-flash cell's shape.
 
 ``--chips 4`` runs ONLY the data-parallel phase and what it is compared
 with (same seed, same global batch, one-device mesh vs dp=4 mesh).
@@ -771,6 +774,83 @@ def phase_short_conv(cfg):
                 f"{ms:.3f} ms a call")
 
 
+def phase_ssm_scan(cfg):
+    """The selective scan of a Mamba layer (``kernels/selective_scan.py``)
+    as its kernel pair against the ``lax.scan`` twin: g and the VJP to all
+    eight inputs, each to ``tol`` of the twin's largest element; float32
+    at a small shape over three chunks of two channel tiles to rounding,
+    then bfloat16 u, dt, z, B, C at (1, 8192) of 5,120 channels and 16
+    states, the Phi-4-mini-flash cell's Mamba layer, to bfloat16's. There
+    both are timed alone (host clock, 20 calls back to back, forward and
+    forward with the VJP). The kernels are forced (``use_pallas=True``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import kernels
+    from mxnet_tpu.kernels.selective_scan import selective_scan
+
+    on_chip = not cfg.rehearse
+    rs = onp.random.RandomState(SEED + 8)
+    names = ("g", "du", "ddt", "dA", "dB", "dC", "dD", "dz", "ddt_bias")
+
+    def inputs(dtype, b, s, ch, n):
+        """delta = softplus(dt + bias) in [1e-3, 0.1], A = -(1..n) a
+        channel, as a Mamba layer's initialiser draws them."""
+        def randn(*shape):
+            return rs.randn(*shape).astype("f")
+        delta = onp.exp(rs.uniform(onp.log(1e-3), onp.log(0.1),
+                                   (b, s, ch))).astype("f")
+        bias = 0.5 * randn(ch)
+        dt = onp.log(onp.expm1(delta)) - bias
+        a = -onp.arange(1, n + 1, dtype="f") * onp.exp(0.1 * randn(ch, n))
+        low = (randn(b, s, ch), dt, a, randn(b, s, n), randn(b, s, n),
+               1.0 + 0.1 * randn(ch), randn(b, s, ch), bias)
+        cast = (dtype, dtype, jnp.float32, dtype, dtype, jnp.float32,
+                dtype, jnp.float32)
+        return tuple(jnp.asarray(x, t) for x, t in zip(low, cast)), \
+            jnp.asarray(randn(b, s, ch), dtype)
+
+    def run(pallas, with_vjp=True):
+        def f(args, dg):
+            g, vjp = jax.vjp(lambda *a: selective_scan(
+                *a, use_pallas=pallas), *args)
+            return (g,) + vjp(dg) if with_vjp else (g,)
+        return jax.jit(f)
+
+    def check(dtype, b, s, ch, n, tol):
+        args, dg = inputs(dtype, b, s, ch, n)
+        got = run(True)(args, dg)
+        if on_chip:
+            assert "tpu_custom_call" in run(True).lower(args, dg).as_text()
+        want = run(False)(args, dg)
+        for name, a, w in zip(names, got, want):
+            a, w = (jnp.asarray(x, jnp.float32) for x in (a, w))
+            err = float(jnp.abs(a - w).max() / jnp.abs(w).max())
+            log(f"ssm scan {jnp.dtype(dtype).name} {(b, s, ch, n)} {name} "
+                f"max |pallas - plain| = {err:.3e} of the largest "
+                f"(tol {tol})")
+            assert bool(jnp.isfinite(a).all()) and err < tol, (name, err)
+        return args, dg
+
+    check(jnp.float32, 1, 768, 512, 16, 1e-5)
+    if cfg.rehearse:
+        return
+    args = check(jnp.bfloat16, 1, 8192, 5120, 16, 2e-2)
+    assert kernels.counters().get("ssm_scan_pallas", 0) > 0
+    for pallas in (True, False):
+        for with_vjp in (False, True):
+            fn = run(pallas, with_vjp)
+            jax.block_until_ready(fn(*args))
+            t0 = time.perf_counter()
+            for _ in range(20):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            ms = (time.perf_counter() - t0) / 20 * 1e3
+            log(f"ssm scan alone, {'kernels' if pallas else 'twin'}, "
+                f"{'forward + VJP' if with_vjp else 'forward'}: "
+                f"{ms:.3f} ms a call")
+
+
 def phase_dp4(cfg):
     """Data-parallel training over four chips against the same steps on
     one: same seed, same global batch.
@@ -858,7 +938,8 @@ def main(argv=None):
                 "kernels": phase_kernels, "masked": phase_masked,
                 "prologue": phase_prologue,
                 "delta_prologue": phase_delta_prologue,
-                "short_conv": phase_short_conv}
+                "short_conv": phase_short_conv,
+                "ssm_scan": phase_ssm_scan}
     todo = {"dp4": phase_dp4} if args.chips == 4 else one_chip
     if args.phases:
         todo = {n: todo[n] for n in args.phases.split(",")}

@@ -28,18 +28,31 @@ order (reversed backward):
   ``D u`` and the gate ``silu(z)`` inside, so that g leaves in the
   input's dtype and y never reaches HBM.
 - ``ssm_scan_bwd``: the chunk's states again from its stored incoming
-  state (into VMEM, one a position), the gate's derivative for the whole
-  chunk, then the walk backwards with ``dh`` carried in scratch across
-  chunks: du, d(dt) (through softplus) and dz a position and channel;
-  dB, dC a position (partial sums over the tile's channels, summed after
-  the kernel); dA, dD and d(dt_bias) summed over the tile's positions in
-  blocks that stay resident across its chunks.
+  state (into a (chunk + 1, N, tile) float32 scratch, one a position) and
+  the read-out's cotangent ``dy = dg silu(z)`` for the whole chunk; then
+  the walk backwards, which carries only the recurrence:
+  ``dh <- dh + C_t dy_t``, stored for each position into a (chunk, N,
+  tile) float32 scratch, then ``dh <- exp(delta_t A) dh``, ``dh`` kept
+  in scratch across chunks. Everything else leaves the serial loops for
+  a pass over the chunk after the walk, eight positions a step, that
+  reads the states, the stored ``dh`` and the chunk's inputs: dC and dB
+  a (position, state) pair, summed over the tile's channels (partial
+  sums, added over the tiles after the kernel); the read-out y, du and
+  d(dt) (through softplus) a position and channel, summed over the
+  states; dz a position and channel; dA, dD and d(dt_bias) summed over
+  the tile's positions in blocks that stay resident across its chunks.
 
-Inside a chunk a loop takes ``_GROUP`` positions at a time (unrolled):
-its rows of u, dt, z are loaded once, B and C come as (N, _GROUP)
-blocks with the positions along the lanes (``_by_group``), and a
-position's row or column of a result is selected into the group's block,
-which is stored whole.
+Inside a chunk a loop takes ``_GROUP`` positions at a time (unrolled,
+the backward's two groups an iteration): its rows of u, dt, z are
+loaded once and B and C come as (N, _GROUP) blocks with the positions
+along the lanes (``_by_group``). The forward selects a position's
+read-out into the group's block, which is stored whole. The backward's
+sums over channels fold a tile's lanes to 128 and transpose eight
+positions' (N, 128) blocks, so that vector adds down the sublanes take
+them (``_lane_sums``); its sums over states fold eight positions' (8,
+tile) blocks into one in three halving steps (``_sublane_sums``): whole
+blocks, no per-position select and no cross-lane reduction. The
+backward's exponentials are ``2^(delta A log2 e)``, A scaled once.
 
 The plain twin (``use_pallas=False``, off-TPU, and whatever ``eligible``
 refuses) is the recurrence under ``lax.scan`` over positions in float32,
@@ -65,6 +78,7 @@ _GROUP = 16
 #: positions a grid step takes, and the widest channel tile
 _CHUNK = 256
 _LANES = 256
+_LOG2E = 1.4426950408889634
 
 
 def _sigmoid(x):
@@ -158,13 +172,80 @@ def _fwd_kernel(u_ref, d_ref, z_ref, b_ref, c_ref, a_ref, dd_ref, bias_ref,
     g_ref[...] = ((y_scr[...] + dd_ref[...] * u) * gate).astype(g_ref.dtype)
 
 
+def _fold_lanes(x):
+    """(N, tile) -> (N, 128): the tile's 128-lane pieces added."""
+    out = x[:, :_TILE_COLS]
+    for k in range(_TILE_COLS, x.shape[1], _TILE_COLS):
+        out = out + x[:, k:k + _TILE_COLS]
+    return out
+
+
+def _fold_states(x):
+    """(N, tile) -> (8, tile): the state's sublane tiles added."""
+    out = x[:8]
+    for k in range(8, x.shape[0], 8):
+        out = out + x[k:k + 8]
+    return out
+
+
+def _lane_sums(blocks):
+    """Eight positions' (N, 128) blocks -> (1, 8 N): each (position,
+    state) row summed over its lanes, position-major along the result's
+    lanes. One transpose puts the rows' sums down the sublanes, where
+    plain vector adds take them, instead of a cross-lane reduction a row."""
+    return jnp.sum(jnp.concatenate(blocks, axis=0).T, axis=0, keepdims=True)
+
+
+def _sublane_sums(blocks):
+    """Eight positions' (8, tile) blocks -> (8, tile), row j the sum of
+    block j's rows: three halving steps, each adding rows ``k`` apart
+    with those of the second half's blocks rolled in."""
+    row = lax.broadcasted_iota(jnp.int32, blocks[0].shape, 0)
+    for k in (4, 2, 1):
+        low = (row & k) == 0
+        half = len(blocks) // 2
+        nxt = []
+        for p in range(half):
+            keep = jnp.where(low, blocks[p], blocks[p + half])
+            other = jnp.where(low, blocks[p + half], blocks[p])
+            moved = _roll(other, 8 - k)         # other[row + k]
+            if k < 4:                           # other[row - k] above
+                moved = jnp.where(low, moved, _roll(other, k))
+            nxt.append(keep + moved)
+        blocks = nxt
+    return blocks[0]
+
+
+def _roll(x, shift):
+    """x's rows rolled down by ``shift`` (``jnp.roll`` on axis 0)."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.roll(x, shift, 0)
+
+
+def _pairs(groups, body, carry, reverse=False):
+    """``carry = body(i, carry)`` for the groups i in order (reversed),
+    two groups a loop iteration, so that the scheduler overlaps one
+    group's set-up with the other's work; an odd group last."""
+    def two(k, carry):
+        i = groups - 1 - 2 * k if reverse else 2 * k
+        return body(i - 1 if reverse else i + 1, body(i, carry))
+
+    carry = lax.fori_loop(0, groups // 2, two, carry)
+    if groups % 2:
+        carry = body(0 if reverse else groups - 1, carry)
+    return carry
+
+
 def _bwd_kernel(u_ref, d_ref, z_ref, b_ref, c_ref, a_ref, dd_ref, bias_ref,
                 s_ref, dg_ref, du_ref, ddl_ref, dz_ref, db_ref, dc_ref,
-                da_ref, dD_ref, dbias_ref, dh_scr, hs_scr, y_scr, *, groups):
+                da_ref, dD_ref, dbias_ref, dh_scr, hs_scr, y_scr, dhs_scr, *,
+                groups):
     """The same grid, the chunks reversed: a tile's last chunk first,
     ``dh`` in ``dh_scr``; ``hs_scr[t]`` the state entering position t of
-    the chunk (``hs_scr[t + 1]`` leaving it). dA, dD and the bias's
-    gradient accumulate in their resident output blocks."""
+    the chunk (``hs_scr[t + 1]`` leaving it), ``dhs_scr[t]`` the state's
+    gradient at t. dA, dD and the bias's gradient accumulate in their
+    resident output blocks; dB and dC leave a row of eight positions'
+    (position, state) sums at a time."""
     f32 = jnp.float32
 
     @pl.when(pl.program_id(2) == 0)
@@ -175,72 +256,75 @@ def _bwd_kernel(u_ref, d_ref, z_ref, b_ref, c_ref, a_ref, dd_ref, bias_ref,
         dbias_ref[...] = jnp.zeros_like(dbias_ref)
 
     a = a_ref[...]
+    a2 = a * _LOG2E        # exp(delta A) = 2^(delta a2)
     hs_scr[0] = s_ref[...]
 
     def again(i, h):        # the forward of the chunk, every state kept
-        dl, du, bg, cg, _ = _group_inputs(i, u_ref, d_ref, b_ref, c_ref,
-                                          bias_ref)
-        y = jnp.zeros(dl.shape, f32)
+        dl, du, bg, _, _ = _group_inputs(i, u_ref, d_ref, b_ref, c_ref,
+                                         bias_ref)
         for j in range(_GROUP):
-            h = jnp.exp(dl[j:j + 1] * a) * h + bg[:, j:j + 1] * du[j:j + 1]
+            h = jnp.exp2(dl[j:j + 1] * a2) * h + bg[:, j:j + 1] * du[j:j + 1]
             hs_scr[i * _GROUP + j + 1] = h
-            y = _select(y, jnp.sum(cg[:, j:j + 1] * h, axis=0,
-                                   keepdims=True), j, 0)
-        y_scr[_rows(i), :] = y
         return h
 
-    lax.fori_loop(0, groups, again, s_ref[...])
-    # g = (y + D u) silu(z): its derivative for the chunk at once; y_scr
-    # then holds dy
-    u = u_ref[...].astype(f32)
-    sz, gate = _silu_parts(z_ref[...].astype(f32))
-    dg = dg_ref[...].astype(f32)
-    dy = dg * gate
-    dz_ref[...] = (dg * (y_scr[...] + dd_ref[...] * u) * sz
-                   * (1.0 + z_ref[...].astype(f32) * (1.0 - sz))
-                   ).astype(dz_ref.dtype)
-    dD_ref[...] += jnp.sum(dy * u, axis=0, keepdims=True)
+    _pairs(groups, again, s_ref[...])
+    # g = (y + D u) silu(z): the read-out's cotangent dy for the chunk at
+    # once, into y_scr
+    dy = dg_ref[...].astype(f32) * _silu_parts(z_ref[...].astype(f32))[1]
+    dD_ref[...] += jnp.sum(dy * u_ref[...].astype(f32), axis=0,
+                           keepdims=True)
     y_scr[...] = dy
 
-    def back(k, carry):
-        dh, da, dbias = carry
-        i = groups - 1 - k
+    def walk_group(i, dh):  # the recurrence alone, backwards
+        dl, _, _, cg, _ = _group_inputs(i, u_ref, d_ref, b_ref, c_ref,
+                                        bias_ref)
+        dyg = y_scr[_rows(i), :]
+        for j in reversed(range(_GROUP)):
+            dh = dh + cg[:, j:j + 1] * dyg[j:j + 1]
+            dhs_scr[i * _GROUP + j] = dh
+            dh = jnp.exp2(dl[j:j + 1] * a2) * dh
+        return dh
+
+    dh_scr[...] = _pairs(groups, walk_group, dh_scr[...], reverse=True)
+
+    def after(i, carry):    # a group's gradients from the stored states
+        da, dbias = carry
         rows = _rows(i)
         dl, du, bg, cg, raw = _group_inputs(i, u_ref, d_ref, b_ref, c_ref,
                                             bias_ref)
-        uu = u_ref[rows, :].astype(f32)
         dyg = y_scr[rows, :]
-        dus = jnp.zeros(dl.shape, f32)
-        dds = jnp.zeros(dl.shape, f32)
-        dbs = jnp.zeros(bg.shape, f32)
-        dcs = jnp.zeros(cg.shape, f32)
-        for j in reversed(range(_GROUP)):
-            t = i * _GROUP + j
-            h, hp = hs_scr[t + 1], hs_scr[t]
-            dyt = dyg[j:j + 1]
-            dcs = _select(dcs, jnp.sum(h * dyt, axis=1, keepdims=True), j, 1)
-            dh = dh + cg[:, j:j + 1] * dyt
-            ea = jnp.exp(dl[j:j + 1] * a)
-            dexp = dh * hp * ea         # of the exponent delta A
-            dbu = dh * bg[:, j:j + 1]   # of delta u
-            dds = _select(dds, jnp.sum(dexp * a + dbu * uu[j:j + 1], axis=0,
-                                       keepdims=True), j, 0)
-            dus = _select(dus, jnp.sum(dbu, axis=0, keepdims=True), j, 0)
-            dbs = _select(dbs, jnp.sum(dh * du[j:j + 1], axis=1,
-                                       keepdims=True), j, 1)
-            da = da + dexp * dl[j:j + 1]
-            dh = ea * dh
+        ys, dus, dxa = [], [], []
+        for half in (0, 8):
+            cs, bs, us, xs, hy = [], [], [], [], []
+            for j in range(half, half + 8):
+                t = i * _GROUP + j
+                dh, hp, h = dhs_scr[t], hs_scr[t], hs_scr[t + 1]
+                hy.append(_fold_states(h * cg[:, j:j + 1]))
+                cs.append(_fold_lanes(h * dyg[j:j + 1]))
+                bs.append(_fold_lanes(dh * du[j:j + 1]))
+                us.append(_fold_states(dh * bg[:, j:j + 1]))
+                dexp = dh * hp * jnp.exp2(dl[j:j + 1] * a2)   # of delta A
+                da = da + dexp * dl[j:j + 1]
+                xs.append(_fold_states(dexp * a))
+            at = pl.ds(2 * i + half // 8, 1)
+            dc_ref[at, :] = _lane_sums(cs)
+            db_ref[at, :] = _lane_sums(bs)
+            ys.append(_sublane_sums(hy))        # the read-out y
+            dus.append(_sublane_sums(us))       # of delta u
+            dxa.append(_sublane_sums(xs))
+        y, dus, dxa = (jnp.concatenate(x) for x in (ys, dus, dxa))
+        uu = u_ref[rows, :].astype(f32)
+        dds = (dxa + dus * uu) * _sigmoid(raw)  # of the raw step size
         du_ref[rows, :] = (dus * dl + dd_ref[...] * dyg).astype(du_ref.dtype)
-        dds = dds * _sigmoid(raw)      # of the raw step size
         ddl_ref[rows, :] = dds.astype(ddl_ref.dtype)
-        db_ref[i] = dbs
-        dc_ref[i] = dcs
-        return dh, da, dbias + jnp.sum(dds, axis=0, keepdims=True)
+        zz = z_ref[rows, :].astype(f32)
+        sz = _sigmoid(zz)
+        dz_ref[rows, :] = (dg_ref[rows, :].astype(f32) * (y + dd_ref[...] * uu)
+                           * sz * (1.0 + zz * (1.0 - sz))).astype(dz_ref.dtype)
+        return da, dbias + jnp.sum(dds, axis=0, keepdims=True)
 
-    dh, da, dbias = lax.fori_loop(
-        0, groups, back, (dh_scr[...], jnp.zeros_like(da_ref),
-                          jnp.zeros_like(dbias_ref)))
-    dh_scr[...] = dh
+    da, dbias = _pairs(groups, after, (jnp.zeros_like(da_ref),
+                                       jnp.zeros_like(dbias_ref)))
     da_ref[...] += da
     dbias_ref[...] += dbias
 
@@ -266,13 +350,15 @@ def lanes_of(channels):
 def vmem_bytes(rows, lanes, n, itemsize):
     """What the backward, the larger pass, holds: u, z, dg, du, dz in the
     input's dtype and dt, d(dt) float32 at most, a chunk by a tile each, B,
-    C, dB, dC as (N, 128)-padded group blocks, A, dA, the states and dh
-    (N, tile) float32, all blocks double-buffered; then the chunk's
-    states and dy, float32 scratch."""
+    C as (N, 128)-padded group blocks, dB, dC a chunk's (position, state)
+    sums, A, dA, the states and dh (N, tile) float32, all blocks
+    double-buffered; then the chunk's states, the state's gradient at each
+    position and dy, float32 scratch."""
     seq = rows * lanes * (5 * itemsize + 2 * 4)
-    small = 4 * (rows // _GROUP) * n * _TILE_COLS * 4
+    small = 2 * (rows // _GROUP) * n * _TILE_COLS * 4 + 2 * rows * n * 4
     tile = 4 * n * lanes * 4
-    scratch = (rows + 1) * n * lanes * 4 + rows * lanes * 4 + n * lanes * 4
+    scratch = (2 * rows + 1) * n * lanes * 4 + rows * lanes * 4 \
+        + n * lanes * 4
     return 2 * (seq + small + tile) + scratch
 
 
@@ -293,18 +379,15 @@ def _by_group(x):
         .transpose(0, 1, 3, 2)
 
 
-def _from_group(x, dtype):
-    b, g, n, _ = x.shape
-    return x.transpose(0, 1, 3, 2).reshape(b, g * _GROUP, n).astype(dtype)
-
-
 def _specs(rows, lanes, n, at=lambda t: t, zc=0):
     """Block specs on the grid (B, tiles, chunks) at the chunk ``at(t)``:
     a chunk by a tile of a (B, S, channels) array (z's from the tile
     ``zc`` on); a chunk's groups of a (B, S / G, N, G) one; a (N, tile)
     row block of A^T and a (1, tile) one of D or the bias; a chunk's (N,
-    tile) state; a tile's (N, tile) and (1, tile) sums over positions,
-    resident across the chunks."""
+    tile) state; a tile's chunk of (position, state) sums over its
+    channels, eight positions a row of (chunk / 8, 8 N); a tile's (N,
+    tile) and (1, tile) sums over positions, resident across the
+    chunks."""
     return dict(
         seq=pl.BlockSpec((None, rows, lanes),
                          lambda b, c, t: (b, at(t), c)),
@@ -316,8 +399,8 @@ def _specs(rows, lanes, n, at=lambda t: t, zc=0):
         d=pl.BlockSpec((1, lanes), lambda b, c, t: (0, c)),
         state=pl.BlockSpec((None, None, n, lanes),
                            lambda b, c, t: (b, at(t), 0, c)),
-        tile_grp=pl.BlockSpec((None, None, rows // _GROUP, n, _GROUP),
-                              lambda b, c, t: (b, c, at(t), 0, 0)),
+        sum_c=pl.BlockSpec((None, None, None, rows // 8, 8 * n),
+                           lambda b, c, t: (b, c, at(t), 0, 0)),
         sum_a=pl.BlockSpec((None, n, lanes), lambda b, c, t: (b, 0, c)),
         sum_d=pl.BlockSpec((None, 1, lanes), lambda b, c, t: (b, 0, c)),
     )
@@ -372,27 +455,28 @@ def _backward(u, dt, at, bg, cg, d, z, bias, states, dg, how):
     _count("ssm_scan_chunks", chunks)
     sp = _specs(rows, lanes, n, at=lambda t: chunks - 1 - t,
                 zc=z_col // lanes)
-    grp = (b, tiles, s // _GROUP, n, _GROUP)
+    sums = (b, tiles, chunks, rows // 8, 8 * n)   # (S, N) a tile
     du, ddl, dz, db, dc, da, dd, dbias = _call(
         functools.partial(_bwd_kernel, groups=rows // _GROUP),
         "ssm_scan_bwd", (b, tiles, chunks),
         [sp["seq"], sp["seq"], sp["z"], sp["grp"], sp["grp"], sp["a"],
          sp["d"], sp["d"], sp["state"], sp["seq"]],
-        [sp["seq"], sp["seq"], sp["seq"], sp["tile_grp"], sp["tile_grp"],
+        [sp["seq"], sp["seq"], sp["seq"], sp["sum_c"], sp["sum_c"],
          sp["sum_a"], sp["sum_d"], sp["sum_d"]],
         [jax.ShapeDtypeStruct(u.shape, u.dtype),
          jax.ShapeDtypeStruct(dt.shape, dt.dtype),
          jax.ShapeDtypeStruct(u.shape, z.dtype),
-         jax.ShapeDtypeStruct(grp, jnp.float32),
-         jax.ShapeDtypeStruct(grp, jnp.float32),
+         jax.ShapeDtypeStruct(sums, jnp.float32),
+         jax.ShapeDtypeStruct(sums, jnp.float32),
          jax.ShapeDtypeStruct((b, n, ch), jnp.float32),
          jax.ShapeDtypeStruct((b, 1, ch), jnp.float32),
          jax.ShapeDtypeStruct((b, 1, ch), jnp.float32)],
-        [(n, lanes), (rows + 1, n, lanes), (rows, lanes)], interpret,
+        [(n, lanes), (rows + 1, n, lanes), (rows, lanes), (rows, n, lanes)],
+        interpret,
     )(u, dt, z, bg, cg, at, _row(d), _row(bias), states,
       dg.astype(u.dtype))
-    return (du, ddl, dz, da.sum(0), db.sum(1), dc.sum(1), dd.sum((0, 1)),
-            dbias.sum((0, 1)))
+    return (du, ddl, dz, da.sum(0), db.sum(1).reshape(b, s, n),
+            dc.sum(1).reshape(b, s, n), dd.sum((0, 1)), dbias.sum((0, 1)))
 
 
 # ---------------------------------------------------------------------------
@@ -413,14 +497,14 @@ def _scan_bwd(how, res, dg):
     u, dt, a, b, c, d, z, bias, states = res
     with jax.named_scope("ssm_scan_bwd"):
         at, bg, cg = a.astype(jnp.float32).T, _by_group(b), _by_group(c)
-        du, ddl, dz, dat, dbg, dcg, dd, dbias = _backward(
+        du, ddl, dz, dat, db, dc, dd, dbias = _backward(
             u, dt, at, bg, cg, d, z, bias, states, dg, how)
         z_col = how[1]
         if dz.shape != z.shape:     # z read in place from a wider array
             dz = jnp.pad(dz, ((0, 0), (0, 0),
                               (z_col, z.shape[2] - z_col - dz.shape[2])))
-        return (du, ddl, dat.T.astype(a.dtype), _from_group(dbg, b.dtype),
-                _from_group(dcg, c.dtype), dd.astype(d.dtype), dz,
+        return (du, ddl, dat.T.astype(a.dtype), db.astype(b.dtype),
+                dc.astype(c.dtype), dd.astype(d.dtype), dz,
                 dbias.astype(bias.dtype))
 
 

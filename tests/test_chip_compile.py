@@ -581,6 +581,43 @@ def test_short_conv_kernels_compile(one_chip, monkeypatch):
         "short_conv_plain", 0)
 
 
+@pytest.mark.parametrize("chunk", [256, 128])
+def test_selective_scan_kernels_compile(one_chip, monkeypatch, chunk):
+    """A Mamba layer's selective scan at the shape of the cell
+    phi4flash3.8b-train-s8192, u, dt, z, B, C bfloat16 (1, 8192) of 5,120
+    channels and 16 states, value and gradient in all eight inputs:
+    ``ssm_scan_fwd`` and ``ssm_scan_bwd`` under the names
+    ``ssm_scan_ms.tokens`` reads, counted once as kernels over 8192 /
+    ``chunk`` chunks a pass, at the default chunk and at 128 rows; the
+    backward's stored gradient of every position's state fits beside its
+    other scratch."""
+    from mxnet_tpu.kernels import selective_scan as ss
+
+    def loss(*args):
+        return ss.selective_scan(*args, chunk=chunk).astype(
+            jnp.float32).sum()
+
+    before = kernels.counters()
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        c = _compile(jax.value_and_grad(loss, range(8)), one_chip,
+                     ((1, 8192, 5120), jnp.bfloat16),
+                     ((1, 8192, 5120), jnp.bfloat16),
+                     ((5120, 16), jnp.float32),
+                     ((1, 8192, 16), jnp.bfloat16),
+                     ((1, 8192, 16), jnp.bfloat16),
+                     ((5120,), jnp.float32),
+                     ((1, 8192, 5120), jnp.bfloat16),
+                     ((5120,), jnp.float32))
+    _assert_kernel(c, "ssm_scan_fwd")
+    _assert_kernel(c, "ssm_scan_bwd")
+    after = kernels.counters()
+    assert after["ssm_scan_pallas"] == before.get("ssm_scan_pallas", 0) + 1
+    assert after.get("ssm_scan_plain", 0) == before.get("ssm_scan_plain", 0)
+    assert after["ssm_scan_chunks"] - before.get("ssm_scan_chunks", 0) \
+        == 2 * 8192 // chunk
+
+
 def test_the_lfm2_cells_step_fits_and_takes_the_short_conv_kernels(
         one_chip, monkeypatch):
     """``SPMDTrainer``'s step of the cell lfm2moe24b-train-s8192 at its

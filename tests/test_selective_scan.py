@@ -8,6 +8,7 @@ import pytest
 
 from mxnet_tpu import kernels
 from mxnet_tpu.kernels import selective_scan as ss
+from mxnet_tpu.kernels.cost_model import _VMEM_BUDGET_BYTES
 
 
 def _inputs(b, s, ch, n, dtype, seed=0):
@@ -36,17 +37,24 @@ def _rel(x, y):
     return float(onp.linalg.norm(x - y) / max(onp.linalg.norm(y), 1e-30))
 
 
-@pytest.mark.parametrize("s,chunk", [
-    (64, 32),       # two chunks of two groups
-    (80, 64),       # one whole chunk and a padded one
-    (48, 256),      # one chunk shorter than the default
-])
-def test_kernels_match_the_twin_forward_and_vjp(s, chunk):
+@pytest.mark.parametrize("s,chunk,ch", [
+    (64, 32, 256),      # two chunks of two groups
+    (80, 64, 256),      # one whole chunk and a padded one
+    (48, 256, 256),     # one chunk shorter than the default
+    (384, 128, 256),    # three chunks of 128 rows
+    (768, 256, 256),    # three chunks of the default 256
+    (300, 128, 256),    # two whole chunks of 128 and a padded third
+    (64, 32, 384),      # three channel tiles of 128 lanes
+], ids=["64-32", "80-64", "48-256", "384-128", "768-256", "300-128",
+        "64-32-384"])
+def test_kernels_match_the_twin_forward_and_vjp(s, chunk, ch):
     """float32 throughout: the kernel and the twin differ by the order of
-    float32 sums only (a position's read-out over 16 states, dB and dC
-    over the channels in tiles), so 1e-5 of each result's norm."""
-    args = _inputs(2, s, 256, 16, jnp.float32)
-    w = jax.random.normal(jax.random.PRNGKey(9), (2, s, 256))
+    float32 sums only (a position's read-out over 16 states; dB and dC
+    over the channels, a tile's lanes folded and transposed; du and d(dt)
+    over the states in halving steps), so 1e-5 of each result's norm:
+    what a sum taken in bfloat16 would miss by far."""
+    args = _inputs(2, s, ch, 16, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(9), (2, s, ch))
 
     def loss(use, *xs):
         g = ss.selective_scan(*xs, chunk=chunk, use_pallas=use)
@@ -141,7 +149,16 @@ def test_the_twin_is_the_recurrence_as_written():
 
 
 def test_eligible_and_the_refusal():
+    """The Phi-4-mini-flash cell's scan (5,120 channels, 16 states,
+    bfloat16) fits the backward's blocks and scratch, the stored gradient
+    of each position's state among them; at 32 states that store
+    (256 x 32 x 256 float32, 8.4 MB) takes the backward over the budget at
+    the default chunk, which it fitted without the store, and not at a
+    chunk of 128."""
     assert ss.eligible(5120, 16, 2) and ss.lanes_of(5120) == 256
+    assert ss.vmem_bytes(256, 256, 16, 2) <= _VMEM_BUDGET_BYTES
+    assert ss.vmem_bytes(256, 256, 32, 2) > _VMEM_BUDGET_BYTES
+    assert not ss.eligible(5120, 32, 2) and ss.eligible(5120, 32, 2, 128)
     assert ss.lanes_of(384) == 128 and not ss.eligible(100, 16, 2)
     args = _inputs(1, 16, 96, 16, jnp.float32)
     with pytest.raises(ValueError, match="in multiples of 128"):
